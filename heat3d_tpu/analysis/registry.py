@@ -54,6 +54,14 @@ LEDGER_EVENTS: Dict[str, Dict[str, Any]] = {
               "desc": "one supervised checkpoint window (force-synced)"},
     "init_state": {"kind": "span", "module": "models/heat3d.py",
                    "desc": "sharded initial-state construction"},
+    "solver.build": {"kind": "span", "module": "models/heat3d.py",
+                     "desc": "HeatSolver3D construction: config "
+                             "resolution, mesh, step builders (not their "
+                             "compiles)"},
+    "solver.dispatch": {"kind": "span", "module": "models/heat3d.py",
+                        "desc": "one solver program dispatch (program: "
+                                "run|step|step_residual|converge; steps; "
+                                "compile_s when it compiled)"},
     "cg_solve": {"kind": "point", "module": "models/heat3d.py",
                  "desc": "implicit-cg run finished: steps, last solve's "
                          "iteration count and relative residual (the "
@@ -478,13 +486,18 @@ FAIL_SOFT_CONTRACT: Dict[str, tuple] = {
         "Ledger.close",
         "NullLedger.event",
         "NullLedger.span",
+        "_SpanCtx.__enter__",
+        "_SpanCtx.__exit__",
     ),
     "heat3d_tpu/obs/metrics.py": (
         "export_at_exit",
     ),
     "heat3d_tpu/obs/trace.py": (
         "named_phase",
-        "annotate",
+        "open_span",
+        "close_span",
+        "spans",
+        "_on_duration",
     ),
     "heat3d_tpu/obs/perf/profiling.py": (
         "profile_capture",

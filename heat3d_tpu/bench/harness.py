@@ -82,7 +82,7 @@ def bench_throughput(
     import time as _time
 
     for _ in range(warmup):
-        u = solver.run(u, jnp.int32(steps))
+        u = solver.run(u, steps)
         force_sync(u)
     rtt = sync_overhead(probe=jnp.zeros((8, 128)))
 

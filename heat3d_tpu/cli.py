@@ -493,7 +493,7 @@ def _timed_run(args, cfg, solver, u, start_step):
     # per-shard (zeros callback) so no process ever materializes the full
     # global array — same rule as init_state.
     t_warm = time.perf_counter()
-    with obs.get().span("warmup"), obs.annotate("warmup"):
+    with obs.get().span("warmup"):
         _dummy = solver.zeros_state
 
         if cfg.run.tolerance is not None:

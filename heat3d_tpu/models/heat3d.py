@@ -20,6 +20,7 @@ import numpy as np
 from heat3d_tpu import obs
 from heat3d_tpu.core import golden
 from heat3d_tpu.core.config import Precision, SolverConfig
+from heat3d_tpu.parallel.plan import exchange_bytes
 from heat3d_tpu.parallel.step import (
     make_converge_fn,
     make_multistep_fn,
@@ -116,6 +117,14 @@ class HeatSolver3D:
     """
 
     def __init__(self, cfg: SolverConfig, devices=None):
+        with obs.get().span(
+            "solver.build",
+            grid=list(cfg.grid.shape),
+            mesh=list(cfg.mesh.shape),
+        ):
+            self._build(cfg, devices)
+
+    def _build(self, cfg: SolverConfig, devices) -> None:
         # Auto knobs (backend='auto', halo='auto', time_blocking=0)
         # resolve through the tuning cache — the safety net for library
         # users; the CLIs resolve at their entry points so their rows and
@@ -175,6 +184,9 @@ class HeatSolver3D:
         self._carry_res = None
         self._route = None
         self._device_field_cache = {}
+        # bytes one device sends per exchange at width 1 and at width k
+        # (see _halo_bytes); None for the integrators' own programs
+        self._exchange_bytes = None
         if cfg.integrator != "explicit-euler":
             from heat3d_tpu import timeint
 
@@ -203,6 +215,9 @@ class HeatSolver3D:
             donate_argnums=0,
         )
         converge = make_converge_fn(cfg, self.mesh, compute)
+        self._exchange_bytes = (
+            exchange_bytes(cfg, 1), exchange_bytes(cfg, cfg.time_blocking)
+        )
         self._converge_route = converge.route
         self._converge = jax.jit(converge, donate_argnums=0)
 
@@ -392,11 +407,55 @@ class HeatSolver3D:
 
     # ---- stepping --------------------------------------------------------
 
+    def _halo_bytes(self, program: str, steps: int) -> Optional[int]:
+        """Bytes one device sends over the halo in ``steps`` updates of
+        ``program``, priced from shapes (``plan.exchange_bytes``), never
+        read from the device; None for the integrators' own programs. The
+        fixed-step loop makes ``steps // k`` width-k supersteps, then
+        ``steps % k`` single steps; with ``residual_every = K > 1`` the
+        convergence loop makes groups of K - 1 loop updates and one
+        residual step (a run cut by max_steps ends in a shorter group);
+        every other dispatch exchanges once per update."""
+        if self._exchange_bytes is None:
+            return None
+        one, wide = self._exchange_bytes
+        k = self.cfg.time_blocking
+
+        def loop(n):
+            return n // k * wide + n % k * one
+
+        every = max(1, self.cfg.run.residual_every or 1)
+        if program == "run":
+            return loop(steps)
+        if program == "converge" and every > 1:
+            full, rest = divmod(steps, every)
+            return full * (loop(every - 1) + one) + (
+                loop(rest - 1) + one if rest else 0
+            )
+        return steps * one
+
+    def _dispatched(self, program: str, steps: int) -> None:
+        """Count one dispatch of ``program`` that made ``steps`` updates:
+        ``heat3d_solver_steps_total{program}`` and
+        ``heat3d_halo_bytes_total``."""
+        obs.REGISTRY.counter("solver_steps_total").inc(steps, program=program)
+        nbytes = self._halo_bytes(program, steps)
+        if nbytes is not None:
+            obs.REGISTRY.counter("halo_bytes_total").inc(nbytes)
+
     def step(self, u: jax.Array) -> jax.Array:
-        return self._step(u)
+        with obs.get().span("solver.dispatch", program="step", steps=1):
+            u = self._step(u)
+        self._dispatched("step", 1)
+        return u
 
     def step_with_residual(self, u: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        return self._step_res(u)
+        with obs.get().span(
+            "solver.dispatch", program="step_residual", steps=1
+        ):
+            out = self._step_res(u)
+        self._dispatched("step_residual", 1)
+        return out
 
     def carry_residual(self, carry: Tuple[jax.Array, jax.Array]) -> jax.Array:
         """The global change residual (sum of squares) between the two
@@ -431,16 +490,20 @@ class HeatSolver3D:
         field and land in the ledger as a ``cg_solve`` event (the one
         host sync happens after the loop, where the caller consumes the
         field anyway)."""
+        steps = int(num_steps)
+        with obs.get().span("solver.dispatch", program="run", steps=steps):
+            out = self._multistep(u, jnp.int32(steps))
+        self._dispatched("run", steps)
         if self.cfg.integrator == "implicit-cg":
-            u, iters, relres = self._multistep(u, jnp.int32(num_steps))
+            u, iters, relres = out
             obs.get().event(
                 "cg_solve",
-                steps=int(num_steps),
+                steps=steps,
                 cg_iters=int(iters),
                 cg_relres=float(relres),
             )
             return u
-        return self._multistep(u, jnp.int32(num_steps))
+        return out
 
     def run_to_convergence(
         self, u: jax.Array, tol: float, max_steps: int
@@ -454,8 +517,16 @@ class HeatSolver3D:
                 "fixed-step run() (docs/INTEGRATORS.md)"
             )
         self._route = self._converge_route
-        u, steps, res = self._converge(u, jnp.int32(max_steps), jnp.float32(tol))
-        return RunResult(u=u, steps=int(steps), residual=float(res))
+        with obs.get().span("solver.dispatch", program="converge") as sp:
+            u, steps, res = self._converge(
+                u, jnp.int32(max_steps), jnp.float32(tol)
+            )
+        # the step count is a device value: read after the span closes
+        # (the ring record still takes the field)
+        steps = int(steps)
+        sp.add(steps=steps)
+        self._dispatched("converge", steps)
+        return RunResult(u=u, steps=steps, residual=float(res))
 
     def run_supervised(
         self,

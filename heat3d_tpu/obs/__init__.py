@@ -9,11 +9,13 @@ Three instruments, one package (see docs/OBSERVABILITY.md):
   points, run-id/generation/process tagging) written by every entry point.
 - :mod:`~heat3d_tpu.obs.metrics` — counters/gauges/histograms with a
   Prometheus-textfile/JSON exporter and a final per-run summary record.
-- :mod:`~heat3d_tpu.obs.trace` — ``jax.named_scope`` / TraceAnnotation
-  brackets so profiler traces attribute device time to *our* phases.
+- :mod:`~heat3d_tpu.obs.trace` — spans on the profiler's clock (each
+  ``span`` is also a ``TraceAnnotation`` and a record in an always-on
+  in-process ring, :func:`spans`) and ``jax.named_scope`` brackets, so
+  profiler traces attribute host and device time to *our* phases.
 
-Library code uses the module-level conveniences and pays a no-op when
-nothing is configured::
+Library code uses the module-level conveniences; with nothing configured
+a span costs its annotation and ring record, an event nothing::
 
     from heat3d_tpu import obs
 
@@ -43,4 +45,4 @@ from heat3d_tpu.obs.metrics import (  # noqa: F401
     REGISTRY,
     export_at_exit,
 )
-from heat3d_tpu.obs.trace import annotate, named_phase, scoped  # noqa: F401
+from heat3d_tpu.obs.trace import named_phase, scoped, spans  # noqa: F401

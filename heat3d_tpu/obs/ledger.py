@@ -20,12 +20,18 @@ Spans are written AT CLOSE, so file order is end-time order and parent
 spans appear after their children — the lint's nesting check and the
 summary's timeline both rely on this.
 
+``span`` is the program's one span API, with three sinks: while open, a
+profiler ``TraceAnnotation("heat3d.<name>")``; at close, always, a record
+in the in-process ring (``obs.spans()``, on the profiler's clock — see
+``obs/trace.py``); and, only on an active ledger, the JSONL line.
+
 Activation: entry points call :func:`activate` with their ``--ledger``
 flag; library code calls :func:`get` unconditionally and writes through
 whatever is active. With no flag, ``HEAT3D_LEDGER=<path>`` activates the
 ledger from the environment (how ``run_bench_suite.sh`` threads one ledger
 through every row's subprocess); with neither, :func:`get` returns the
-:data:`NULL` ledger and every hook is a cheap no-op.
+:data:`NULL` ledger: events are dropped, and spans keep only their
+annotation and ring record.
 
 Context tagging: ``set_context(generation=8)`` merges fields into every
 subsequent event (the supervisor tags its current generation this way), so
@@ -41,6 +47,8 @@ import threading
 import time
 import uuid
 from typing import Any, Dict, Optional
+
+from heat3d_tpu.obs.trace import Span, close_span, open_span
 
 ENV_LEDGER = "HEAT3D_LEDGER"
 ENV_LEDGER_MAX_MB = "HEAT3D_LEDGER_MAX_MB"
@@ -111,51 +119,45 @@ def ledger_segments(path: str) -> "list[str]":
     return out
 
 
-class SpanHandle:
-    """Mutable view of an in-flight span: ``add(**fields)`` attaches fields
-    to the record written at close; ``dur_s`` is readable after the span
-    exits (callers feed it to the metrics registry)."""
-
-    def __init__(self) -> None:
-        self.fields: Dict[str, Any] = {}
-        self.dur_s: Optional[float] = None
-
-    def add(self, **fields: Any) -> None:
-        self.fields.update(fields)
-
-
 class _SpanCtx:
-    def __init__(self, ledger: "Ledger", name: str, fields: Dict[str, Any]):
+    """The one span: opens a :class:`~heat3d_tpu.obs.trace.Span` (its
+    profiler annotation and its ring record, always) and, on a real
+    ledger, writes the span event at close."""
+
+    def __init__(self, ledger: "Optional[Ledger]", name: str,
+                 fields: Dict[str, Any]):
         self._ledger = ledger
         self._name = name
         self._fields = fields
-        self.handle = SpanHandle()
 
-    def __enter__(self) -> SpanHandle:
+    def __enter__(self) -> Span:
         self._t0 = time.monotonic()
-        self._depth = self._ledger._enter_span()
-        return self.handle
+        if self._ledger is not None:
+            self._depth = self._ledger._enter_span()
+        self.span = open_span(self._name, self._fields)
+        return self.span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        t1 = time.monotonic()
-        self.handle.dur_s = t1 - self._t0
-        self._ledger._exit_span()
-        status = "ok" if exc_type is None else "error"
-        fields = dict(self._fields)
-        fields.update(self.handle.fields)
+        span = self.span
         if exc_type is not None:
-            fields.setdefault(
+            span.fields.setdefault(
                 "error", f"{exc_type.__name__}: {str(exc)[:200]}"
             )
+        close_span(span, ok=exc_type is None)
+        t1 = time.monotonic()
+        span.dur_s = t1 - self._t0
+        if self._ledger is None:
+            return False
+        self._ledger._exit_span()
         span_fields = {
             "t0": self._t0,
             "t1": t1,
-            "dur_s": self.handle.dur_s,
+            "dur_s": span.dur_s,
             "depth": self._depth,
-            "status": status,
+            "status": span.status,
         }
         span_fields.update(
-            (k, v) for k, v in fields.items() if k not in span_fields
+            (k, v) for k, v in span.fields.items() if k not in span_fields
         )
         self._ledger._write(self._name, "span", span_fields)
         return False  # never swallow
@@ -340,9 +342,10 @@ class Ledger:
         self._write(name, "point", fields)
 
     def span(self, name: str, **fields: Any) -> _SpanCtx:
-        """Context manager timing a region; writes one span event at exit
-        (status ``error`` + the exception's repr if the body raised —
-        re-raised, never swallowed). Yields a :class:`SpanHandle`."""
+        """Context manager timing a region (see :class:`_SpanCtx`); writes
+        one span event at exit (status ``error`` + the exception's repr if
+        the body raised — re-raised, never swallowed). Yields the
+        :class:`~heat3d_tpu.obs.trace.Span`."""
         return _SpanCtx(self, name, fields)
 
     def close(self, **fields: Any) -> None:
@@ -367,7 +370,8 @@ class Ledger:
 class NullLedger:
     """The inactive ledger: same surface, no IO — library code calls
     ``obs.get().event(...)`` unconditionally and pays one attribute check
-    when no ledger is configured."""
+    when no ledger is configured; its spans still reach the profiler and
+    the ring."""
 
     path = None
     run_id = None
@@ -379,22 +383,12 @@ class NullLedger:
     def event(self, name: str, **fields: Any) -> None:
         pass
 
-    def span(self, name: str, **fields: Any) -> "_NullSpanCtx":
-        return _NullSpanCtx()
+    def span(self, name: str, **fields: Any) -> _SpanCtx:
+        """The span without its ledger line: annotation and ring record."""
+        return _SpanCtx(None, name, fields)
 
     def close(self, **fields: Any) -> None:
         pass
-
-
-class _NullSpanCtx:
-    def __enter__(self) -> SpanHandle:
-        self._t0 = time.monotonic()
-        self.handle = SpanHandle()
-        return self.handle
-
-    def __exit__(self, exc_type, exc, tb) -> bool:
-        self.handle.dur_s = time.monotonic() - self._t0
-        return False
 
 
 NULL = NullLedger()

@@ -222,12 +222,20 @@ def normalize_phase(token: str) -> str:
     (``halo.x.lo.p0``) and per-axis DMA (``halo.x.dma``) scopes — all
     fold into ``halo_exchange``, so the roofline/timeline joins keep
     attributing the finer-grained exchange scopes to the one exchange
-    phase instead of ``(unattributed)``. The names then join
-    ``parallel.step.phase_programs`` / the ledger spans on one key."""
+    phase instead of ``(unattributed)``. A named Pallas kernel's op
+    (``heat3d.stencil.<family>``) folds into its phase the same way: the
+    fused DMA families (``fused_dma*``, ``fused_rdma*``) into
+    ``fused_dma``, every other family into ``stencil``. The names then
+    join ``parallel.step.phase_programs`` / the ledger spans on one key."""
     if token.startswith("heat3d."):
         token = token[len("heat3d."):]
     if token == "halo" or token.startswith("halo."):
         return "halo_exchange"
+    if token.startswith("stencil."):
+        family = token[len("stencil."):]
+        if family.startswith(("fused_dma", "fused_rdma")):
+            return "fused_dma"
+        return "stencil"
     return token
 
 

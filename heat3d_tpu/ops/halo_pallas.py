@@ -167,6 +167,7 @@ def _exchange_axis_dma_width1(
     plane_elems = plane_shape[0] * plane_shape[1]
     ghost_lo, ghost_hi = pl.pallas_call(
         kernel,
+        name=f"heat3d.halo.{axis_name}.dma",
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=(
             pl.BlockSpec(memory_space=pltpu.VMEM),
@@ -300,6 +301,7 @@ def _exchange_axis_dma_slab(
     slab_elems = lo_face.shape[0] * lo_face.shape[1] * lo_face.shape[2]
     ghost_lo, ghost_hi = pl.pallas_call(
         kernel,
+        name=f"heat3d.halo.{axis_name}.dma",
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),

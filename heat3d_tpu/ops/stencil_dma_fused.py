@@ -617,6 +617,7 @@ def apply_step_fused_dma(
         operands = (u, u, u, u)
     out, glo, ghi = pl.pallas_call(
         kernel,
+        name="heat3d.stencil.fused_dma",
         grid=(n_chunks, nx + 4),
         in_specs=in_specs,
         out_specs=(
@@ -983,6 +984,7 @@ def apply_superstep_fused_dma(
         operands = (u, u, u, u)
     out, _glo, _ghi = pl.pallas_call(
         kernel,
+        name="heat3d.stencil.fused_dma2",
         grid=(n_chunks, nx + 8),
         in_specs=in_specs,
         out_specs=(

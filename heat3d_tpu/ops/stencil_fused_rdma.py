@@ -263,6 +263,7 @@ def apply_step_fused_rdma(
         operands = (u, u, u, u)
     out, _glo, _ghi = pl.pallas_call(
         kernel,
+        name="heat3d.stencil.fused_rdma",
         grid=(n_chunks, nx + 4),
         in_specs=in_specs,
         out_specs=(
@@ -368,6 +369,7 @@ def apply_superstep_fused_rdma(
         operands = (u, u, u, u)
     out, _glo, _ghi = pl.pallas_call(
         kernel,
+        name="heat3d.stencil.fused_rdma2",
         grid=(n_chunks, nx + 8),
         in_specs=in_specs,
         out_specs=(

@@ -276,6 +276,7 @@ def apply_taps_pallas_stream(
     flops_per_cell = 2 * len(flat)
     return pl.pallas_call(
         kernel,
+        name="heat3d.stencil.stream",
         grid=(nxp,),
         in_specs=[pl.BlockSpec((1, nyp, nzp), lambda i: (i, 0, 0))],
         # Steps 0-1 park on output plane 0; step 2 overwrites it with the
@@ -480,6 +481,7 @@ def apply_taps_pallas_stream2(
     )
     return pl.pallas_call(
         kernel,
+        name="heat3d.stencil.stream2",
         grid=(nx + 4,),
         in_specs=[pl.BlockSpec((1, ny + 4, nz + 4), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec(
@@ -726,6 +728,7 @@ def apply_taps_pallas_streamk(
     )
     return pl.pallas_call(
         kernel,
+        name="heat3d.stencil.streamk",
         grid=(nx + 2 * k,),
         in_specs=[
             pl.BlockSpec((1, ny + 2 * k, nz + 2 * k), lambda i: (i, 0, 0))
@@ -832,6 +835,7 @@ def apply_taps_pallas(
     # the grid walks x only.
     return pl.pallas_call(
         kernel,
+        name="heat3d.stencil.taps",
         grid=(nx // bx,),
         in_specs=[
             pl.BlockSpec(

@@ -485,6 +485,7 @@ def apply_taps_direct(
     flops_per_cell = 2 * (MEHRSTELLEN_OPS if coeffs is not None else len(flat))
     return pl.pallas_call(
         kernel,
+        name="heat3d.stencil.direct",
         grid=(n_chunks, nx + 2),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
@@ -729,6 +730,7 @@ def apply_taps_direct2(
     raw_cells = (nx + 2) * (ny + 2) * (nz + 2) + nx * ny * nz
     return pl.pallas_call(
         kernel,
+        name="heat3d.stencil.direct2",
         grid=(n_chunks, nx + 4),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(

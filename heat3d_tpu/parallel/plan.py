@@ -477,6 +477,17 @@ def effective_halo_plan(cfg: SolverConfig) -> str:
     return resolve_halo_plan(cfg)
 
 
+def _plan_args(cfg: SolverConfig, width: int) -> Dict[str, Any]:
+    """:func:`build_plan`'s keyword arguments for ``cfg``'s exchange at
+    ``width`` ghost layers: the one transport and mode rule."""
+    return {
+        "width": int(width),
+        "halo_order": cfg.halo_order,
+        "transport": "dma" if cfg.halo == "dma" else "ppermute",
+        "mode": resolve_halo_plan(cfg),
+    }
+
+
 def plan_for(cfg: SolverConfig, width: int = 1) -> ExchangePlan:
     """The cached plan for ``cfg``'s exchange at ``width`` ghost layers.
 
@@ -485,16 +496,15 @@ def plan_for(cfg: SolverConfig, width: int = 1) -> ExchangePlan:
     dtype, grid size — the plan is shape-agnostic until ``apply``).
     Emits ``exchange_plan_built`` on a genuine build and
     ``plan_cache_hit`` on reuse, each once per (run, plan key)."""
-    mode = resolve_halo_plan(cfg)
-    transport = "dma" if cfg.halo == "dma" else "ppermute"
+    args = _plan_args(cfg, width)
     key = (
         cfg.mesh.shape,
         cfg.mesh.axis_names,
         cfg.stencil.bc,
-        int(width),
-        cfg.halo_order,
-        transport,
-        mode,
+        args["width"],
+        args["halo_order"],
+        args["transport"],
+        args["mode"],
         DEFAULT_PARTITIONS,
         part_min_bytes(),  # env-overridable floor keys its own plans
     )
@@ -502,17 +512,24 @@ def plan_for(cfg: SolverConfig, width: int = 1) -> ExchangePlan:
     if plan is not None:
         _event_once("plan_cache_hit", plan.key)
         return plan
-    plan = build_plan(
-        cfg.mesh,
-        cfg.stencil.bc,
-        width=width,
-        halo_order=cfg.halo_order,
-        transport=transport,
-        mode=mode,
-    )
+    plan = build_plan(cfg.mesh, cfg.stencil.bc, **args)
     _PLAN_CACHE[key] = plan
     _event_once("exchange_plan_built", plan.key, **plan.describe())
     return plan
+
+
+def exchange_bytes(cfg: SolverConfig, width: int = 1) -> int:
+    """Bytes one device sends in one exchange of ``cfg``'s field at
+    ``width`` ghost layers, as :meth:`ExchangePlan.traffic` prices it:
+    every face of a sharded axis in both directions, later axes' faces
+    carrying the earlier ghosts. The fused and faces-direct kernel routes
+    move the same faces. Priced from shapes on an uncached plan (no plan
+    event); zero on a mesh of one device."""
+    import jax.numpy as jnp
+
+    plan = build_plan(cfg.mesh, cfg.stencil.bc, **_plan_args(cfg, width))
+    itemsize = jnp.dtype(cfg.precision.storage).itemsize
+    return plan.traffic(cfg.local_shape, itemsize)["bytes_per_device"]
 
 
 def adhoc_exchange(u, cfg: SolverConfig, width: int = 1, bc_value: Any = None):

@@ -11,6 +11,7 @@ and ``tune.cache.chip_generation`` reads the v5e generation.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -122,6 +123,29 @@ def test_block_mesh_step_compiles_at_cell_d(v5e, tb):
     text = _compile_text(*_sharded_step(v5e, cfg))
     assert "tpu_custom_call" in text
     assert "collective-permute" in text or "collective_permute" in text
+
+
+def test_direct2_superstep_kernel_keeps_its_name(v5e):
+    """The direct2 kernel's ``name=`` names its compiled custom call
+    ``%heat3d.stencil.direct2.<n>`` in the (2,2,1) superstep: the
+    ``%heat3d.stencil.`` prefix the benchmark's kernel readers match."""
+    cfg = SolverConfig(
+        grid=GridConfig(shape=(2048, 2048, 1024)),
+        stencil=StencilConfig(kind="7pt"),
+        mesh=MeshConfig(shape=(2, 2, 1)),
+        precision=Precision.fp32(),
+        backend="auto",
+        time_blocking=2,
+    )
+    text = _compile_text(*_sharded_step(v5e, cfg))
+    calls = re.findall(
+        r"(%[\w.-]+) = [^\n]* custom-call\([^\n]*"
+        r'custom_call_target="tpu_custom_call"',
+        text,
+    )
+    assert calls and all(
+        re.fullmatch(r"%heat3d\.stencil\.direct2\.\d+", c) for c in calls
+    ), calls
 
 
 @pytest.mark.parametrize(

@@ -123,6 +123,205 @@ def test_ledger_unserializable_span_field_salvaged_schema_valid(tmp_path):
     assert ledger_check.check_file(p) == []
 
 
+# ---- spans: one API, three sinks -----------------------------------------
+
+
+def _ring_since(mark):
+    return [r for r in obs.spans() if r["id"] > mark]
+
+
+def _last_id():
+    recs = obs.spans()
+    return recs[-1]["id"] if recs else -1
+
+
+def test_spans_reach_the_ring_with_no_ledger_active(monkeypatch):
+    monkeypatch.delenv("HEAT3D_LEDGER", raising=False)
+    mark = _last_id()
+    led = obs.get()
+    assert not led.active
+    with led.span("outer", steps=3) as outer:
+        with led.span("inner") as inner:
+            inner.add(note="x")
+    with pytest.raises(RuntimeError, match="boom"):
+        with led.span("fails"):
+            raise RuntimeError("boom")
+    recs = {r["name"]: r for r in _ring_since(mark)}
+    assert set(recs) == {"outer", "inner", "fails"}
+    assert recs["outer"]["parent"] is None
+    assert recs["inner"]["parent"] == recs["outer"]["id"]
+    assert recs["inner"]["fields"] == {"note": "x"}
+    assert recs["outer"]["fields"] == {"steps": 3}
+    assert recs["outer"]["start_ns"] <= recs["inner"]["start_ns"]
+    assert recs["inner"]["end_ns"] <= recs["outer"]["end_ns"]
+    assert recs["fails"]["status"] == "error"
+    assert "boom" in recs["fails"]["fields"]["error"]
+    assert outer.dur_s is not None and outer.dur_s >= 0
+
+
+def test_span_ring_stays_bounded():
+    from heat3d_tpu.obs.trace import SpanRing
+
+    ring = SpanRing(size=8)
+    for i in range(20):
+        ring.close(ring.open(f"s{i}", {}), ok=True)
+    names = [r["name"] for r in ring.records()]
+    assert names == [f"s{i}" for i in range(12, 20)]
+    assert ring.innermost() is None
+
+
+def test_span_ring_record_matches_its_trace_annotation(tmp_path):
+    """The ring's clock is the profiler's: a span's ``start_ns`` equals
+    its TraceAnnotation twin's offset plus the trace's
+    ``profile_start_time``, within 1 ms, and so does its end."""
+    import glob
+    import time
+
+    import jax
+
+    mark = _last_id()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.get().span("twin_probe"):
+            time.sleep(0.02)
+    finally:
+        jax.profiler.stop_trace()
+    (rec,) = [r for r in _ring_since(mark) if r["name"] == "twin_probe"]
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path[0])
+    (env,) = [p for p in pd.planes if p.name == "Task Environment"]
+    t0 = dict(env.stats)["profile_start_time"]
+    twins = [
+        ev
+        for p in pd.planes
+        for line in p.lines
+        for ev in line.events
+        if ev.name == "heat3d.twin_probe"
+    ]
+    assert len(twins) == 1
+    ev = twins[0]
+    assert abs(t0 + ev.start_ns - rec["start_ns"]) < 1e6
+    assert abs(t0 + ev.start_ns + ev.duration_ns - rec["end_ns"]) < 1e6
+
+
+def test_first_dispatch_carries_compile_s_and_the_second_none():
+    from heat3d_tpu.core.config import GridConfig, SolverConfig
+    from heat3d_tpu.models.heat3d import HeatSolver3D
+
+    solver = HeatSolver3D(
+        SolverConfig(grid=GridConfig.cube(8), backend="jnp", time_blocking=2)
+    )
+    mark = _last_id()
+    u = solver.init_state("hot-cube")
+    u = solver.run(u, 3)
+    u = solver.run(u, 3)
+    u, _ = solver.step_with_residual(u)
+    recs = _ring_since(mark)
+    runs = [r for r in recs if r["name"] == "solver.dispatch"]
+    assert [r["fields"]["program"] for r in runs] == [
+        "run", "run", "step_residual",
+    ]
+    assert runs[0]["fields"]["compile_s"] > 0
+    assert "compile_s" not in runs[1]["fields"]
+    assert runs[2]["fields"]["compile_s"] > 0
+    assert [r["fields"]["steps"] for r in runs] == [3, 3, 1]
+    (init,) = [r for r in recs if r["name"] == "init_state"]
+    assert init["end_ns"] <= runs[0]["start_ns"]
+
+
+def test_converge_dispatch_span_reads_steps_after_it_closes():
+    """The ``converge`` dispatch span closes when the program call
+    returns; its ``steps`` (a device value) is read after the span and
+    still reaches the ring record. The halo bytes of each program follow
+    its loop: ``n // k`` width-k supersteps and ``n % k`` steps, and with
+    ``residual_every = K`` groups of K - 1 loop updates and a residual
+    step."""
+    from heat3d_tpu.core.config import GridConfig, RunConfig, SolverConfig
+    from heat3d_tpu.models.heat3d import HeatSolver3D
+
+    solver = HeatSolver3D(SolverConfig(
+        grid=GridConfig.cube(8), backend="jnp", time_blocking=2,
+        run=RunConfig(residual_every=4),
+    ))
+    u = solver.init_state("hot-cube")
+    mark = _last_id()
+    result = solver.run_to_convergence(u, tol=0.0, max_steps=9)
+    (rec,) = [r for r in _ring_since(mark) if r["name"] == "solver.dispatch"]
+    assert rec["fields"]["program"] == "converge"
+    assert rec["fields"]["steps"] == result.steps == 9
+    # priced per exchange: 10 bytes at width 1, 30 at width 2
+    solver._exchange_bytes = (10, 30)
+    assert solver._halo_bytes("run", 5) == 2 * 30 + 10
+    assert solver._halo_bytes("step_residual", 1) == 10
+    # two groups of (30 + 10) + 10, then a cut group of one residual step
+    assert solver._halo_bytes("converge", 9) == 2 * 50 + 10
+
+
+_HALO_PROBE = """
+import json, re
+import jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from heat3d_tpu import obs
+from heat3d_tpu.core.config import GridConfig, MeshConfig, SolverConfig
+from heat3d_tpu.models.heat3d import HeatSolver3D
+from heat3d_tpu.parallel.step import make_superstep_fn
+
+cfg = SolverConfig(grid=GridConfig(shape=(16, 16, 8)),
+                   mesh=MeshConfig(shape=(2, 2, 1)), backend="auto",
+                   time_blocking=2)
+solver = HeatSolver3D(cfg)
+u = solver.init_state("hot-cube")
+u = jax.block_until_ready(solver.run(u, 2))
+snap = obs.REGISTRY.snapshot()
+superstep = make_superstep_fn(solver.cfg, solver.mesh)
+x = jax.ShapeDtypeStruct(cfg.grid.shape, jnp.float32,
+                         sharding=NamedSharding(solver.mesh, P("x", "y", "z")))
+text = jax.jit(superstep).lower(x).compile().as_text()
+permutes = re.findall(r"= (\\w+)\\[([\\d,]*)\\][^ ]* collective-permute\\(", text)
+print(json.dumps({
+    "route": solver.route,
+    "counter_bytes": snap["heat3d_halo_bytes_total"]["values"][""],
+    "steps": snap["heat3d_solver_steps_total"]["values"],
+    "permutes": permutes,
+}))
+"""
+
+
+def test_halo_counter_equals_the_superstep_collective_permute_bytes():
+    """On a (2,2,1) CPU mesh with the faces-direct kernel route emulated
+    (HEAT3D_DIRECT_INTERPRET), one superstep's dispatch adds to the halo
+    bytes counter exactly the operand bytes of the collective-permutes in
+    the compiled superstep program."""
+    import subprocess
+
+    import numpy as np
+
+    env = dict(
+        os.environ,
+        JAX_PLATFORMS="cpu",
+        HEAT3D_DIRECT_INTERPRET="1",
+        XLA_FLAGS="--xla_force_host_platform_device_count=4",
+        PYTHONPATH=REPO,
+    )
+    p = subprocess.run(
+        [sys.executable, "-c", _HALO_PROBE], cwd=REPO, env=env,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert p.returncode == 0, p.stderr[-3000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["route"]["kernel"] == "direct"
+    itemsize = {"f32": 4}
+    permute_bytes = sum(
+        itemsize[dt] * int(np.prod([int(d) for d in dims.split(",")]))
+        for dt, dims in out["permutes"]
+    )
+    # x faces (2,8,8) and x-extended y faces (12,2,8), both directions
+    assert permute_bytes == 4 * 2 * (2 * 8 * 8 + 12 * 2 * 8)
+    assert len(out["permutes"]) == 4
+    assert out["counter_bytes"] == permute_bytes
+    assert out["steps"] == {'{program="run"}': 2.0}
+
+
 def test_metrics_export_unwritable_path_does_not_raise(tmp_path, monkeypatch):
     """export_at_exit on an unwritable HEAT3D_METRICS path logs and
     returns None — telemetry must not turn a completed run into a

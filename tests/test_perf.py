@@ -727,6 +727,40 @@ def test_device_phase_totals_duck_typed_and_halo_fold():
     assert normalize_phase("heat3d.step") == "step"
 
 
+def test_device_phase_totals_fold_named_kernels_into_their_phase():
+    """A named Pallas kernel's op (``heat3d.stencil.<family>.<n>``, as
+    XLA names the custom call after the kernel's ``name=``) lands in its
+    phase: fused DMA families in fused_dma, the rest in stencil, the halo
+    DMA kernels in halo_exchange — no orphan ``stencil.<family>`` row."""
+    from types import SimpleNamespace
+
+    from heat3d_tpu.obs.perf.timeline import device_phase_totals
+
+    def ev(mid, dur_us):
+        return SimpleNamespace(metadata_id=mid, duration_ps=dur_us * 1e6)
+
+    meta = {
+        1: SimpleNamespace(name="heat3d.stencil.direct2.1"),
+        2: SimpleNamespace(name="heat3d.step/heat3d.stencil.streamk.4"),
+        3: SimpleNamespace(name="heat3d.stencil.fused_dma.3"),
+        4: SimpleNamespace(name="heat3d.stencil.fused_rdma2.7"),
+        5: SimpleNamespace(name="heat3d.halo.x.dma.2"),
+    }
+    plane = SimpleNamespace(
+        name="/device:TPU:0",
+        lines=[SimpleNamespace(
+            name="XLA Ops",
+            events=[ev(1, 30.0), ev(2, 10.0), ev(3, 7.0), ev(4, 5.0),
+                    ev(5, 3.0)],
+        )],
+        event_metadata=meta,
+    )
+    totals = device_phase_totals(SimpleNamespace(planes=[plane]))
+    assert totals == pytest.approx(
+        {"stencil": 40.0, "fused_dma": 12.0, "halo_exchange": 3.0}
+    )
+
+
 def _write_synthetic_xplane(tmp_path, stencil_us=40.0, halo_us=10.0,
                             step_us=0.0):
     xplane_pb2 = pytest.importorskip(
