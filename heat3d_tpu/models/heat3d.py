@@ -232,7 +232,8 @@ class HeatSolver3D:
                 run = make_multistep_fn(self.cfg, self.mesh, self._compute)
             # the integrators' own programs dispatch no kernel family
             self._route = getattr(
-                run, "route", {"kernel": None, "interpret": False}
+                run, "route",
+                {"kernel": None, "interpret": False, "layout": None},
             )
             self._multistep_cache = jax.jit(run, donate_argnums=0)
         return self._multistep_cache
@@ -246,7 +247,8 @@ class HeatSolver3D:
         exchange path, and ``interpret`` whether that kernel runs under
         the off-TPU emulation. The loop that ran last is the one
         reported; before any run, the fixed-step loop is built (not
-        compiled) to ask it."""
+        compiled) to ask it. ``layout`` is the kernel's VMEM plane layout
+        ('aligned' or 'framed', None without a kernel)."""
         if self._route is None:
             _ = self._multistep
         return {"backend": resolved_backend_name(self.cfg), **self._route}

@@ -28,6 +28,27 @@ Layout: the local (nx, ny, nz) volume is walked as a 2D Pallas grid
 inner — so arbitrarily large fields stream through a 3-slot VMEM plane ring
 exactly once per update.
 
+Ring planes come in two layouts, chosen from the shape (_aligned_layout):
+
+- Aligned, whenever nz is a multiple of 128 on the tap-chain route (every
+  benchmarked deployment): a plane is exactly nz lanes wide, with no ghost
+  lanes, and the (by, nz) chunk sits at a tile-aligned row between one
+  storage tile of pad rows above and one below (8 rows for 4-byte storage,
+  16 for 2-byte), its ghost rows stored narrowly into the pad next to it.
+  Every store and every unshifted read is whole tiles. A tap term is a
+  rotation of an aligned plane: dj = ±1 a sublane roll, dk = ±1 a lane
+  roll, whose wrap is the periodic boundary and which a one-lane select
+  turns into the Dirichlet one (_aligned_plane_taps). The tb=2 stage
+  between the two updates computes whole aligned planes too.
+- Ghost-framed, for any other nz and for the mehrstellen q-ring route:
+  each plane is (by + 2h, nz + 2h) with its ghost ring stored around the
+  chunk at offset (h, h), and every tap term is a slice at a lane and
+  sublane offset of 0..2h, which Mosaic relayouts. The fused-DMA and
+  fused-RDMA kernels share this scaffold.
+
+Both layouts sum the same values in the canonical order, so their results
+are bitwise equal.
+
 Note on grid-step count (so it isn't re-derived): per-step fixed overhead
 cannot be amortized by fusing bi > 1 x-planes per block. Every scheme holds
 ~10 block-sized buffers (rings + in/out pipelines), so steps =
@@ -51,6 +72,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from heat3d_tpu.core.stencils import (
+    accumulate_taps,
     decompose_mehrstellen,
     effective_num_taps,
     flat_taps,
@@ -83,11 +105,31 @@ def _plane_bytes(rows: int, lanes: int, itemsize: int) -> int:
     return _round_up(rows, _SUBLANE) * _round_up(lanes, _LANE) * itemsize
 
 
+def _pad_rows(itemsize: int) -> int:
+    """Rows of one storage tile: the aligned layout's pad above and below
+    the chunk (8 for 4-byte storage, 16 for 2-byte)."""
+    return _SUBLANE * max(1, 4 // itemsize)
+
+
+def _aligned_rows(by: int, itemsize: int) -> int:
+    """Rows of an aligned ring plane: the chunk, rounded up to whole
+    storage tiles, between one pad tile above and one below."""
+    p = _pad_rows(itemsize)
+    return p + _round_up(by, p) + p
+
+
 def _tap_stack_bytes(
-    by: int, nz: int, halo: int, n_taps: int, compute_itemsize: int = 4
+    by: int, nz: int, halo: int, n_taps: int, compute_itemsize: int = 4,
+    aligned_itemsize: int = 0,
 ) -> int:
     """Scoped-stack estimate of one tap chain: the fused (halo=2) kernel's
-    widest chain is the intermediate plane, one ghost ring larger."""
+    widest chain is the intermediate plane, one ghost ring larger. Under
+    the aligned layout (``aligned_itemsize`` = storage itemsize) the chain
+    runs on, or rotates, whole aligned planes."""
+    if aligned_itemsize:
+        return _tap_stack_bytes_2d(
+            _aligned_rows(by, aligned_itemsize), nz, n_taps, compute_itemsize
+        )
     r = halo - 1
     return _tap_stack_bytes_2d(
         by + 2 * r, nz + 2 * r, n_taps, compute_itemsize
@@ -101,14 +143,23 @@ def _vmem_bytes(
     in_itemsize: int,
     out_itemsize: int,
     q_itemsize: int = 0,
+    aligned: bool = False,
 ) -> int:
     """VMEM footprint of the direct kernel at chunk height ``by`` and ghost
     width ``halo`` (1 = single step, 2 = fused two-step): the assembled-plane
     ring(s), the double-buffered input chunk + ghost-row pipeline, and the
     double-buffered output pipeline. ``q_itemsize`` > 0 adds the mehrstellen
-    per-plane 2D-conv cache ring (3 planes, compute dtype)."""
-    ring = 3 * _plane_bytes(by + 2 * halo, nz + 2 * halo, in_itemsize)
-    if halo == 2:  # fused two-step: second ring for the intermediate planes
+    per-plane 2D-conv cache ring (3 planes, compute dtype). ``aligned``
+    sizes the rings as aligned planes (_aligned_layout): nz lanes, the
+    chunk between two pad tiles, in both rings."""
+    if aligned:
+        ring = 3 * halo * _plane_bytes(
+            _aligned_rows(by, in_itemsize), nz, in_itemsize
+        )
+    else:
+        ring = 3 * _plane_bytes(by + 2 * halo, nz + 2 * halo, in_itemsize)
+    if halo == 2 and not aligned:
+        # fused two-step: second ring for the intermediate planes
         ring += 3 * _plane_bytes(by + 2, nz + 2, in_itemsize)
     if q_itemsize:
         # one q ring per update stage: (by, nz) for the final stage, plus
@@ -141,6 +192,7 @@ def choose_chunk(
     q_ring: bool = False,
     reserve_bytes: int = 0,
     total_budget: Optional[int] = None,
+    aligned: bool = False,
 ) -> Optional[int]:
     """Largest y-chunk height ``by`` (a divisor of ny, multiple of 8 when
     ny >= 8) whose working set fits the VMEM budget — both the explicit
@@ -148,6 +200,8 @@ def choose_chunk(
     ``q_ring``) and the emit chain's scoped stack — or None. ``q_ring``
     overrides ``n_taps`` with the mehrstellen stack size here, in ONE
     place, so the dispatch gate and the kernel builder can't drift.
+    ``aligned`` budgets the aligned ring layout (the direct kernels pass
+    _aligned_layout's verdict; the fused-DMA kernels keep the framed one).
 
     ``total_budget`` (with ``reserve_bytes``) adds a COMBINED whole-chip
     constraint on top of the separate ring/stack ceilings: reserve +
@@ -169,8 +223,12 @@ def choose_chunk(
         ring = _vmem_bytes(
             by, nz, halo, in_itemsize, out_itemsize,
             q_itemsize=compute_itemsize if q_ring else 0,
+            aligned=aligned,
         )
-        stack = _tap_stack_bytes(by, nz, halo, n_taps, compute_itemsize)
+        stack = _tap_stack_bytes(
+            by, nz, halo, n_taps, compute_itemsize,
+            aligned_itemsize=in_itemsize if aligned else 0,
+        )
         if ring > _VMEM_BUDGET or stack > _TAP_STACK_BUDGET:
             continue
         if (
@@ -194,6 +252,23 @@ def _mehrstellen_q_ring(taps) -> bool:
     )
 
 
+def _aligned_layout(nz: int, q_ring: bool) -> bool:
+    """Whether the direct kernels lay their ring planes out on whole
+    tiles — nz lanes with no ghost lanes, the chunk at a tile-aligned row
+    — and build tap terms by rotation (_aligned_plane_taps): on the chain
+    route whenever nz is a whole number of lane tiles. The ONE layout
+    predicate the gate (direct_supported), both builders and the solver's
+    route record share."""
+    return nz % _LANE == 0 and not q_ring
+
+
+def direct_layout(local_shape: Tuple[int, int, int], taps=None) -> str:
+    """'aligned' or 'framed': the ring layout the direct kernels build for
+    this local shape (``taps`` as for direct_supported)."""
+    aligned = _aligned_layout(local_shape[2], _mehrstellen_q_ring(taps))
+    return "aligned" if aligned else "framed"
+
+
 def direct_supported(
     local_shape: Tuple[int, int, int],
     halo: int = 1,
@@ -214,6 +289,7 @@ def direct_supported(
         choose_chunk(
             local_shape, halo, in_itemsize, out_itemsize, n_taps,
             compute_itemsize, q_ring=q_ring,
+            aligned=_aligned_layout(nz, q_ring),
         )
         is not None
     )
@@ -260,6 +336,92 @@ def _store_input_plane(ring, k, chunk, top, bot, bc, periodic, h, ghost_x):
     @pl.when(jnp.logical_not(ghost_x))
     def _real_plane():
         _store_framed_plane(ring, k, chunk, top, bot, bc, False, h)
+
+
+def _store_aligned_plane(ring, k, chunk, top, bot, bc, periodic, h, ghost_x):
+    """The aligned layout's _store_input_plane: the chunk goes in at the
+    tile-aligned row ``pad`` by one whole-tile store (no lane or sublane
+    shift), its h ghost rows by narrow stores into the pad tiles beside
+    it. There are no ghost lanes: _aligned_plane_taps supplies the z edge.
+    Dirichlet domain ghost planes are pure bc, as in the framed layout."""
+    by = chunk.shape[0]
+    pad = _pad_rows(chunk.dtype.itemsize)
+
+    def real_plane():
+        ring[k, pad : pad + by] = chunk
+        ring[k, pad - h : pad] = top
+        ring[k, pad + by : pad + by + h] = bot
+
+    if periodic:
+        real_plane()
+        return
+
+    @pl.when(ghost_x)
+    def _bc_plane():
+        ring[k] = jnp.full(ring.shape[1:], bc, chunk.dtype)
+
+    pl.when(jnp.logical_not(ghost_x))(real_plane)
+
+
+def _edge_lane_select(x, lane, fill):
+    """``x`` with lane ``lane`` set to ``fill``. The select runs on the one
+    lane tile that holds the lane; the other tiles pass through."""
+    t0 = lane // _LANE * _LANE
+    tile = x[:, t0 : t0 + _LANE]
+    ids = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+    tile = jnp.where(ids == lane - t0, fill, tile)
+    parts = [x[:, :t0], tile, x[:, t0 + _LANE :]]
+    return jnp.concatenate([p for p in parts if p.shape[1]], axis=1)
+
+
+def _aligned_plane_taps(planes, taps_flat, lo, n, edge, compute_dtype):
+    """The tap chain over three aligned (R, nz) planes: the (n, nz) update
+    of rows [lo, lo + n) of the middle plane, in the canonical
+    core.stencils.accumulate_taps order with _plane_taps' xsum/ysum
+    caching, so the values summed, and their order, are the framed
+    route's and the results are bitwise equal.
+
+    A shifted term is a rotation of an aligned plane, never a misaligned
+    slice: dj by a sublane roll of the whole plane (rows wrap only into pad
+    rows no valid row reads), dk by a lane roll. Across the z edge the lane
+    roll is the periodic wrap (``edge`` None: z is always one whole block);
+    under Dirichlet (``edge`` = the ghost value in the compute dtype) a
+    select writes on the edge lane what the framed plane's ghost lane holds
+    there: bc, or the sum of bc's that a cached xsum/ysum adds up."""
+    rows_total, nz = planes[0].shape
+    cache = {}
+
+    def rows(src, dj):
+        if dj:
+            src = pltpu.roll(src, (-dj) % rows_total, 0)
+        return src[lo : lo + n]
+
+    def lanes(x, dk, fill):
+        if dk == 0:
+            return x
+        x = pltpu.roll(x, (-dk) % nz, 1)
+        if fill is None:
+            return x
+        return _edge_lane_select(x, 0 if dk < 0 else nz - 1, fill)
+
+    def twice(v):
+        return None if v is None else v + v
+
+    def term(di, dj, dk):
+        if di == "xsum":
+            if "p" not in cache:
+                cache["p"] = planes[-1] + planes[1]
+            src, fill = cache["p"], twice(edge)
+        else:
+            src, fill = planes[di], edge
+        if dj == "ysum":
+            key = ("ys", di)
+            if key not in cache:
+                cache[key] = rows(src, -1) + rows(src, 1)
+            return lanes(cache[key], dk, twice(fill))
+        return lanes(rows(src, dj), dk, fill)
+
+    return accumulate_taps(taps_flat, term, compute_dtype)
 
 
 # Tap accumulation shared with the exchange-path kernels: op order must stay
@@ -325,18 +487,21 @@ def _direct_kernel(
     bc_value,
     compute_dtype,
     out_dtype,
+    aligned=False,
 ):
-    """Grid step (j, i): assemble ghost-framed plane p = i-1 of chunk column
-    j into a 3-slot ring; once 3 planes are resident emit output plane i-2.
+    """Grid step (j, i): assemble plane p = i-1 of chunk column j into a
+    3-slot ring; once 3 planes are resident emit output plane i-2.
     Conceptual plane p runs -1 .. nx (the two x ghost planes); the index maps
     wrap (periodic) or clamp (Dirichlet, substituted with bc here).
 
     Two emit routes over one scaffold (the ring-slot arithmetic and ghost
     synthesis are load-bearing invariants kept in exactly one place):
-    ``taps_flat`` = the canonical tap chain; ``coeffs`` + ``ring_q`` = the
-    mehrstellen S+F route, where each stored plane also caches its 2D conv
-    in ``ring_q`` (computed ONCE per input plane instead of once per output
-    plane that reads it — the shifted-read reuse the route exists for)."""
+    ``taps_flat`` = the canonical tap chain, on ghost-framed planes or, when
+    ``aligned``, on aligned planes (_aligned_layout); ``coeffs`` + ``ring_q``
+    = the mehrstellen S+F route on framed planes, where each stored plane
+    also caches its 2D conv in ``ring_q`` (computed ONCE per input plane
+    instead of once per output plane that reads it — the shifted-read reuse
+    the route exists for)."""
     j = pl.program_id(0)
     i = pl.program_id(1)
     bc = u_ref.dtype.type(bc_value)
@@ -355,7 +520,8 @@ def _direct_kernel(
         def _store(k=k):
             # Conceptual planes -1 and nx are domain ghost planes: the
             # clamped load fetched plane 0 / nx-1; store a pure-bc plane.
-            _store_input_plane(
+            store = _store_aligned_plane if aligned else _store_input_plane
+            store(
                 ring, k, chunk, top, bot, bc, periodic, 1,
                 ghost_x=jnp.logical_or(i == 0, i == nx + 1),
             )
@@ -377,6 +543,11 @@ def _direct_kernel(
                 q_planes = {d: ring_q[s] for d, s in slots.items()}
                 res = _plane_mehrstellen(
                     planes, q_planes, coeffs, by, nz, compute_dtype
+                )
+            elif aligned:
+                res = _aligned_plane_taps(
+                    planes, taps_flat, _pad_rows(ring.dtype.itemsize), by,
+                    None if periodic else compute_dtype(bc), compute_dtype,
                 )
             else:
                 res = _plane_taps(planes, taps_flat, by, nz, compute_dtype)
@@ -438,11 +609,13 @@ def apply_taps_direct(
     flat = flat_taps(taps)
     q_ring = _mehrstellen_q_ring(taps)
     coeffs = decompose_mehrstellen(taps) if q_ring else None
+    aligned = _aligned_layout(nz, q_ring)
     by = choose_chunk(
         u.shape, 1, u.dtype.itemsize, jnp.dtype(out_dtype).itemsize,
         n_taps=effective_num_taps(taps),
         compute_itemsize=jnp.dtype(compute_dtype).itemsize,
         q_ring=q_ring,
+        aligned=aligned,
     )
     if by is None:
         raise ValueError(f"no VMEM-feasible chunking for {u.shape}")
@@ -454,7 +627,11 @@ def apply_taps_direct(
         x_of = lambda i: jnp.clip(i - 1, 0, nx - 1)
 
     single = n_chunks == 1
-    scratch_shapes = [pltpu.VMEM((3, by + 2, nz + 2), u.dtype)]
+    if aligned:
+        plane = (_aligned_rows(by, u.dtype.itemsize), nz)
+    else:
+        plane = (by + 2, nz + 2)
+    scratch_shapes = [pltpu.VMEM((3, *plane), u.dtype)]
     shared = dict(
         nx=nx,
         by=by,
@@ -464,6 +641,7 @@ def apply_taps_direct(
         bc_value=bc_value,
         compute_dtype=compute_dtype,
         out_dtype=jnp.dtype(out_dtype),
+        aligned=aligned,
     )
     base = _direct_kernel if not single else _direct_kernel_single
     if coeffs is not None:
@@ -524,10 +702,11 @@ def _direct2_kernel(
     compute_dtype,
     storage_dtype,
     out_dtype,
+    aligned=False,
 ):
     """Fused two-update direct kernel (temporal blocking k=2 in one HBM
-    sweep). Grid step (j, i): (a) assemble width-2 ghost-framed input plane
-    q = i (conceptual global plane i-2) into ring_a; (b) at i>=2 compute
+    sweep). Grid step (j, i): (a) assemble input plane q = i (conceptual
+    global plane i-2, width-2 ghosts) into ring_a; (b) at i>=2 compute
     intermediate plane m = i-2 (global i-4, one ghost ring wide) into
     ring_b, pinning Dirichlet domain ghosts exactly as the unfused sequence
     sees them; (c) at i>=4 emit output plane o = i-4 (global). Same plane
@@ -535,14 +714,20 @@ def _direct2_kernel(
     (assembled vs pre-padded) differs. Chunk columns recompute their two
     boundary intermediate rows — ~2/by duplicated VPU work, no extra HBM.
 
-    Routes as in _direct_kernel: ``taps_flat`` = tap chain;
-    ``coeffs`` + ``ring_qa``/``ring_qb`` = mehrstellen, with a per-stage
-    q cache (each stored input/intermediate plane's 2D conv computed once;
-    stage (b)'s cache is built AFTER the ghost pinning so it convolves
-    exactly the plane the unfused sequence would read)."""
+    Routes as in _direct_kernel: ``taps_flat`` = tap chain, framed or
+    ``aligned``; ``coeffs`` + ``ring_qa``/``ring_qb`` = mehrstellen, with a
+    per-stage q cache (each stored input/intermediate plane's 2D conv
+    computed once; stage (b)'s cache is built AFTER the ghost pinning so it
+    convolves exactly the plane the unfused sequence would read).
+
+    Aligned, stage (b) computes the whole aligned plane — rows pad-1 ..
+    pad+by are the intermediate's, the rest pad rows nothing reads — and
+    ring_b has no ghost lanes: stage (c)'s edge select supplies bc there."""
     j = pl.program_id(0)
     i = pl.program_id(1)
     bc_s = u_ref.dtype.type(bc_value)
+    pad = _pad_rows(storage_dtype.itemsize)
+    edge = None if periodic else compute_dtype(bc_s)
 
     chunk = u_ref[0]  # (by, nz)
     top, bot = _chunk_ghost_rows(chunk, top_ref, bot_ref, 2, periodic, bc_s)
@@ -554,7 +739,8 @@ def _direct2_kernel(
 
         @pl.when(jax.lax.rem(i, 3) == k)
         def _load(k=k):
-            _store_input_plane(
+            store = _store_aligned_plane if aligned else _store_input_plane
+            store(
                 ring_a, k, chunk, top, bot, bc_s, periodic, 2,
                 ghost_x=jnp.logical_or(i <= 1, i >= nx + 2),
             )
@@ -575,6 +761,11 @@ def _direct2_kernel(
                 mid = _plane_mehrstellen(
                     planes, q_planes, coeffs, by + 2, nz + 2, compute_dtype
                 )
+            elif aligned:
+                mid = _aligned_plane_taps(
+                    planes, taps_flat, 0, ring_a.shape[1], edge,
+                    compute_dtype,
+                )
             else:
                 mid = _plane_taps(
                     planes, taps_flat, by + 2, nz + 2, compute_dtype
@@ -594,7 +785,7 @@ def _direct2_kernel(
                 @pl.when(ghost_plane)
                 def _bc_mid():
                     ring_b[slot] = jnp.full(
-                        (by + 2, nz + 2), bc_s, storage_dtype
+                        ring_b.shape[1:], bc_s, storage_dtype
                     )
 
                 @pl.when(jnp.logical_not(ghost_plane))
@@ -603,20 +794,26 @@ def _direct2_kernel(
                     # narrow stores after the bulk store; ghost ROWS exist
                     # only on the edge chunk columns (interior chunk
                     # borders hold genuinely-updated cells), ghost lane
-                    # columns 0 / nz+1 always
+                    # columns 0 / nz+1 always (framed only: the aligned
+                    # plane has none)
                     ring_b[slot] = mid.astype(storage_dtype)
-                    edge_col = jnp.full((by + 2, 1), bc_s, storage_dtype)
-                    ring_b[slot, :, 0:1] = edge_col
-                    ring_b[slot, :, nz + 1 : nz + 2] = edge_col
-                    edge_row = jnp.full((1, nz + 2), bc_s, storage_dtype)
+                    width = ring_b.shape[2]
+                    if aligned:
+                        top_row, bot_row = pad - 1, pad + by
+                    else:
+                        top_row, bot_row = 0, by + 1
+                        edge_col = jnp.full((by + 2, 1), bc_s, storage_dtype)
+                        ring_b[slot, :, 0:1] = edge_col
+                        ring_b[slot, :, nz + 1 : nz + 2] = edge_col
+                    edge_row = jnp.full((1, width), bc_s, storage_dtype)
 
                     @pl.when(j == 0)
                     def _top_row():
-                        ring_b[slot, 0:1, :] = edge_row
+                        ring_b[slot, top_row : top_row + 1, :] = edge_row
 
                     @pl.when(j == n_chunks - 1)
                     def _bot_row():
-                        ring_b[slot, by + 1 : by + 2, :] = edge_row
+                        ring_b[slot, bot_row : bot_row + 1, :] = edge_row
 
                 if coeffs is not None:
                     # after BOTH branches' stores: convolve the exact
@@ -638,6 +835,10 @@ def _direct2_kernel(
                 q_planes = {d: ring_qb[s] for d, s in slots.items()}
                 res = _plane_mehrstellen(
                     planes, q_planes, coeffs, by, nz, compute_dtype
+                )
+            elif aligned:
+                res = _aligned_plane_taps(
+                    planes, taps_flat, pad, by, edge, compute_dtype
                 )
             else:
                 res = _plane_taps(planes, taps_flat, by, nz, compute_dtype)
@@ -673,11 +874,13 @@ def apply_taps_direct2(
     flat = flat_taps(taps)
     q_ring = _mehrstellen_q_ring(taps)
     coeffs = decompose_mehrstellen(taps) if q_ring else None
+    aligned = _aligned_layout(nz, q_ring)
     by = choose_chunk(
         u.shape, 2, u.dtype.itemsize, jnp.dtype(out_dtype).itemsize,
         n_taps=effective_num_taps(taps),
         compute_itemsize=jnp.dtype(compute_dtype).itemsize,
         q_ring=q_ring,
+        aligned=aligned,
     )
     if by is None:
         raise ValueError(f"no VMEM-feasible chunking for {u.shape}")
@@ -700,11 +903,13 @@ def apply_taps_direct2(
         compute_dtype=compute_dtype,
         storage_dtype=u.dtype,
         out_dtype=jnp.dtype(out_dtype),
+        aligned=aligned,
     )
-    scratch_shapes = [
-        pltpu.VMEM((3, by + 4, nz + 4), u.dtype),
-        pltpu.VMEM((3, by + 2, nz + 2), u.dtype),
-    ]
+    if aligned:  # both rings on the same aligned planes
+        planes = [(_aligned_rows(by, u.dtype.itemsize), nz)] * 2
+    else:
+        planes = [(by + 4, nz + 4), (by + 2, nz + 2)]
+    scratch_shapes = [pltpu.VMEM((3, *p), u.dtype) for p in planes]
     if coeffs is not None:
         kernel = functools.partial(base, coeffs=coeffs, **shared)
         scratch_shapes += [

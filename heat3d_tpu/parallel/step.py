@@ -46,14 +46,21 @@ def _log_step_path_once(msg: str) -> None:
         get_logger(__name__).info("%s", msg)
 
 
-def _routed(fn, kernel, interpret=False):
+def _routed(fn, kernel, interpret=False, layout=None):
     """Record on a built step function the route its builder dispatched:
     ``kernel`` is the Pallas kernel family ('direct', 'fused_dma',
     'fused_rdma', 'streamk'), or None for the exchange path with the
     padded-block compute; ``interpret`` says the kernel (or its XLA
-    reference contract) runs off-TPU under HEAT3D_DIRECT_INTERPRET. The
-    CLI summary, bench rows and chip_smoke read this one record."""
-    fn.route = {"kernel": kernel, "interpret": bool(interpret)}
+    reference contract) runs off-TPU under HEAT3D_DIRECT_INTERPRET;
+    ``layout`` is the direct kernels' VMEM plane layout, 'aligned' or
+    'framed' (ops.stencil_pallas_direct.direct_layout). Every other kernel
+    family builds framed planes, and there is no layout without a kernel.
+    The CLI summary, bench rows and chip_smoke read this one record."""
+    fn.route = {
+        "kernel": kernel,
+        "interpret": bool(interpret),
+        "layout": None if kernel is None else layout or "framed",
+    }
     return fn
 
 
@@ -998,6 +1005,7 @@ def make_step_fn(
     axes = cfg.mesh.axis_names
     local_step = _local_step
     kernel = None
+    layout = None
     # fused_rdma='on' wins the route when its gates pass: the knob is an
     # explicit opt-in, so it is dispatched ahead of the direct family
     # (which would otherwise take the same scope)
@@ -1020,7 +1028,10 @@ def make_step_fn(
         else _direct_kernel_fn(cfg, halo=1, multichip=True)
     )
     if direct is not None:
+        from heat3d_tpu.ops.stencil_pallas_direct import direct_layout
+
         kernel = "direct"
+        layout = direct_layout(cfg.local_shape, taps)
         interpret = _kernel_env_gate(cfg)[1]
         _log_step_path_once(
             "step path: %s direct kernel (no padded copy)"
@@ -1131,6 +1142,7 @@ def make_step_fn(
             ),
             kernel,
             kernel is not None and interpret,
+            layout,
         )
 
     def local(u_local):
@@ -1146,6 +1158,7 @@ def make_step_fn(
         ),
         kernel,
         kernel is not None and interpret,
+        layout,
     )
 
 
@@ -1274,6 +1287,8 @@ def make_superstep_fn(
                             u_local, taps, cfg, direct2
                         )
 
+            from heat3d_tpu.ops.stencil_pallas_direct import direct_layout
+
             return _routed(
                 scoped(
                     PHASE_STEP,
@@ -1284,6 +1299,7 @@ def make_superstep_fn(
                 ),
                 "direct",
                 _kernel_env_gate(cfg)[1],
+                direct_layout(cfg.local_shape, taps),
             )
 
     # The fused k-sweep streaming kernel (k=2..4): keeps the width-k

@@ -241,7 +241,8 @@ def test_solver_records_direct_route(monkeypatch):
     # off the chip 'auto' keeps the jnp padded-block compute; the direct
     # kernel replaces it on the (1,1,1) mesh
     assert HeatSolver3D(cfg).route == {
-        "backend": "jnp", "kernel": "direct", "interpret": True
+        "backend": "jnp", "kernel": "direct", "interpret": True,
+        "layout": "framed",  # nz = 16: not a whole lane tile
     }
     monkeypatch.setenv("HEAT3D_NO_DIRECT", "1")
     assert HeatSolver3D(cfg).route["kernel"] is None
@@ -274,7 +275,9 @@ def test_step_builders_record_fused_dma_route(monkeypatch, mesh, tb, kernel):
         time_blocking=tb,
     )
     run = make_multistep_fn(cfg, abstract_mesh(cfg.mesh))
-    assert run.route == {"kernel": kernel, "interpret": True}
+    assert run.route == {
+        "kernel": kernel, "interpret": True, "layout": "framed"
+    }
     pp = dataclasses.replace(cfg, halo="ppermute", overlap=tb == 1)
     run = make_multistep_fn(pp, abstract_mesh(pp.mesh))
     assert run.route["kernel"] == "direct"

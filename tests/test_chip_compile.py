@@ -75,14 +75,16 @@ def _compile_text(fn, *avals):
 @pytest.mark.parametrize("tb", [1, 2])
 def test_direct_kernel_compiles_at_1024(v5e, kind, storage, tb):
     """The one-chip hot path (cells a-c): direct (tb=1) and direct2
-    (tb=2) at 1024^3."""
+    (tb=2) at 1024^3, on the aligned plane layout."""
     from heat3d_tpu.ops.stencil_pallas_direct import (
         apply_taps_direct,
         apply_taps_direct2,
+        direct_layout,
     )
 
     kernel = apply_taps_direct if tb == 1 else apply_taps_direct2
     taps = stencil_taps(STENCILS[kind], 0.1, 0.1, (1.0, 1.0, 1.0))
+    assert direct_layout((1024, 1024, 1024), taps) == "aligned"
     x = jax.ShapeDtypeStruct(
         (1024, 1024, 1024), jnp.dtype(storage),
         sharding=SingleDeviceSharding(v5e.devices[0]),
@@ -175,5 +177,6 @@ def test_fused_slab_route_at_cell_e(v5e, route, tb):
     assert fn.route == {
         "kernel": "fused_dma" if "halo" in route else "fused_rdma",
         "interpret": False,
+        "layout": "framed",
     }
     assert "tpu_custom_call" in _compile_text(fn, x)

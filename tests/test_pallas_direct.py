@@ -64,7 +64,11 @@ def test_direct_interpret_matches_jnp(shape, kind):
         )
 
 
-@pytest.mark.parametrize("shape", [(16, 16, 16), (8, 16, 32), (4, 4, 4)])
+@pytest.mark.parametrize(
+    "shape",
+    # nz = 128 and 256: the aligned layout (_aligned_layout)
+    [(16, 16, 16), (8, 16, 32), (4, 4, 4), (4, 8, 128), (3, 16, 256)],
+)
 @pytest.mark.parametrize("kind", ["7pt", "27pt"])
 def test_direct2_interpret_matches_two_steps(shape, kind):
     u = jnp.asarray(golden.random_init(shape, seed=3))
@@ -177,11 +181,16 @@ def test_solver_end_to_end_direct_interpret(monkeypatch):
 
 @pytest.mark.tpu_smoke
 @pytest.mark.skipif(not on_tpu, reason="needs TPU")
+@pytest.mark.parametrize(
+    "shape,layout", [((64, 64, 128), "aligned"), ((64, 64, 96), "framed")]
+)
 @pytest.mark.parametrize("kind", ["7pt", "27pt"])
-def test_direct_compiled_on_tpu(kind):
-    shape = (64, 64, 128)
+def test_direct_compiled_on_tpu(kind, shape, layout):
+    from heat3d_tpu.ops.stencil_pallas_direct import direct_layout
+
     u = jnp.asarray(golden.random_init(shape, seed=5))
     taps = _taps(kind, shape)
+    assert direct_layout(shape, taps) == layout
     for bc, bcv in CASES:
         periodic = bc is BoundaryCondition.PERIODIC
         want = step_single_device(u, taps, bc, bcv)
@@ -190,16 +199,31 @@ def test_direct_compiled_on_tpu(kind):
         )(u)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6,
-            err_msg=f"{kind} {bc}",
+            err_msg=f"{kind} {shape} {bc}",
         )
 
 
 @pytest.mark.tpu_smoke
 @pytest.mark.skipif(not on_tpu, reason="needs TPU")
-def test_direct2_compiled_on_tpu():
-    shape = (64, 64, 128)
+@pytest.mark.parametrize(
+    "shape,layout",
+    [
+        ((64, 64, 128), "aligned"),
+        # multi-chunk: ghost-row pads and rotations across chunk borders
+        ((32, 2048, 256), "aligned"),
+        # nz not a whole lane tile: the ghost-framed planes, single- and
+        # multi-chunk
+        ((64, 64, 96), "framed"),
+        ((32, 2048, 96), "framed"),
+    ],
+)
+@pytest.mark.parametrize("kind", ["7pt", "27pt"])
+def test_direct2_compiled_on_tpu(kind, shape, layout):
+    from heat3d_tpu.ops.stencil_pallas_direct import direct_layout
+
     u = jnp.asarray(golden.random_init(shape, seed=6))
-    taps = _taps("7pt", shape)
+    taps = _taps(kind, shape)
+    assert direct_layout(shape, taps) == layout
     for bc, bcv in CASES:
         periodic = bc is BoundaryCondition.PERIODIC
         want = step_single_device(
@@ -210,19 +234,28 @@ def test_direct2_compiled_on_tpu():
         )(u)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6,
-            err_msg=f"{bc}",
+            err_msg=f"{kind} {shape} {bc}",
         )
 
 
-@pytest.mark.parametrize("kind", ["7pt", "27pt"])
-def test_multichunk_interpret_matches_jnp(kind, monkeypatch):
+@pytest.mark.parametrize(
+    "kind,nz",
+    [
+        pytest.param("7pt", 32, id="7pt"),
+        pytest.param("27pt", 32, id="27pt"),
+        pytest.param("7pt", 128, id="7pt-aligned"),
+        pytest.param("27pt", 128, id="27pt-aligned"),
+    ],
+)
+def test_multichunk_interpret_matches_jnp(kind, nz, monkeypatch):
     """Force n_chunks > 1 (ny=16, by=8) so the 8-row-aligned ghost-row
     blocks — the TPU-lowerable replacement for single-row BlockSpecs — are
-    exercised numerically, top/bottom substitution and wrap included."""
+    exercised numerically, top/bottom substitution and wrap included, in
+    the framed (nz = 32) and the aligned (nz = 128) layout."""
     import heat3d_tpu.ops.stencil_pallas_direct as d
 
     monkeypatch.setattr(d, "choose_chunk", lambda *a, **k: 8)
-    shape = (6, 16, 32)
+    shape = (6, 16, nz)
     u = jnp.asarray(golden.random_init(shape, seed=9))
     taps = _taps(kind, shape)
     for bc, bcv in CASES:
@@ -249,20 +282,34 @@ def test_direct_kernels_cross_lower_for_tpu(monkeypatch):
     """Pallas->Mosaic lowering for the TPU target runs host-side, so the
     block-spec alignment rules are checkable without hardware (this caught
     the original single-row ghost BlockSpecs, which violated the
-    8-divisible-sublane rule). Covers single- and multi-chunk modes."""
+    8-divisible-sublane rule). Covers single- and multi-chunk modes, in
+    the aligned layout (nz = 128, both storage widths: 8- and 16-row pad
+    tiles) and the ghost-framed one (nz = 96)."""
     import heat3d_tpu.ops.stencil_pallas_direct as d
 
     shape = (16, 32, 128)
     taps = _taps("27pt", shape)
-    u = jax.ShapeDtypeStruct(shape, jnp.float32)
+    routes = [
+        (shape, "aligned", jnp.float32),
+        (shape, "aligned", jnp.bfloat16),
+        ((16, 32, 96), "framed", jnp.float32),
+    ]
     for by in (32, 8):  # single-chunk, then 4-chunk
         monkeypatch.setattr(d, "choose_chunk", lambda *a, _by=by, **k: _by)
         for periodic in (False, True):
             for fn in (d.apply_taps_direct, d.apply_taps_direct2):
-                low = jax.jit(
-                    lambda v, f=fn, p=periodic: f(v, taps, periodic=p, bc_value=0.5)
-                ).trace(u).lower(lowering_platforms=("tpu",))
-                assert "tpu_custom_call" in low.as_text(), (by, periodic, fn)
+                for ushape, layout, storage in routes:
+                    assert d.direct_layout(ushape, taps) == layout
+                    u = jax.ShapeDtypeStruct(ushape, storage)
+                    low = jax.jit(
+                        lambda v, f=fn, p=periodic: f(
+                            v, taps, periodic=p, bc_value=0.5
+                        )
+                    ).trace(u).lower(lowering_platforms=("tpu",))
+                    assert "tpu_custom_call" in low.as_text(), (
+                        by, periodic, fn, layout, storage,
+                    )
+        u = jax.ShapeDtypeStruct(shape, jnp.float32)
         # mehrstellen q-ring variants (tb=1 and the fused tb=2 kernel)
         monkeypatch.setenv("HEAT3D_MEHRSTELLEN", "1")
         for periodic in (False, True):
@@ -382,3 +429,94 @@ def test_direct2_mehrstellen_multichunk_interpret(monkeypatch):
             np.asarray(got), np.asarray(want), rtol=3e-6, atol=3e-6,
             err_msg=f"multichunk direct2 mehrstellen bc={bc}",
         )
+
+
+# XLA's CPU backend contracts a multiply and an add into one FMA where it
+# fuses them, and the two layouts fuse differently; with instruction
+# fusion off every product is rounded before it is summed, as the canonical
+# order says, so the two routes can be compared bit for bit.
+_NO_FMA_CONTRACTION = {"xla_disable_hlo_passes": "cpu-instruction-fusion"}
+
+
+def _unfused(fn, u):
+    return np.asarray(
+        jax.jit(fn).lower(u).compile(_NO_FMA_CONTRACTION)(u), np.float32
+    )
+
+
+@pytest.mark.parametrize(
+    "kind,case,storage",
+    [
+        (kind, case, "float32")
+        for kind in ("7pt", "27pt")
+        for case in range(len(CASES))
+    ]
+    + [("7pt", 1, "bfloat16"), ("27pt", 2, "bfloat16")],
+)
+def test_aligned_matches_framed_bitwise(kind, case, storage, monkeypatch):
+    """The aligned layout (rotated tap terms on tile-aligned planes) sums
+    the same values in the same order as the framed one (misaligned slices
+    of ghost-framed planes): both kernels, single- and multi-chunk, give
+    the same bits."""
+    import heat3d_tpu.ops.stencil_pallas_direct as d
+
+    bc, bcv = CASES[case]
+    periodic = bc is BoundaryCondition.PERIODIC
+    for shape, by in (((5, 16, 128), None), ((6, 16, 256), 8)):
+        taps = _taps(kind, shape)
+        u = jnp.asarray(golden.random_init(shape, seed=11), storage)
+        if by is not None:
+            monkeypatch.setattr(d, "choose_chunk", lambda *a, **k: by)
+        for fn in (d.apply_taps_direct, d.apply_taps_direct2):
+            run = lambda v, f=fn: f(  # noqa: E731
+                v, taps, periodic=periodic, bc_value=bcv, interpret=True
+            )
+            assert d.direct_layout(shape, taps) == "aligned"
+            aligned = _unfused(run, u)
+            with monkeypatch.context() as m:
+                m.setattr(d, "_aligned_layout", lambda nz, q_ring: False)
+                framed = _unfused(run, u)
+            np.testing.assert_array_equal(
+                aligned, framed, err_msg=f"{fn.__name__} {shape} by={by}"
+            )
+
+
+@pytest.mark.parametrize("tb", [1, 2])
+def test_direct_bf16_storage_aligned(tb):
+    """bf16 storage on the aligned layout (16-row pad tiles), fp32 math:
+    within one bf16 ulp of rounding-order headroom of the jnp steps."""
+    shape = (6, 16, 128)
+    u = jnp.asarray(golden.random_init(shape, seed=2), jnp.bfloat16)
+    taps = _taps("7pt", shape)
+    want = u
+    for _ in range(tb):
+        want = step_single_device(
+            want, taps, BoundaryCondition.DIRICHLET, 0.5, Precision.bf16()
+        )
+    fn = apply_taps_direct if tb == 1 else apply_taps_direct2
+    got = fn(
+        u, taps, periodic=False, bc_value=0.5, out_dtype=jnp.bfloat16,
+        interpret=True,
+    )
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(got, dtype=np.float32), np.asarray(want, dtype=np.float32),
+        rtol=2e-2, atol=1e-2,
+    )
+
+
+@pytest.mark.parametrize("nz,layout", [(128, "aligned"), (32, "framed")])
+@pytest.mark.parametrize("tb", [1, 2])
+def test_solver_route_records_layout(nz, layout, tb, monkeypatch):
+    """The solver's route names the plane layout its direct kernel builds:
+    aligned where nz is a whole number of lane tiles, framed elsewhere."""
+    from heat3d_tpu.models.heat3d import HeatSolver3D
+
+    monkeypatch.setenv("HEAT3D_DIRECT_INTERPRET", "1")
+    monkeypatch.delenv("HEAT3D_NO_DIRECT", raising=False)
+    cfg = SolverConfig(
+        grid=GridConfig(shape=(8, 8, nz)), time_blocking=tb
+    )
+    route = HeatSolver3D(cfg).route
+    assert route["kernel"] == "direct"
+    assert route["layout"] == layout
