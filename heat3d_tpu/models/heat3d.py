@@ -248,10 +248,16 @@ class HeatSolver3D:
         the off-TPU emulation. The loop that ran last is the one
         reported; before any run, the fixed-step loop is built (not
         compiled) to ask it. ``layout`` is the kernel's VMEM plane layout
-        ('aligned' or 'framed', None without a kernel)."""
+        ('aligned' or 'framed', None without a kernel). Where the devices
+        carry ICI ``coords`` (TPU), ``mesh_coords`` lists them in the
+        mesh's row-major order (``parallel.topology.ici_order``)."""
         if self._route is None:
             _ = self._multistep
-        return {"backend": resolved_backend_name(self.cfg), **self._route}
+        route = {"backend": resolved_backend_name(self.cfg), **self._route}
+        coords = [getattr(d, "coords", None) for d in self.mesh.devices.flat]
+        if all(c is not None for c in coords):
+            route["mesh_coords"] = [list(c) for c in coords]
+        return route
 
     # ---- state -----------------------------------------------------------
 

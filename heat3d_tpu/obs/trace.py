@@ -39,6 +39,10 @@ docs/OBSERVABILITY.md):
   attributes here instead of ``(unattributed)``)
 - ``heat3d.halo_exchange`` (and ``heat3d.halo.<axis>`` per axis)
 - ``heat3d.stencil``
+- ``heat3d.shell`` (nested in ``heat3d.stencil``: the faces-direct and
+  fused-DMA block routes' shard-boundary shell patches — slab assembly,
+  tap applications, pins and the update slices; the bulk kernel stays
+  ``heat3d.stencil.<family>``)
 - ``heat3d.fused_dma``
 - ``heat3d.residual``
 

@@ -408,20 +408,23 @@ def _patch_boundary_shells(
     bulk kernel's in-register ghost synthesis was wrong) from virtual
     padded slabs over the exchanged ``faces``, and patch them into
     ``out``. Axes of mesh size 1 are skipped — local BC/wrap synthesis is
-    already exact there."""
-    for axis in axes:
-        if cfg.mesh.shape[axis] == 1:
-            continue
-        n = u_local.shape[axis]
-        for start, pos in ((0, 0), (n - 1, n - 1)):
-            slab = _padded_slab(u_local, faces, axis, start)
-            shell = apply_taps_padded(
-                slab, taps, compute_dtype=compute_dtype, out_dtype=out_dtype
-            )
-            idx = [0, 0, 0]
-            idx[axis] = pos
-            out = lax.dynamic_update_slice(out, shell, tuple(idx))
-    return out
+    already exact there. Every op of the patches carries the
+    ``heat3d.shell`` scope."""
+    with named_phase("shell"):
+        for axis in axes:
+            if cfg.mesh.shape[axis] == 1:
+                continue
+            n = u_local.shape[axis]
+            for start, pos in ((0, 0), (n - 1, n - 1)):
+                slab = _padded_slab(u_local, faces, axis, start)
+                shell = apply_taps_padded(
+                    slab, taps, compute_dtype=compute_dtype,
+                    out_dtype=out_dtype,
+                )
+                idx = [0, 0, 0]
+                idx[axis] = pos
+                out = lax.dynamic_update_slice(out, shell, tuple(idx))
+        return out
 
 
 def _pin_slab_mid(
@@ -460,7 +463,9 @@ def _local_superstep_direct_faces(
     virtual width-2 padded slabs (faces carry 2-deep neighbor data,
     corners included): apply taps, pin the slab intermediate's domain
     ghosts (storage-dtype round trip like the unfused sequence), apply taps
-    again, patch in. One exchange and one HBM sweep per TWO updates."""
+    again, patch in. One exchange and one HBM sweep per TWO updates. The
+    patches (slab assembly, both tap applications, the pin, the update)
+    carry the ``heat3d.shell`` scope; the bulk kernel does not."""
     from heat3d_tpu.parallel.halo import exchange_halo_faces
 
     periodic = cfg.stencil.bc is BoundaryCondition.PERIODIC
@@ -477,26 +482,31 @@ def _local_superstep_direct_faces(
         compute_dtype=compute_dtype,
         out_dtype=out_dtype,
     )
-    for axis, size in enumerate(cfg.mesh.shape):
-        if size == 1:
-            continue  # kernel's local BC/wrap is already exact on this axis
-        n = u_local.shape[axis]
-        for start in (0, n - 2):  # width-2 padded coords; final planes
-            # env-default route: the direct2 bulk kernel follows the
-            # mehrstellen knob (q-ring variant), so patched cells follow
-            # it too (cross-kernel ulp-match contract)
-            slab = _padded_slab(u_local, faces, axis, start, w=2, thickness=6)
-            mid = apply_taps_padded(
-                slab, taps, compute_dtype=compute_dtype, out_dtype=out_dtype
-            )
-            mid = _pin_slab_mid(mid, cfg, axis, start)
-            shell = apply_taps_padded(
-                mid, taps, compute_dtype=compute_dtype, out_dtype=out_dtype
-            )
-            idx = [0, 0, 0]
-            idx[axis] = start  # local planes [start, start+2)
-            out = lax.dynamic_update_slice(out, shell, tuple(idx))
-    return out
+    with named_phase("shell"):
+        for axis, size in enumerate(cfg.mesh.shape):
+            if size == 1:
+                continue  # kernel's local BC/wrap is already exact here
+            n = u_local.shape[axis]
+            for start in (0, n - 2):  # width-2 padded coords; final planes
+                # env-default route: the direct2 bulk kernel follows the
+                # mehrstellen knob (q-ring variant), so patched cells
+                # follow it too (cross-kernel ulp-match contract)
+                slab = _padded_slab(
+                    u_local, faces, axis, start, w=2, thickness=6
+                )
+                mid = apply_taps_padded(
+                    slab, taps, compute_dtype=compute_dtype,
+                    out_dtype=out_dtype,
+                )
+                mid = _pin_slab_mid(mid, cfg, axis, start)
+                shell = apply_taps_padded(
+                    mid, taps, compute_dtype=compute_dtype,
+                    out_dtype=out_dtype,
+                )
+                idx = [0, 0, 0]
+                idx[axis] = start  # local planes [start, start+2)
+                out = lax.dynamic_update_slice(out, shell, tuple(idx))
+        return out
 
 
 def _fused_dma_gate(cfg: SolverConfig) -> bool:
@@ -1506,6 +1516,9 @@ def make_converge_fn(
 # record joins a measured span on ONE key (obs/perf/roofline.py consumes).
 PHASE_STEP = "step"
 PHASE_STENCIL = "stencil"
+# the faces-direct routes' shard-boundary shell patches, nested inside
+# the stencil scope; no compile target of its own (phase_programs)
+PHASE_SHELL = "shell"
 PHASE_HALO = "halo_exchange"
 PHASE_FUSED = "fused_dma"
 PHASE_RESIDUAL = "residual"
@@ -1515,7 +1528,10 @@ PHASE_RESIDUAL = "residual"
 # the PHASE_* constants (obs/perf/roofline.profile_join_records /
 # _phase_calls); obs/perf/timeline.normalize_phase folds trace scopes
 # onto the same names.
-PHASES = (PHASE_STEP, PHASE_STENCIL, PHASE_HALO, PHASE_FUSED, PHASE_RESIDUAL)
+PHASES = (
+    PHASE_STEP, PHASE_STENCIL, PHASE_SHELL, PHASE_HALO, PHASE_FUSED,
+    PHASE_RESIDUAL,
+)
 
 
 def phase_programs(

@@ -10,7 +10,7 @@ the v5p 3D torus mesh" part of BASELINE.json's north star).
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -18,14 +18,21 @@ from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec
 
 from heat3d_tpu.core.config import MeshConfig
 
+# the largest device list whose order is searched for ICI neighbours (one
+# host); above it the given order stands
+MAX_ORDERED_DEVICES = 8
+
 
 def build_mesh(cfg: MeshConfig, devices: Optional[Sequence] = None) -> Mesh:
     """Build the device mesh for (Px, Py, Pz).
 
     With no explicit device list and a mesh spanning every visible device,
     defer to ``jax.make_mesh`` (which picks an ICI-friendly physical
-    ordering on TPU). Otherwise take the first Px*Py*Pz devices in default
-    order — the moral equivalent of MPI_Cart_create(reorder=0).
+    ordering on TPU). Otherwise take the first Px*Py*Pz devices, ordered
+    by :func:`ici_order` — the MPI_Cart_create(reorder=1) analogue: on
+    devices that carry ICI ``coords``, mesh neighbours land on ICI
+    neighbours where such an order exists; everywhere else (CPU devices,
+    an order that already has it) the given order stands.
     """
     n = cfg.num_devices
     if devices is None:
@@ -37,8 +44,60 @@ def build_mesh(cfg: MeshConfig, devices: Optional[Sequence] = None) -> Mesh:
                 f"mesh {cfg.shape} needs {n} devices, only {len(avail)} visible"
             )
         devices = avail[:n]
-    dev = np.asarray(devices).reshape(cfg.shape)
-    return Mesh(dev, cfg.axis_names)
+    dev = np.asarray(ici_order(list(devices), tuple(cfg.shape)))
+    return Mesh(dev.reshape(cfg.shape), cfg.axis_names)
+
+
+def _hops(a, b) -> int:
+    """ICI hops between two devices by their ``coords`` (no torus wrap)."""
+    return sum(abs(int(p) - int(q)) for p, q in zip(a.coords, b.coords))
+
+
+def _neighbour_pairs(shape: Tuple[int, ...]) -> List[Tuple[int, int]]:
+    """(lower, higher) flat row-major positions of every pair of mesh
+    neighbours: adjacent along one axis, no wrap."""
+    idx = np.arange(int(np.prod(shape))).reshape(shape)
+    pairs = []
+    for axis, size in enumerate(shape):
+        if size > 1:
+            lo = np.take(idx, range(size - 1), axis=axis).ravel()
+            hi = np.take(idx, range(1, size), axis=axis).ravel()
+            pairs += [(int(a), int(b)) for a, b in zip(lo, hi)]
+    return pairs
+
+
+def ici_order(devices: List, shape: Tuple[int, ...]) -> List:
+    """``devices`` in an order whose row-major reshape to ``shape`` puts
+    every pair of mesh neighbours at most one ICI hop apart, read from the
+    devices' ``coords``: the first such order among the permutations of
+    the given list in lexicographic order, so the given order itself
+    wherever it already has this. The given order also stands when no
+    order has it, when a device carries no ``coords`` (CPU), and above
+    :data:`MAX_ORDERED_DEVICES` devices. On a 2x2 v5e host, ids (0,0)
+    (1,0) (0,1) (1,1) become the ring (0,0) (1,0) (1,1) (0,1) for a
+    (4,1,1) chain, and stay as they are for (2,2,1)."""
+    if (
+        len(devices) > MAX_ORDERED_DEVICES
+        or any(getattr(d, "coords", None) is None for d in devices)
+    ):
+        return devices
+    # each position's neighbours at lower positions, checked when it fills
+    below: List[List[int]] = [[] for _ in devices]
+    for a, b in _neighbour_pairs(shape):
+        below[b].append(a)
+
+    def extend(order, rest):
+        if not rest:
+            return order
+        p = len(order)
+        for i, d in enumerate(rest):
+            if all(_hops(d, order[q]) <= 1 for q in below[p]):
+                found = extend(order + [d], rest[:i] + rest[i + 1:])
+                if found is not None:
+                    return found
+        return None
+
+    return extend([], devices) or devices
 
 
 def abstract_mesh(cfg: MeshConfig) -> AbstractMesh:

@@ -15,9 +15,8 @@ import re
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from heat3d_tpu.core.config import (
@@ -95,9 +94,9 @@ def test_direct_kernel_compiles_at_1024(v5e, kind, storage, tb):
 
 def _sharded_step(topo, cfg):
     from heat3d_tpu.parallel.step import make_step_fn, make_superstep_fn
+    from heat3d_tpu.parallel.topology import build_mesh
 
-    devices = np.array(topo.devices[: cfg.mesh.num_devices])
-    mesh = Mesh(devices.reshape(cfg.mesh.shape), cfg.mesh.axis_names)
+    mesh = build_mesh(cfg.mesh, topo.devices[: cfg.mesh.num_devices])
     build = make_superstep_fn if cfg.time_blocking == 2 else make_step_fn
     fn = build(cfg, mesh)
     x = jax.ShapeDtypeStruct(
@@ -107,16 +106,29 @@ def _sharded_step(topo, cfg):
     return fn, x
 
 
-@pytest.mark.parametrize("tb", [1, 2])
-def test_block_mesh_step_compiles_at_cell_d(v5e, tb):
-    """Cell (d): (2,2,1) at 2048x2048x1024, 1024^3 per chip, through the
-    faces-direct kernel step and its collectives."""
+# the two four-chip cells: the 2x2x2 block run cut to (2,2,1), and the
+# 1D slab run as published; the first keeps its case ids
+FOUR_CHIP_CELLS = [
+    pytest.param(tb, mesh, grid, id=f"{tb}{tag}")
+    for mesh, grid, tag in [
+        ((2, 2, 1), (2048, 2048, 1024), ""),
+        ((4, 1, 1), (1024, 1024, 1024), "-4x1x1"),
+    ]
+    for tb in (1, 2)
+]
+
+
+@pytest.mark.parametrize("tb,mesh,grid", FOUR_CHIP_CELLS)
+def test_block_mesh_step_compiles_at_cell_d(v5e, tb, mesh, grid):
+    """The four-chip cells, 1024^3 per chip on (2,2,1) and 256x1024x1024
+    per chip on (4,1,1), through the faces-direct kernel step and its
+    collectives."""
     from heat3d_tpu.parallel.step import _direct_kernel_fn
 
     cfg = SolverConfig(
-        grid=GridConfig(shape=(2048, 2048, 1024)),
+        grid=GridConfig(shape=grid),
         stencil=StencilConfig(kind="7pt"),
-        mesh=MeshConfig(shape=(2, 2, 1)),
+        mesh=MeshConfig(shape=mesh),
         precision=Precision.fp32(),
         backend="auto",
         time_blocking=tb,
@@ -156,11 +168,12 @@ def test_direct2_superstep_kernel_keeps_its_name(v5e):
 )
 @pytest.mark.parametrize("tb", [1, 2])
 def test_fused_slab_route_at_cell_e(v5e, route, tb):
-    """Cell (e): (4,1,1) slab at 128x1024x1024 per chip. The explicitly
-    requested fused route either compiles holding its kernel, or the v5e
-    VMEM gate refuses it by name — never another route in its place."""
+    """Cell (e): the (4,1,1) slab run, 1024^3 at 256x1024x1024 per chip.
+    The explicitly requested fused route either compiles holding its
+    kernel, or the v5e VMEM gate refuses it by name — never another route
+    in its place."""
     cfg = SolverConfig(
-        grid=GridConfig(shape=(512, 1024, 1024)),
+        grid=GridConfig(shape=(1024, 1024, 1024)),
         stencil=StencilConfig(kind="7pt"),
         mesh=MeshConfig(shape=(4, 1, 1)),
         precision=Precision.fp32(),
@@ -180,3 +193,19 @@ def test_fused_slab_route_at_cell_e(v5e, route, tb):
         "layout": "framed",
     }
     assert "tpu_custom_call" in _compile_text(fn, x)
+
+
+def test_build_mesh_orders_the_described_host(v5e):
+    """The described 2x2 host's devices carry real v5e coords: (4,1,1)
+    comes out with every chain neighbour one ICI hop apart, (2,2,1) in
+    id order, as before."""
+    from heat3d_tpu.parallel.topology import build_mesh
+
+    devs = v5e.devices[:4]
+    chain = list(build_mesh(MeshConfig(shape=(4, 1, 1)), devs).devices.flat)
+    assert all(
+        sum(abs(p - q) for p, q in zip(a.coords, b.coords)) == 1
+        for a, b in zip(chain, chain[1:])
+    ), [d.coords for d in chain]
+    block = build_mesh(MeshConfig(shape=(2, 2, 1)), devs)
+    assert list(block.devices.flat) == list(devs)
