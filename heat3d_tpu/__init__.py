@@ -14,8 +14,9 @@ idiomatic JAX/XLA/Pallas program:
   the TPU torus (``parallel.topology``)
 - the mpirun driver + time-stepping loop         -> ``jax.distributed`` entrypoint and a
   jit-compiled ``lax.fori_loop`` time loop (``models.heat3d``, ``cli``); the
-  pointer swap is a ping-pong pair carry that XLA compiles to copy-free
-  buffer alternation (``parallel.step._pingpong_loop``)
+  pointer swap is a ping-pong pair the solver keeps across dispatches, each
+  update written into the buffer the field is not in, so no program copies
+  the field (``parallel.step.make_pair_multistep_fn``)
 
 The reference mount is empty in this environment (see SURVEY.md §0); the
 capability spec is BASELINE.json's north star and config matrix, and

@@ -67,8 +67,9 @@ def bench_throughput(
     (matching how the reference class reports its timing: minimum over
     repetitions cancels host jitter). ``steps`` is a floor: the step count
     is auto-calibrated UP until the program's device time swamps the host
-    round trip (the multistep executable takes the trip count dynamically,
-    so calibration costs no recompiles) — without this, small grids finish
+    round trip (the loop takes its pair-trip count dynamically, and the
+    executable of each new write structure is warmed before its timed
+    call, ``HeatSolver3D.warm_up``) — without this, small grids finish
     in a few host round trips and every row is RTT-dominated no matter how
     the arithmetic subtracts it."""
     solver = HeatSolver3D(cfg)
@@ -88,6 +89,7 @@ def bench_throughput(
 
     def _timed(n):
         nonlocal u
+        solver.warm_up(int(n))
         t0 = _time.perf_counter()
         u = solver.run(u, jnp.int32(n))
         force_sync(u)

@@ -484,6 +484,30 @@ def _main(argv: Optional[List[str]] = None) -> int:
     )
 
 
+def _chunks(args, total: int):
+    """The timed loop's dispatch groups, ``(n, want_residual)`` each:
+    advance to the next reporting boundary — a residual point, a
+    checkpoint point, or the end. The final step is always a residual
+    step, so exactly ``total`` updates run — no overshoot."""
+    done = 0
+    while done < total:
+        boundaries = [total]
+        if args.residual_every:
+            boundaries.append(
+                (done // args.residual_every + 1) * args.residual_every
+            )
+        if args.checkpoint and args.checkpoint_every:
+            boundaries.append(
+                (done // args.checkpoint_every + 1) * args.checkpoint_every
+            )
+        nxt = min(boundaries)
+        yield nxt - done, bool(
+            nxt == total
+            or (args.residual_every and nxt % args.residual_every == 0)
+        )
+        done = nxt
+
+
 def _timed_run(args, cfg, solver, u, start_step):
     """Warmup + the timed stepping loop; returns
     ``(u, elapsed, steps_done, residual, warmup_s)`` — ``warmup_s`` is
@@ -492,6 +516,7 @@ def _timed_run(args, cfg, solver, u, start_step):
     # (SURVEY.md §3.5: warmup iterations excluded). The dummy field is built
     # per-shard (zeros callback) so no process ever materializes the full
     # global array — same rule as init_state.
+    total = cfg.run.num_steps
     t_warm = time.perf_counter()
     with obs.get().span("warmup"):
         _dummy = solver.zeros_state
@@ -501,7 +526,11 @@ def _timed_run(args, cfg, solver, u, start_step):
             # advancing
             solver.run_to_convergence(_dummy(), tol=1.0, max_steps=0)
         else:
-            u = solver.run(u, 0)
+            # the fixed-step loop has one executable per write structure
+            # of its step count (HeatSolver3D.warm_up)
+            for n in {n - 1 if want_residual else n
+                      for n, want_residual in _chunks(args, total)}:
+                solver.warm_up(n)
             jax.block_until_ready(solver.step_with_residual(_dummy()))
         force_sync(u)
     warmup_s = time.perf_counter() - t_warm
@@ -522,28 +551,9 @@ def _timed_run(args, cfg, solver, u, start_step):
             u, residual = result.u, result.residual
             done = result.steps
         else:
-            total = cfg.run.num_steps
             done = 0
-            while done < total:
-                # Advance to the next reporting boundary: a residual point,
-                # a checkpoint point, or the end. The final step is always a
-                # residual step, so exactly `total` updates run — no
-                # overshoot.
-                boundaries = [total]
-                if args.residual_every:
-                    boundaries.append(
-                        (done // args.residual_every + 1) * args.residual_every
-                    )
-                if args.checkpoint and args.checkpoint_every:
-                    boundaries.append(
-                        (done // args.checkpoint_every + 1)
-                        * args.checkpoint_every
-                    )
-                nxt = min(min(boundaries), total)
-                n = nxt - done
-                want_residual = nxt == total or (
-                    args.residual_every and nxt % args.residual_every == 0
-                )
+            for n, want_residual in _chunks(args, total):
+                nxt = done + n
                 if want_residual:
                     if n > 1:
                         u = solver.run(u, n - 1)

@@ -23,8 +23,9 @@ from heat3d_tpu.core.config import Precision, SolverConfig
 from heat3d_tpu.parallel.plan import exchange_bytes
 from heat3d_tpu.parallel.step import (
     make_converge_fn,
-    make_multistep_fn,
-    make_step_fn,
+    make_pair_multistep_fn,
+    make_pair_step_fn,
+    pair_plan,
 )
 from heat3d_tpu.parallel.topology import build_mesh, field_sharding
 from heat3d_tpu.utils import checkpoint as ckpt
@@ -181,6 +182,8 @@ class HeatSolver3D:
         # constraints (halo transport, local extents) that convergence-mode
         # runs never exercise.
         self._multistep_cache = None
+        # the ping-pong pair's other buffer (explicit Euler): see _on_pair
+        self._spare = None
         self._carry_res = None
         self._route = None
         self._device_field_cache = {}
@@ -205,14 +208,18 @@ class HeatSolver3D:
             return
         compute = _select_backend(cfg)
         self._compute = compute
-        # One executable per entrypoint; donation makes the time loop
-        # double-buffer in place (SURVEY.md §1 L0 mapping).
+        # Each program takes the field and the solver's spare buffer, both
+        # donated, and writes every update into the buffer the field is
+        # not in: the reference's swap(u_old, u_new) (SURVEY.md §1 L0
+        # mapping) kept across dispatches, so no program copies the field
+        # back into the buffer it came in (_on_pair).
         self._step = jax.jit(
-            make_step_fn(cfg, self.mesh, compute), donate_argnums=0
+            make_pair_step_fn(cfg, self.mesh, compute),
+            donate_argnums=(0, 1), keep_unused=True,
         )
         self._step_res = jax.jit(
-            make_step_fn(cfg, self.mesh, compute, with_residual=True),
-            donate_argnums=0,
+            make_pair_step_fn(cfg, self.mesh, compute, with_residual=True),
+            donate_argnums=(0, 1), keep_unused=True,
         )
         converge = make_converge_fn(cfg, self.mesh, compute)
         self._exchange_bytes = (
@@ -228,14 +235,22 @@ class HeatSolver3D:
                 from heat3d_tpu import timeint
 
                 run = timeint.make_multistep_fn(self.cfg, self.mesh)
+                jitted = jax.jit(run, donate_argnums=0)
             else:
-                run = make_multistep_fn(self.cfg, self.mesh, self._compute)
+                # one executable per (odd, rest) of the plan (pair_plan)
+                run = make_pair_multistep_fn(
+                    self.cfg, self.mesh, self._compute
+                )
+                jitted = jax.jit(
+                    run, donate_argnums=(0, 1),
+                    static_argnames=("odd", "rest"),
+                )
             # the integrators' own programs dispatch no kernel family
             self._route = getattr(
                 run, "route",
                 {"kernel": None, "interpret": False, "layout": None},
             )
-            self._multistep_cache = jax.jit(run, donate_argnums=0)
+            self._multistep_cache = jitted
         return self._multistep_cache
 
     @property
@@ -451,9 +466,62 @@ class HeatSolver3D:
         if nbytes is not None:
             obs.REGISTRY.counter("halo_bytes_total").inc(nbytes)
 
+    def _take_spare(self, u: jax.Array) -> jax.Array:
+        """The pair's spare buffer for field ``u``: the one the solver
+        holds, or, where it holds none of ``u``'s shape, dtype and
+        sharding, a new one filled with zeros
+        (``heat3d_solver_spare_allocs_total``). Its contents are never
+        read."""
+        spare, self._spare = self._spare, None
+        if (
+            spare is None
+            or spare.is_deleted()
+            or spare.shape != u.shape
+            or spare.dtype != u.dtype
+            or not spare.sharding.is_equivalent_to(u.sharding, u.ndim)
+        ):
+            spare = jax.jit(jnp.zeros_like, out_shardings=u.sharding)(u)
+            obs.REGISTRY.counter("solver_spare_allocs_total").inc()
+        return spare
+
+    def _on_pair(self, program: str, fn, u: jax.Array, writes: int,
+                 *args, **static) -> tuple:
+        """Dispatch the pair program ``fn(u, spare, *args, **static)``,
+        which makes ``writes`` out-of-place updates, each into the buffer
+        the field is not in, and returns both buffers in order (then any
+        further outputs). After an odd count the field is in the spare's
+        buffer (``heat3d_solver_pair_swaps_total{program}``: each such
+        dispatch is a full-field copy saved). The buffer the field is not
+        in becomes the next spare. Returns the field and the further
+        outputs."""
+        out = fn(u, self._take_spare(u), *args, **static)
+        swapped = writes % 2
+        self._spare = out[1 - swapped]
+        if swapped:
+            obs.REGISTRY.counter("solver_pair_swaps_total").inc(
+                program=program
+            )
+        return (out[swapped], *out[2:])
+
+    def warm_up(self, num_steps: int) -> None:
+        """Dispatch once, on a zero field, the loop program that
+        ``run(u, num_steps)`` takes, with the fewest updates that take it,
+        so that a timed ``run`` compiles nothing. Under explicit Euler the
+        loop has one executable per ``(odd, rest)`` of its plan
+        (``parallel.step.pair_plan``); the integrators' loops take the
+        count traced, so their one executable is warmed with no update."""
+        n = 0
+        if self.cfg.integrator == "explicit-euler":
+            _, odd, rest = pair_plan(num_steps, self.cfg.time_blocking)
+            n = odd * self.cfg.time_blocking + rest
+        jax.block_until_ready(self.run(self.zeros_state(), n))
+
     def step(self, u: jax.Array) -> jax.Array:
         with obs.get().span("solver.dispatch", program="step", steps=1):
-            u = self._step(u)
+            if self.cfg.integrator != "explicit-euler":
+                u = self._step(u)
+            else:
+                (u,) = self._on_pair("step", self._step, u, 1)
         self._dispatched("step", 1)
         return u
 
@@ -461,7 +529,10 @@ class HeatSolver3D:
         with obs.get().span(
             "solver.dispatch", program="step_residual", steps=1
         ):
-            out = self._step_res(u)
+            if self.cfg.integrator != "explicit-euler":
+                out = self._step_res(u)
+            else:
+                out = self._on_pair("step_residual", self._step_res, u, 1)
         self._dispatched("step_residual", 1)
         return out
 
@@ -492,7 +563,10 @@ class HeatSolver3D:
 
     def run(self, u: jax.Array, num_steps: int) -> jax.Array:
         """num_steps updates as one device-side loop (benchmark mode: no
-        mid-loop host syncs — SURVEY.md §3.3). Under
+        mid-loop host syncs — SURVEY.md §3.3). Under explicit Euler the
+        loop runs over the field and the solver's spare buffer, and the
+        field comes back in whichever of the two the last update wrote
+        (``_on_pair``); ``u`` is donated, as in every program. Under
         ``integrator='implicit-cg'`` each update is a CG solve; the last
         solve's iteration count and relative residual come back with the
         field and land in the ledger as a ``cg_solve`` event (the one
@@ -500,7 +574,14 @@ class HeatSolver3D:
         field anyway)."""
         steps = int(num_steps)
         with obs.get().span("solver.dispatch", program="run", steps=steps):
-            out = self._multistep(u, jnp.int32(steps))
+            if self.cfg.integrator != "explicit-euler":
+                out = self._multistep(u, jnp.int32(steps))
+            else:
+                trips, odd, rest = pair_plan(steps, self.cfg.time_blocking)
+                (out,) = self._on_pair(
+                    "run", self._multistep, u, 2 * trips + odd + rest,
+                    jnp.int32(trips), odd=odd, rest=rest,
+                )
         self._dispatched("run", steps)
         if self.cfg.integrator == "implicit-cg":
             u, iters, relres = out

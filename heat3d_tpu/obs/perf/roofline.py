@@ -146,15 +146,15 @@ def step_cost_fields(solver) -> Dict[str, Optional[float]]:
     aval = jax.ShapeDtypeStruct(
         cfg.padded_shape, solver.storage_dtype, sharding=solver.sharding
     )
-    if cfg.time_blocking > 1:
-        from heat3d_tpu.parallel.step import make_superstep_fn
-
-        program = jax.jit(
-            make_superstep_fn(cfg, solver.mesh, solver._compute)
-        )
-        updates = cfg.time_blocking
-    else:
+    if cfg.integrator != "explicit-euler":
+        # the integrators' own one-update program
         program, updates = solver._step, 1
+    else:
+        from heat3d_tpu.parallel.step import make_step_fn, make_superstep_fn
+
+        build = make_superstep_fn if cfg.time_blocking > 1 else make_step_fn
+        program = jax.jit(build(cfg, solver.mesh, solver._compute))
+        updates = cfg.time_blocking
     compiled = program.lower(aval).compile()
     flops, bytes_ = extract_cost(compiled.cost_analysis())
     # Raw-vs-effective honesty for temporally-blocked supersteps: the

@@ -1172,6 +1172,33 @@ def make_step_fn(
     )
 
 
+def make_pair_step_fn(
+    cfg: SolverConfig,
+    mesh: Mesh,
+    compute_padded: LocalCompute = apply_taps_padded,
+    with_residual: bool = False,
+):
+    """Build one update over the ping-pong pair, ``(u, spare) -> (u,
+    u_new)`` (or ``(u, u_new, residual_sumsq)``): ``make_step_fn``'s
+    update written into the spare's buffer, whose contents are never
+    read, and both buffers returned in order. A caller that donates both
+    (``HeatSolver3D.step``, ``step_with_residual``; jit them with
+    ``keep_unused=True``, since the spare is only a buffer) pays no copy:
+    ``u`` stays in its own buffer, alive for the residual's reduction,
+    which reads both. The program is named ``wrapper``, as the step
+    builders' ``scoped`` programs are (jitted: ``jit_wrapper``), the name
+    the benchmark's ``solver.residual_share`` finds the residual step by."""
+    step = make_step_fn(cfg, mesh, compute_padded, with_residual=with_residual)
+
+    def wrapper(u, spare):
+        del spare  # its buffer takes the update
+        out = step(u)
+        return (u, *out) if with_residual else (u, out)
+
+    wrapper.route = step.route
+    return wrapper
+
+
 def make_superstep_fn(
     cfg: SolverConfig,
     mesh: Mesh,
@@ -1374,8 +1401,10 @@ def make_superstep_fn(
     )
 
 
-def _pingpong_loop(step_fn, u: jax.Array, count) -> jax.Array:
-    """Apply ``step_fn`` ``count`` times via a two-buffer pair carry.
+def _pingpong_loop(step_fn, a: jax.Array, b: jax.Array, trips):
+    """Apply ``step_fn`` ``2 * trips`` times over the ping-pong pair
+    ``(a, b)``, the field starting in ``a``; returns the pair in buffer
+    order, the field back in ``a``.
 
     A single-buffer ``fori_loop(0, n, lambda _, v: step_fn(v), u)`` forces
     XLA to insert a full-volume copy every iteration: the while carry is a
@@ -1387,19 +1416,76 @@ def _pingpong_loop(step_fn, u: jax.Array, count) -> jax.Array:
     assignment elides the copy entirely (verified: the compiled pair-loop
     body is two custom-calls, zero copies). This is the reference's
     ``swap(u_old, u_new)`` pointer swap (SURVEY.md §1 L0) done the XLA way.
+    ``b``'s contents are never read."""
 
-    The scratch buffer is zero-initialized once per call (a write-only
-    broadcast, amortized over the run); the odd trailing iteration runs in
-    a ≤1-trip loop that still pays one copy."""
-
-    def body2(_, uv):
-        a, b = uv
+    def body2(_, ab):
+        a, b = ab
         b = step_fn(a)
         a = step_fn(b)
         return (a, b)
 
-    u, _ = lax.fori_loop(0, count // 2, body2, (u, jnp.zeros_like(u)))
-    return lax.fori_loop(0, count % 2, lambda _, v: step_fn(v), u)
+    return lax.fori_loop(0, trips, body2, (a, b))
+
+
+def _alternate(step_fns, a: jax.Array, b: jax.Array):
+    """Apply each of ``step_fns`` in turn, out of place, the field
+    starting in ``a`` and moving to the other buffer at every write;
+    returns the pair in buffer order (the field in ``b`` after an odd
+    number of writes). The count is static, so every write has a dead
+    buffer to go to and no write pays a copy."""
+    pair = [a, b]
+    for i, fn in enumerate(step_fns):
+        pair[(i + 1) % 2] = fn(pair[i % 2])
+    return tuple(pair)
+
+
+def pair_plan(num_steps: int, k: int):
+    """``(trips, odd, rest)`` of :func:`make_pair_multistep_fn` for
+    ``num_steps`` updates at ``time_blocking`` k: ``2 * trips + odd`` hot
+    updates, then ``rest`` single steps. The field ends in the spare's
+    buffer when ``odd + rest`` is odd."""
+    if num_steps < 0:
+        raise ValueError(f"num_steps must be >= 0, got {num_steps}")
+    hot, rest = divmod(num_steps, k)
+    trips, odd = divmod(hot, 2)
+    return trips, odd, rest
+
+
+def make_pair_multistep_fn(
+    cfg: SolverConfig,
+    mesh: Mesh,
+    compute_padded: LocalCompute = apply_taps_padded,
+):
+    """Build the fixed-step loop over the ping-pong pair,
+    ``(a, b, trips, odd, rest) -> (a, b)``: the field starts in ``a``,
+    ``b`` is a spare buffer of the same shape whose contents are never
+    read, and both are returned in buffer order. The loop makes ``2 *
+    trips`` hot updates (``trips`` traced), then ``odd`` (0 or 1) more,
+    then ``rest`` single steps; ``odd`` and ``rest`` are static. A hot
+    update is the k-update superstep at ``cfg.time_blocking == k > 1``
+    (1/k the exchanges) and the single step at k = 1, so ``num_steps``
+    updates take ``pair_plan(num_steps, k)``.
+    Every update writes into the buffer the field is not in, so the field
+    ends in ``b`` after an odd number ``2 * trips + odd + rest`` of writes
+    and in ``a`` otherwise, and the program copies nothing: the caller
+    keeps both buffers and donates both (``HeatSolver3D.run``).
+
+    The program is named ``runk`` at k > 1 and ``run`` at k = 1 (jitted:
+    ``jit_runk`` / ``jit_run``), names the benchmark's trace readers
+    find it by. ``hot`` and ``step`` are the two update functions."""
+    step = make_step_fn(cfg, mesh, compute_padded, with_residual=False)
+    k = cfg.time_blocking
+    # the superstep carries the loop: its route is the run's
+    hot = make_superstep_fn(cfg, mesh, compute_padded) if k > 1 else step
+
+    def run(a, b, trips, odd, rest):
+        a, b = _pingpong_loop(hot, a, b, trips)
+        return _alternate((hot,) * odd + (step,) * rest, a, b)
+
+    run.__name__ = run.__qualname__ = "runk" if k > 1 else "run"
+    run.route = hot.route
+    run.hot, run.step = hot, step
+    return run
 
 
 def make_multistep_fn(
@@ -1407,35 +1493,30 @@ def make_multistep_fn(
     mesh: Mesh,
     compute_padded: LocalCompute = apply_taps_padded,
 ):
-    """Build ``(u, num_steps) -> u_after`` with the fori_loop *inside* the
-    compiled program. num_steps is a traced scalar so one executable serves
+    """Build ``(u, num_steps) -> u_after`` with the loop *inside* the
+    compiled program and ``num_steps`` traced, so one executable serves
     any step count (the reference recompiles nothing either — its loop is
     host-side; ours is device-side, SURVEY.md §3.2 TPU mapping).
 
-    With cfg.time_blocking == k > 1, the loop advances in k-update
-    supersteps (1/k the exchanges) plus trailing single steps for the
-    remainder. Both loops use the ping-pong pair carry (_pingpong_loop) so
-    the stencil sweeps alternate between two field buffers copy-free."""
-    step = make_step_fn(cfg, mesh, compute_padded, with_residual=False)
-
-    if cfg.time_blocking > 1:
-        k = cfg.time_blocking
-        superstep = make_superstep_fn(cfg, mesh, compute_padded)
-
-        def runk(u, num_steps):
-            u = _pingpong_loop(superstep, u, num_steps // k)
-            # remainder is <= k-1 trips: a plain loop (one carry copy per
-            # trip) beats materializing another full-volume scratch
-            return lax.fori_loop(0, num_steps % k, lambda _, v: step(v), u)
-
-        # the superstep carries the loop: its route is the run's
-        runk.route = superstep.route
-        return runk
+    A thin use of :func:`make_pair_multistep_fn`: its pair loop on a
+    zero-filled scratch buffer for the even part of the hot updates, then
+    the traced tail — an odd superstep and the ``num_steps % k`` single
+    steps — each in a ≤1- or ≤(k-1)-trip loop on the one buffer, which
+    XLA copies back once a trip. ``HeatSolver3D`` runs the pair form
+    itself, with the parity static and both buffers its own, and pays no
+    copy."""
+    pair = make_pair_multistep_fn(cfg, mesh, compute_padded)
+    k = cfg.time_blocking
 
     def run(u, num_steps):
-        return _pingpong_loop(step, u, num_steps)
+        hot_updates = num_steps // k
+        u, _ = pair(u, jnp.zeros_like(u), hot_updates // 2, 0, 0)
+        u = lax.fori_loop(0, hot_updates % 2, lambda _, v: pair.hot(v), u)
+        if k > 1:
+            u = lax.fori_loop(0, num_steps % k, lambda _, v: pair.step(v), u)
+        return u
 
-    run.route = step.route
+    run.route = pair.route
     return run
 
 

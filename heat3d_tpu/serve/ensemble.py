@@ -24,7 +24,7 @@ parameters differently at codegen:
   differently.
 - ``bind='baked'``: per-member coefficients are compile-time constants,
   and each member runs ITS OWN executable — literally the solo
-  ``make_multistep_fn`` program over the spatial mesh, driven through
+  ``make_pair_multistep_fn`` program over the spatial mesh, driven through
   the ensemble's batched state layout. Bitwise-identical to B
   independent :class:`HeatSolver3D` runs BY CONSTRUCTION (the tier-1
   acceptance proof; stacking members into one XLA module was measured
@@ -80,6 +80,7 @@ from heat3d_tpu.parallel.step import (
     _fill_mid_ghosts,
     _pin_padding,
     _solver_taps,
+    pair_plan,
 )
 from heat3d_tpu.serve.scenario import ScenarioBatch
 from heat3d_tpu.utils import checkpoint as ckpt
@@ -479,10 +480,14 @@ class EnsembleSolver:
         # ---- baked binding: one SOLO executable per member --------------
         # The whole point of this binding is bitwise identity with B
         # independent HeatSolver3D runs, so each member gets the EXACT
-        # solo program — make_multistep_fn over the spatial mesh, jitted
-        # with the same donation — dispatched from the batched state
-        # (slice member in, run, stack back out; pure data movement).
-        from heat3d_tpu.parallel.step import make_multistep_fn, make_step_fn
+        # solo programs — the pair loop and pair residual step over the
+        # spatial mesh, jitted as the solver jits them — dispatched from
+        # the batched state (slice member in, run with a zero spare, stack
+        # back out; pure data movement).
+        from heat3d_tpu.parallel.step import (
+            make_pair_multistep_fn,
+            make_pair_step_fn,
+        )
 
         member_cfgs = [self.batch.member_config(m) for m in range(B)]
         space_dev = np.asarray(self.mesh.devices)[0]
@@ -492,18 +497,18 @@ class EnsembleSolver:
         )
         self._member_run = [
             jax.jit(
-                make_multistep_fn(c, self._space_mesh, apply_taps_padded),
-                donate_argnums=0,
+                make_pair_multistep_fn(c, self._space_mesh, apply_taps_padded),
+                donate_argnums=(0, 1), static_argnames=("odd", "rest"),
             )
             for c in member_cfgs
         ]
         self._member_step_res = [
             jax.jit(
-                make_step_fn(
+                make_pair_step_fn(
                     c, self._space_mesh, apply_taps_padded,
                     with_residual=True,
                 ),
-                donate_argnums=0,
+                donate_argnums=(0, 1), keep_unused=True,
             )
             for c in member_cfgs
         ]
@@ -631,9 +636,14 @@ class EnsembleSolver:
             )
             return self._run_p(u, W, C, BCV, b_dev)
         outs = []
+        k = self.cfg.time_blocking
         for m in range(self.B):
             um = jax.device_put(u[m], self._space_sharding)
-            outs.append(self._member_run[m](um, jnp.int32(int(budgets[m]))))
+            trips, odd, rest = pair_plan(int(budgets[m]), k)
+            pair = self._member_run[m](
+                um, jnp.zeros_like(um), jnp.int32(trips), odd=odd, rest=rest
+            )
+            outs.append(pair[(odd + rest) % 2])
         return self._stack(*outs)
 
     def step_with_residual(self, u: jax.Array):
@@ -654,7 +664,7 @@ class EnsembleSolver:
         outs, rs = [], []
         for m in range(self.B):
             um = jax.device_put(u[m], self._space_sharding)
-            new, r = self._member_step_res[m](um)
+            _, new, r = self._member_step_res[m](um, jnp.zeros_like(um))
             outs.append(new)
             rs.append(jnp.asarray(r))
         return self._stack(*outs), jnp.stack(rs)

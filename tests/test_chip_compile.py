@@ -209,3 +209,97 @@ def test_build_mesh_orders_the_described_host(v5e):
     ), [d.coords for d in chain]
     block = build_mesh(MeshConfig(shape=(2, 2, 1)), devs)
     assert list(block.devices.flat) == list(devs)
+
+
+def full_volume_copies(text, shape):
+    """Copies of a whole ``shape`` fp32 block in HBM in compiled HLO text:
+    ``copy`` and ``copy-start`` whose destination is in the default memory
+    space (a layout without ``S(n)``; an ``S(1)`` destination is a
+    prefetch into on-chip memory)."""
+    dims = r"f32\[%s\]" % ",".join(map(str, shape))
+    copies = re.findall(r"= %s(\{[^}]*\}) copy\(" % dims, text)
+    starts = re.findall(r"= \(%s(\{[^}]*\}), [^\n]* copy-start\(" % dims, text)
+    return sum("S(" not in layout for layout in copies + starts)
+
+
+# n = 9, 50, 51 at tb=2: (odd, rest) = (0, 1), (1, 0), (1, 1)
+PAIR_PROGRAMS = ["run-9", "run-50", "run-51", "step", "residual"]
+
+
+def _mesh_field(topo, cfg):
+    from heat3d_tpu.parallel.topology import build_mesh
+
+    mesh = build_mesh(cfg.mesh, topo.devices[: cfg.mesh.num_devices])
+    return mesh, jax.ShapeDtypeStruct(
+        cfg.grid.shape, jnp.float32,
+        sharding=NamedSharding(mesh, P(*cfg.mesh.axis_names)),
+    )
+
+
+def _pair_program(topo, cfg, program):
+    """A solver program as ``HeatSolver3D`` jits it, lowered for the
+    described chips: the pair loop for ``run(u, n)`` or a pair step."""
+    from heat3d_tpu.parallel.step import (
+        make_pair_multistep_fn,
+        make_pair_step_fn,
+    )
+
+    mesh, x = _mesh_field(topo, cfg)
+    if program.startswith("run-"):
+        hot, rest = divmod(int(program[4:]), cfg.time_blocking)
+        run = jax.jit(make_pair_multistep_fn(cfg, mesh),
+                      donate_argnums=(0, 1), static_argnames=("odd", "rest"))
+        return run.lower(x, x, jax.ShapeDtypeStruct((), jnp.int32),
+                         odd=hot % 2, rest=rest)
+    step = make_pair_step_fn(cfg, mesh, with_residual=program == "residual")
+    return jax.jit(step, donate_argnums=(0, 1), keep_unused=True).lower(x, x)
+
+
+@pytest.mark.parametrize("program", PAIR_PROGRAMS)
+def test_solver_programs_copy_no_field_at_1024(v5e, program):
+    """The one-chip cells' programs, 1024^3 at tb=2: the fixed-step loop
+    at each (odd, rest) and both step programs write every update into
+    the buffer the field is not in, so the compiled program holds no
+    full-volume copy and no temporary field; the pair comes back
+    aliased to the two donated inputs."""
+    cfg = SolverConfig(grid=GridConfig.cube(1024), time_blocking=2,
+                       backend="auto")
+    compiled = _pair_program(v5e, cfg, program).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert full_volume_copies(text, (1024,) * 3) == 0
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    assert "{0}: (0, {}, may-alias), {1}: (1, {}, may-alias)" in text
+
+
+def test_single_buffer_loop_copies_the_field(v5e):
+    """The control: ``make_multistep_fn``'s one-buffer tail (the odd
+    superstep and the remainder step, in loops of at most one trip)
+    copies the field, and the count above sees it."""
+    from heat3d_tpu.parallel.step import make_multistep_fn
+
+    cfg = SolverConfig(grid=GridConfig.cube(1024), time_blocking=2,
+                       backend="auto")
+    mesh, x = _mesh_field(v5e, cfg)
+    text = (
+        jax.jit(make_multistep_fn(cfg, mesh), donate_argnums=0)
+        .lower(x, jax.ShapeDtypeStruct((), jnp.int32))
+        .compile()
+        .as_text()
+    )
+    assert full_volume_copies(text, (1024,) * 3) >= 1
+
+
+@pytest.mark.parametrize("program", ["run-51", "residual"])
+@pytest.mark.parametrize("mesh,grid", [
+    ((2, 2, 1), (2048, 2048, 1024)), ((4, 1, 1), (1024, 1024, 1024)),
+], ids=["2x2x1", "4x1x1"])
+def test_four_chip_solver_programs_copy_no_field(v5e, mesh, grid, program):
+    """The four-chip cells' loop at an odd superstep count with a
+    remainder step, and their residual step: no full-volume copy of the
+    local block on the faces-direct route."""
+    cfg = SolverConfig(grid=GridConfig(shape=grid), mesh=MeshConfig(shape=mesh),
+                       time_blocking=2, backend="auto")
+    text = _pair_program(v5e, cfg, program).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert full_volume_copies(text, cfg.local_shape) == 0

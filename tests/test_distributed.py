@@ -516,3 +516,52 @@ def test_multistep_pair_loop_compiles_copy_free():
         assert copies.get(c, 0) == 0, (
             f"full-volume copy reappeared in pair-loop body {c}: {copies}"
         )
+
+
+@pytest.mark.skipif(
+    jax.devices()[0].platform != "tpu", reason="needs the TPU compiler"
+)
+def test_solver_programs_dispatched_copy_no_field():
+    """Every program ``HeatSolver3D`` dispatches for ``run(u, 9)``,
+    ``run(u, 25)``, ``run(u, 51)`` (tb=2: an even and an odd superstep
+    count, each with a remainder step), ``step_with_residual`` and
+    ``step`` compiles with no full-volume copy of the field: the solver
+    keeps the ping-pong pair across dispatches, so no program copies the
+    field back into the buffer it came in. The programs are recorded as
+    the solver calls them, by the shapes and static arguments of each
+    call, and compiled again from those."""
+    from heat3d_tpu.models.heat3d import HeatSolver3D
+    from tests.test_chip_compile import full_volume_copies
+
+    n = 512
+    cfg = SolverConfig(grid=GridConfig.cube(n), time_blocking=2)
+    solver = HeatSolver3D(cfg)
+    dispatched = []
+
+    def recorded(program):
+        def call(*args, **static):
+            avals = [
+                jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+                if isinstance(a, jax.Array) else a
+                for a in args
+            ]
+            dispatched.append((program, avals, static))
+            return program(*args, **static)
+
+        return call
+
+    solver._multistep_cache = recorded(solver._multistep)
+    solver._step = recorded(solver._step)
+    solver._step_res = recorded(solver._step_res)
+    u = solver.init_state("hot-cube")
+    for steps in (9, 25, 51):
+        u = solver.run(u, steps)
+    u, _ = solver.step_with_residual(u)
+    jax.block_until_ready(solver.step(u))
+    assert len(dispatched) == 5
+    for program, avals, static in dispatched:
+        text = program.lower(*avals, **static).compile().as_text()
+        assert "tpu_custom_call" in text
+        assert full_volume_copies(text, (n, n, n)) == 0, (
+            text.splitlines()[0][:80], static
+        )
