@@ -196,8 +196,8 @@ def one_chip(dev) -> None:
 
 def four_chips(devs) -> None:
     """Cell (d) at full size, then sharded runs against one-chip runs of
-    the same problem on ``devs[0]``, then the fused slab routes. Fields
-    stay on the devices: the comparison runs there too."""
+    the same problem on ``devs[0]``. Fields stay on the devices: the
+    comparison runs there too."""
     import jax
     import jax.numpy as jnp
 
@@ -233,7 +233,7 @@ def four_chips(devs) -> None:
         jax.block_until_ready(u)
         route = solver.route
         say(f"  {cfg.mesh.shape} halo={cfg.halo} overlap={cfg.overlap} "
-            f"fused_rdma={cfg.fused_rdma} tb={cfg.time_blocking}: "
+            f"tb={cfg.time_blocking}: "
             f"route={route} {steps} steps, "
             f"wall_s={time.perf_counter() - t0:.3f}")
         check(not route["interpret"], f"interpret mode on: {route}")
@@ -241,46 +241,28 @@ def four_chips(devs) -> None:
         gc.collect()
         return u, route
 
-    def compare(label, argv, meshes, steps, routes=((),)):
+    def compare(label, argv, meshes, steps):
         with watchdog(f"{label} one chip", 300):
             want, _ = field(argv + ["--mesh", "1", "1", "1"], devs[:1], steps)
         for mesh in meshes:
-            for extra in routes:
-                name = f"{label} mesh={mesh} {' '.join(extra)}"
-                try:
-                    with watchdog(name, 300):
-                        got, route = field(
-                            argv + ["--mesh", *map(str, mesh), *extra],
-                            devs, steps)
-                except ValueError as e:
-                    check("VMEM" in str(e),
-                          f"{name}: refused without naming VMEM: {e}")
-                    say(f"{name}: refused: {e}")
-                    continue
-                if "--halo" in extra:
-                    check_kernel_route(route, name, "fused_dma", None)
-                if "--fused-rdma" in extra:
-                    check_kernel_route(route, name, "fused_rdma", None)
-                err, n_bad, finite = errors(
-                    got, jax.device_put(want, got.sharding))
-                say(f"{name}: max_abs_err vs one chip = {float(err):.3e}, "
-                    f"cells outside rtol={SHARDED_RTOL} atol={SHARDED_ATOL}"
-                    f": {int(n_bad)}")
-                check(bool(finite), f"{name}: field not finite")
-                check(int(n_bad) == 0, f"{name}: {int(n_bad)} cells differ")
-                del got
+            name = f"{label} mesh={mesh}"
+            with watchdog(name, 300):
+                got, _ = field(argv + ["--mesh", *map(str, mesh)], devs,
+                               steps)
+            err, n_bad, finite = errors(
+                got, jax.device_put(want, got.sharding))
+            say(f"{name}: max_abs_err vs one chip = {float(err):.3e}, "
+                f"cells outside rtol={SHARDED_RTOL} atol={SHARDED_ATOL}"
+                f": {int(n_bad)}")
+            check(bool(finite), f"{name}: field not finite")
+            check(int(n_bad) == 0, f"{name}: {int(n_bad)} cells differ")
+            del got
         del want
         gc.collect()
 
     base = ["--stencil", "7pt", "--dtype", "fp32", "--time-blocking", "2"]
     compare("sharded 1024^3", ["--grid", "1024"] + base,
             [(2, 2, 1), (4, 1, 1)], steps=6)
-    compare("fused slab 512x1024x1024",
-            ["--grid", "512", "1024", "1024"] + base, [(4, 1, 1)], steps=6,
-            routes=[r + ("--time-blocking", tb)
-                    for r in (("--halo", "dma", "--overlap"),
-                              ("--fused-rdma", "on"))
-                    for tb in ("1", "2")])
 
 
 def main(argv=None) -> int:

@@ -14,7 +14,7 @@ baseline suppression, ``--json``) and fingerprint on
 text, so baselines survive jax upgrades.
 
 This is the certification layer the halo-path refactors (persistent
-exchange plans, in-kernel RDMA — ROADMAP) land against: a change that
+exchange plans — ROADMAP) land against: a change that
 desynchronizes the exchange topology, starves a tap chain of ghost
 width, leaks a dtype, or breaks the memory contract reds this lint on
 CPU, before any pod session.

@@ -27,10 +27,10 @@ static models promise:
   chain), not a precise audit.
 - **ANL904** — (info) the joined numbers per compiled case, so the
   roofline's inputs are visible from the lint output.
-- **ANL905** — fused-DMA budget adjudication: the generation-aware gate
-  budget (``ops.stencil_dma_fused.chip_vmem_budget_for``) must sit
-  within every known generation's VMEM capacity — the machine-checked
-  resolution of the old standing ANL305 warning.
+- **ANL905** — VMEM budget adjudication: the generation-aware budget
+  (``analysis.vmem.chip_vmem_budget_for``) must sit within every known
+  generation's VMEM capacity — the machine-checked resolution of the old
+  standing ANL305 warning.
 """
 
 from __future__ import annotations
@@ -208,19 +208,19 @@ def check_gate_adjudication(
     live_budget=None,
     live_generation=None,
 ) -> List[Finding]:
-    """ANL905: the fused-DMA gate's VMEM budget vs chip capacity, from
+    """ANL905: the resolved VMEM budget vs chip capacity, from
     two sides. (a) Per generation, ``chip_vmem_budget_for`` vs the
     capacity table — tautological today (the function reads the table)
     but a tripwire against future edits that decouple them. (b) The
     LIVE resolution, ``HEAT3D_VMEM_BYTES`` override included: an
-    operator override above the current part's capacity makes the gate
-    admit kernels Mosaic cannot allocate — the one mis-set knob the old
+    operator override above the current part's capacity admits kernels
+    Mosaic cannot allocate — the one mis-set knob the old
     ANL305 warning existed to prevent, now adjudicated instead of
     warned about. Parameterized for the seeded-violation tests."""
-    from heat3d_tpu.ops import stencil_dma_fused as dma
+    from heat3d_tpu.analysis import vmem
 
-    table = chip_table if chip_table is not None else dma.CHIP_VMEM_BYTES
-    budget_for = budget_for or dma.chip_vmem_budget_for
+    table = chip_table if chip_table is not None else vmem.CHIP_VMEM_BYTES
+    budget_for = budget_for or vmem.chip_vmem_budget_for
     out: List[Finding] = []
     for gen, cap in sorted(table.items()):
         budget = budget_for(gen)
@@ -228,13 +228,13 @@ def check_gate_adjudication(
             out.append(
                 _finding(
                     "gate",
-                    "heat3d_tpu/ops/stencil_dma_fused.py",
+                    "heat3d_tpu/analysis/vmem.py",
                     "ANL905",
                     ERROR,
-                    f"fused-dma-budget:{gen}",
-                    f"fused-DMA gate resolves {budget / MIB:.0f} MiB on "
-                    f"{gen}, which has {cap / MIB:.0f} MiB VMEM: the "
-                    "gate admits kernels Mosaic cannot allocate there "
+                    f"vmem-budget:{gen}",
+                    f"the VMEM budget resolves {budget / MIB:.0f} MiB on "
+                    f"{gen}, which has {cap / MIB:.0f} MiB VMEM: it "
+                    "admits kernels Mosaic cannot allocate there "
                     "(generation table drifted)",
                 )
             )
@@ -245,22 +245,22 @@ def check_gate_adjudication(
     if live_generation in table:
         resolved = (
             live_budget if live_budget is not None
-            else dma._chip_vmem_budget()
+            else vmem._chip_vmem_budget()
         )
         cap = table[live_generation]
         if resolved > cap:
             out.append(
                 _finding(
                     "gate",
-                    "heat3d_tpu/ops/stencil_dma_fused.py",
+                    "heat3d_tpu/analysis/vmem.py",
                     "ANL905",
                     ERROR,
-                    "fused-dma-budget:live",
-                    f"the LIVE fused-DMA budget resolution is "
+                    "vmem-budget:live",
+                    f"the LIVE VMEM budget resolution is "
                     f"{resolved / MIB:.0f} MiB on this "
                     f"{live_generation} ({cap / MIB:.0f} MiB VMEM) — "
                     "HEAT3D_VMEM_BYTES is set above the part's "
-                    "capacity, so the gate admits unallocatable "
+                    "capacity, so it admits unallocatable "
                     "kernels; unset it or lower it",
                 )
             )

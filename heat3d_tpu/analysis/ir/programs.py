@@ -125,9 +125,6 @@ def _case_key(cfg, kind: str) -> str:
         # plan-mode key leg only when non-default, so every fingerprint
         # minted before the knob existed stays stable
         bits.append(cfg.halo_plan)
-    if getattr(cfg, "fused_rdma", "off") != "off":
-        # fused-RDMA leg only when non-default (off), same stability rule
-        bits.append(f"fr-{cfg.fused_rdma}")
     if cfg.overlap:
         bits.append("overlap")
     bits.append(kind)
@@ -380,28 +377,6 @@ def judged_matrix(num_devices: Optional[int] = None) -> List[ProgramCase]:
             ),
             {
                 "time_blocking": (1, 3),
-                "halo_plan": ("monolithic", "partitioned"),
-            },
-            compile_keys,
-        )
-    # the fused in-kernel RDMA route arm (PR 20): fused_rdma='on'
-    # programs certify beside the classic path on the route's x-slab
-    # scope. On the analysis host the route's env gate stands the
-    # Mosaic kernel down and the dispatcher's jnp plan-exchange
-    # stand-in traces (the kernel itself certifies in the kernel-tier
-    # matrix, lint --kernel); this arm pins the knob's config surface
-    # and its partitioned-plan composition through the same judged
-    # collective/ghost invariants.
-    if n >= 4:
-        cases += _solver_cases(
-            SolverConfig(
-                grid=GridConfig.cube(_GRID),
-                mesh=MeshConfig(shape=(4, 1, 1)),
-                backend="jnp",
-                fused_rdma="on",
-            ),
-            {
-                "time_blocking": (1, 2),
                 "halo_plan": ("monolithic", "partitioned"),
             },
             compile_keys,

@@ -3,8 +3,7 @@
 
 The AST tier (PR 6) audits source, the IR tier (PR 9) audits the traced
 programs — and both documented the same blind spot: ``pallas_call``
-bodies were opaque, so every in-kernel DMA (the slab exchanges, the
-fused streaming overlap, the upcoming in-kernel RDMA superstep) was
+bodies were opaque, so every in-kernel DMA (the slab exchanges) was
 certified only by interpret-tier *value* parity, which proves values
 but not schedules. This package closes that: every repo Pallas kernel
 body is traced to its jaxpr on CPU (:mod:`.programs` — kernel functions
@@ -23,8 +22,7 @@ checker families certify the schedule —
 - :mod:`.remote` (ANL1031-1033): every ``make_async_remote_copy``
   device target realizes the ±1 neighbor bijection
   ``parallel.halo.shift_perm`` builds, and plan-driven exchanges
-  realize the ``ExchangePlan`` axis schedule — the standing gate the
-  fused in-kernel-RDMA arc lands against.
+  realize the ``ExchangePlan`` axis schedule.
 
 Findings report through the shared framework (severity policy, inline +
 baseline suppression, ``--json``) and fingerprint on
@@ -101,7 +99,7 @@ def run_kernel_checkers(root: str, names: List[str]) -> List[Finding]:
                     f"jax initialized with {devices} device(s) before the "
                     f"kernel lint could force its {want}-device CPU mesh "
                     "(HEAT3D_KERNEL_LINT_DEVICES): the judged matrix lost "
-                    "its DMA exchange rings and fused-overlap kernels, so "
+                    "its DMA exchange rings, so "
                     "the DMA/remote families certified almost nothing "
                     "this run — run `heat3d lint --kernel` in a fresh "
                     "process"

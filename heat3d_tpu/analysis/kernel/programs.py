@@ -4,9 +4,9 @@ Every case is a REAL ``pallas_call`` the production dispatch can build —
 the streaming stencil ring (``_stream``), the fused two-update and
 k-update supersteps (``_stream2`` / ``_streamk`` at k = 2..4), the
 direct in-kernel-BC kernels (single- and multi-chunk, mehrstellen q-ring
-included), both DMA halo-exchange kernels (width-1 zero-staging and the
-width-k slab path, plus the plan-driven multi-axis composition), and the
-fused DMA-overlap step/superstep — traced to a closed jaxpr on CPU
+included), and both DMA halo-exchange kernels (width-1 zero-staging and
+the width-k slab path, plus the plan-driven multi-axis composition) —
+traced to a closed jaxpr on CPU
 (kernel bodies over ``Ref``s trace without a TPU; shapes mirror the
 interpret-tier parity matrix in tests/multidevice_checks.py) and handed
 to the checkers as :class:`KernelCase` records.
@@ -149,7 +149,7 @@ def ring_ctxs(axes: Sequence[Tuple[str, int]]) -> Tuple[Dict, ...]:
 
 # local shapes: small enough to simulate in milliseconds, large enough
 # that every ring primes fully and the deep-tb epilogues are distinct
-# phases (nx >= 2k + 2 for streamk, nx >= 4 for the fused superstep)
+# phases (nx >= 2k + 2 for streamk)
 _SHAPE = (8, 8, 128)
 
 
@@ -355,9 +355,7 @@ def _dma_axis_case(width: int, size: int, periodic: bool) -> KernelCase:
 def _dma_planned_case() -> Tuple[KernelCase, Any]:
     """The plan-driven multi-axis DMA composition on a (2,2,1) block
     mesh: the traced per-axis kernel sequence must realize the
-    ``ExchangePlan``'s axis schedule (the corner-propagation order) —
-    this is the standing gate the fused in-kernel-RDMA superstep arc
-    lands against (ROADMAP)."""
+    ``ExchangePlan``'s axis schedule (the corner-propagation order)."""
     from heat3d_tpu.core.config import BoundaryCondition, MeshConfig
     from heat3d_tpu.parallel.plan import build_plan
 
@@ -400,141 +398,6 @@ def _dma_planned_case() -> Tuple[KernelCase, Any]:
     return case, plan
 
 
-def _fused_case(
-    kind: str, periodic: bool, superstep: bool, mesh_axes=("x",), tag="",
-    shape=None,
-) -> KernelCase:
-    size = 4
-    names = tuple(mesh_axes)
-    mesh_shape = (size,) + (1,) * (len(names) - 1)
-    axes = tuple(zip(names, mesh_shape))
-    shape = shape or _SHAPE
-
-    def build():
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        from heat3d_tpu.ops.stencil_dma_fused import (
-            apply_step_fused_dma,
-            apply_superstep_fused_dma,
-        )
-
-        taps = _taps(kind)
-        mesh = _mesh(mesh_shape, names)
-        nx, ny, nz = shape
-        aval = jax.ShapeDtypeStruct((size * nx, ny, nz), jnp.float32)
-        apply = apply_superstep_fused_dma if superstep else apply_step_fused_dma
-        fn = _sharded(
-            lambda u: apply(
-                u,
-                taps,
-                axis_name=names[0],
-                axis_size=size,
-                mesh_axes=names,
-                periodic=periodic,
-                bc_value=1.5,
-            ),
-            mesh,
-            P(*([names[0]] + [None] * 2)),
-        )
-        return fn, (aval,)
-
-    ptag = "/periodic" if periodic else ""
-    name = "fused2" if superstep else "fused"
-    return KernelCase(
-        key=f"{name}/{kind}/x{size}{ptag}{tag}",
-        path="heat3d_tpu/ops/stencil_dma_fused.py",
-        entry=(
-            "apply_superstep_fused_dma" if superstep else "apply_step_fused_dma"
-        ),
-        build=build,
-        ctxs=ring_ctxs(axes),
-        comm=(CommAxis(names[0], size),),
-    )
-
-
-def _fused_rdma_case(
-    kind: str, periodic: bool, superstep: bool, plan_mode: str,
-    tag="", shape=None,
-) -> KernelCase:
-    """The fused in-kernel RDMA superstep (ops/stencil_fused_rdma): the
-    template sweep bodies with the plan-scheduled transport — one
-    remote-copy descriptor per (direction, sub-block) of the
-    ``ExchangePlan``'s decomposition, each owning its own flat semaphore
-    cell. ``plan_mode='partitioned'`` builds the plan with the
-    granularity floor off so the certified program genuinely ships
-    sub-blocks (the judged discipline: per-descriptor start/wait
-    pairing, no semaphore-cell aliasing, remote targets still the ±1
-    ring bijection)."""
-    size = 4
-    axes = (("x", size),)
-    shape = shape or _SHAPE
-    width = 2 if superstep else 1
-
-    from heat3d_tpu.core.config import BoundaryCondition, MeshConfig
-    from heat3d_tpu.parallel.plan import build_plan
-
-    plan = build_plan(
-        MeshConfig(shape=(size, 1, 1)),
-        BoundaryCondition.PERIODIC if periodic else BoundaryCondition.DIRICHLET,
-        width=width,
-        transport="ppermute",
-        mode=plan_mode,
-        min_part_bytes=0,
-    )
-
-    def build():
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        from heat3d_tpu.ops.stencil_fused_rdma import (
-            apply_step_fused_rdma,
-            apply_superstep_fused_rdma,
-        )
-
-        taps = _taps(kind)
-        mesh = _mesh((size,), ("x",))
-        nx, ny, nz = shape
-        aval = jax.ShapeDtypeStruct((size * nx, ny, nz), jnp.float32)
-        apply = (
-            apply_superstep_fused_rdma if superstep else apply_step_fused_rdma
-        )
-        fn = _sharded(
-            lambda u: apply(
-                u,
-                taps,
-                plan=plan,
-                axis_name="x",
-                axis_size=size,
-                mesh_axes=("x",),
-                periodic=periodic,
-                bc_value=1.5,
-            ),
-            mesh,
-            P("x", None, None),
-        )
-        return fn, (aval,)
-
-    ptag = "/periodic" if periodic else ""
-    mtag = "/planned" if plan_mode == "partitioned" else ""
-    name = "fused-rdma2" if superstep else "fused-rdma"
-    return KernelCase(
-        key=f"{name}/{kind}/x{size}{ptag}{mtag}{tag}",
-        path="heat3d_tpu/ops/stencil_fused_rdma.py",
-        entry=(
-            "apply_superstep_fused_rdma"
-            if superstep
-            else "apply_step_fused_rdma"
-        ),
-        build=build,
-        ctxs=ring_ctxs(axes),
-        comm=(CommAxis("x", size),),
-        plan_key=plan.key,
-    )
-
-
 @functools.lru_cache(maxsize=1)
 def _cached_matrix() -> Tuple[KernelCase, ...]:
     import jax
@@ -561,54 +424,6 @@ def _cached_matrix() -> Tuple[KernelCase, ...]:
             _dma_axis_case(width=2, size=4, periodic=True),
             _dma_axis_case(width=4, size=4, periodic=False),
             _dma_planned_case()[0],
-            _fused_case("7pt", periodic=False, superstep=False),
-            _fused_case("27pt", periodic=True, superstep=False),
-            _fused_case(
-                "7pt", periodic=False, superstep=False,
-                mesh_axes=("x", "y", "z"), tag="/mesh3",
-            ),
-            _fused_case("7pt", periodic=False, superstep=True),
-            _fused_case("27pt", periodic=True, superstep=True),
-            # multi-chunk-column variants: the 2D grid re-primes the
-            # rings per column and derives j-dependent ghost rows —
-            # the cross-column happens-before discipline is its own
-            # control-flow family (the (8,1024,512) local block chunks
-            # at by=512 / by=256 under the default VMEM budget)
-            _fused_case(
-                "7pt", periodic=False, superstep=False,
-                shape=(8, 1024, 512), tag="/chunked",
-            ),
-            _fused_case(
-                "7pt", periodic=False, superstep=True,
-                shape=(8, 1024, 512), tag="/chunked",
-            ),
-            # the plan-scheduled fused RDMA superstep: monolithic (one
-            # descriptor per direction — the degenerate plan) and
-            # partitioned (per-sub-block descriptors, flat semaphore
-            # cells) arms, step and tb=2 forms, at every ring position
-            _fused_rdma_case(
-                "7pt", periodic=False, superstep=False,
-                plan_mode="monolithic",
-            ),
-            _fused_rdma_case(
-                "27pt", periodic=True, superstep=False,
-                plan_mode="partitioned",
-            ),
-            _fused_rdma_case(
-                "7pt", periodic=False, superstep=True,
-                plan_mode="partitioned",
-            ),
-            _fused_rdma_case(
-                "27pt", periodic=True, superstep=True,
-                plan_mode="monolithic",
-            ),
-            # multi-chunk + partitioned sends: the cross-column ring
-            # re-prime composed with per-sub-block descriptor waits
-            _fused_rdma_case(
-                "7pt", periodic=False, superstep=False,
-                plan_mode="partitioned", shape=(8, 1024, 512),
-                tag="/chunked",
-            ),
         ]
         cases.append(
             _direct_case(

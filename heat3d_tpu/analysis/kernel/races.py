@@ -2,8 +2,8 @@
 edges.
 
 The %3 VMEM ring discipline every streaming kernel shares (``_stream``,
-``_stream2``, ``_streamk`` k=2..4, the direct kernels' rings, the fused
-DMA kernels' input/mid rings): plane ``p`` lives in slot ``p % 3``,
+``_stream2``, ``_streamk`` k=2..4, the direct kernels' rings): plane
+``p`` lives in slot ``p % 3``,
 written once per grid step, and every consumer stage at step ``i`` reads
 three *consecutively produced* planes — the writes from steps ``i-2``,
 ``i-1``, ``i`` of the same chunk column. This checker rebuilds the
@@ -74,8 +74,8 @@ def replay(rec):
     is when its content is safe to read); ``hazards`` is a list of
     ``(kind, ev, detail)`` in-flight violations found along the way.
     Memoized per record: the coverage checker re-reads the same
-    timelines (once per output), and the chunked fused cases' event
-    streams are the dominant cost of the whole ``--kernel`` run."""
+    timelines (once per output), and the chunked cases' event streams
+    are the dominant cost of the whole ``--kernel`` run."""
     cached = getattr(rec, "_replay_cache", None)
     if cached is not None:
         return cached

@@ -20,10 +20,7 @@ position of the case's ring and proves:
 - **ANL1032** — a plan-driven exchange must realize the
   ``ExchangePlan``'s axis schedule: one kernel per sharded axis, in the
   plan's corner-propagation order, each moving data along exactly that
-  axis (a dict ``device_id`` touching any other mesh axis fires). This
-  is the standing gate the fused in-kernel-RDMA superstep arc lands
-  against (ROADMAP): a superstep that consumes the plan out of order or
-  ships a sub-block off-axis reds this lint on CPU.
+  axis (a dict ``device_id`` touching any other mesh axis fires).
 - **ANL1033** — direction completeness: every exchange kernel must
   carry BOTH ring directions (on a size-2 ring the two shifts coincide
   — the self-inverse case ANL604 pinned at the IR tier — and one class

@@ -29,19 +29,11 @@ ROUTE_FIELDS = (
     "platform",
     "direct_path",
     "mehrstellen_route",
-    "fused_dma_path",
-    "fused_dma_emulated",
     "streamk_path",
     "streamk_emulated",
     # exchange-plan mode (monolithic | partitioned): the partitioned A/B
     # changes the message schedule, not the bytes — rows must carry it
     "halo_plan",
-    # fused in-kernel RDMA route (fused_rdma='on' / HEAT3D_FUSED_RDMA):
-    # the halo bytes move inside the step kernel, so the traffic model
-    # and the fused-vs-unfused A/B must be keyable from the row alone;
-    # the _emulated twin marks reference-contract (off-TPU) resolutions
-    "fused_rdma_path",
-    "fused_rdma_emulated",
 )
 MAX_REPORT = 20
 

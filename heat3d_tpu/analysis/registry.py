@@ -149,11 +149,6 @@ LEDGER_EVENTS: Dict[str, Dict[str, Any]] = {
     "plan_cache_hit": {"kind": "point", "module": "parallel/plan.py",
                        "desc": "exchange plan reused from the process "
                                "cache (once per plan key per run)"},
-    "fused_rdma_dispatch": {"kind": "point", "module": "parallel/step.py",
-                            "desc": "fused in-kernel RDMA superstep route "
-                                    "selected (plan key, tb, sub-block "
-                                    "count, emulated flag) — once per "
-                                    "plan key per run"},
     # autotuning
     "tune_search_start": {"kind": "point", "module": "tune/measure.py",
                           "desc": "search opened: space, budget, key"},
@@ -352,12 +347,6 @@ ENV_VARS: Dict[str, Dict[str, str]] = {
                                "ad-hoc dispatch; partitioned degrades to "
                                "monolithic — the parity tests' reference "
                                "arm)"},
-    "HEAT3D_FUSED_RDMA": {
-        "module": "parallel/step.py",
-        "desc": "overrides the fused_rdma config knob: 1/on forces the "
-                "fused in-kernel RDMA superstep route, anything else "
-                "stands it down (the A/B counterfactual arm; row "
-                "identity in resilience/sweepstate)"},
     "HEAT3D_PLAN_PART_MIN_BYTES": {
         "module": "parallel/plan.py",
         "desc": "partition granularity floor in bytes (default 1 MiB): "
@@ -368,8 +357,8 @@ ENV_VARS: Dict[str, Dict[str, str]] = {
                                 "desc": "1 routes kernels through the Pallas interpreter off-TPU (tests)"},
     "HEAT3D_DIRECT_FORCE": {"module": "parallel/step.py",
                             "desc": "1 selects real Mosaic kernels off-TPU (compile-only tests)"},
-    "HEAT3D_VMEM_BYTES": {"module": "ops/stencil_dma_fused.py",
-                          "desc": "whole-chip VMEM ceiling override for the fused-DMA gate (default: per-generation table, 32 MiB unknown parts)"},
+    "HEAT3D_VMEM_BYTES": {"module": "analysis/vmem.py",
+                          "desc": "whole-chip VMEM budget override the IR memory-contract check (ANL905) adjudicates (default: per-generation table)"},
     "HEAT3D_FAULTS": {"module": "resilience/faults.py",
                       "desc": "deterministic fault-injection plan"},
     "HEAT3D_FAULT_STATE": {"module": "resilience/faults.py",
@@ -398,8 +387,6 @@ ENV_VARS: Dict[str, Dict[str, str]] = {
                             "desc": "1 skips cache lookup (search driver sets it)"},
     "HEAT3D_BENCH_GRID": {"module": "bench.py",
                           "desc": "headline-bench grid edge override"},
-    "HEAT3D_BENCH_ARGS": {"module": "scripts/pod_ab_fused.sh",
-                          "desc": "extra flags threaded into bench rows"},
     "HEAT3D_BENCH_STEPS": {"module": "bench.py",
                            "desc": "headline-bench step count"},
     "HEAT3D_BENCH_DTYPE": {"module": "bench.py",

@@ -43,20 +43,49 @@ CHECKER = "vmem-budget"
 MIB = 1024 * 1024
 
 
-def __getattr__(name: str):
-    """Per-generation VMEM capacity (bytes/core), keys spelled as the
-    tuning cache normalizes them. The table itself lives in
-    ops/stencil_dma_fused.py since PR 9: the fused-DMA gate resolves its
-    whole-chip budget from it per live generation, so the checker audits
-    the SAME numbers the production gate uses (one source, no drift).
-    Resolved lazily (PEP 562) because importing ops pulls jax — this
-    module must stay cheap to import for `heat3d lint --list` and the
-    pure-AST leg."""
-    if name == "CHIP_VMEM_BYTES":
-        from heat3d_tpu.ops.stencil_dma_fused import CHIP_VMEM_BYTES
+# Per-generation VMEM budget (bytes/core): the scoped VMEM a Mosaic
+# kernel gets by default (the v5e compiler refuses a kernel sized to
+# 32 MiB with RESOURCE_EXHAUSTED). Keyed by the normalized
+# chip-generation strings the tuning cache derives from device_kind
+# (tune.cache.chip_generation). The budget audit below and the IR
+# memory-contract check (ANL905) read it; no kernel gate does.
+CHIP_VMEM_BYTES = {
+    "tpu-v4": 16 * MIB,
+    "tpu-v5-lite": 16 * MIB,
+    "tpu-v5p": 32 * MIB,
+    "tpu-v6-lite": 32 * MIB,
+}
 
-        return CHIP_VMEM_BYTES
-    raise AttributeError(name)
+
+def chip_vmem_budget_for(generation: str) -> int:
+    """The whole-chip VMEM budget of ``generation`` (a normalized
+    ``tune.cache.chip_generation`` string) absent an env override. Off
+    the chip it is the smallest budget in the table, so a shape admitted
+    there fits every listed chip. A TPU generation missing from the
+    table is an error: its budget is unknown."""
+    if generation in CHIP_VMEM_BYTES:
+        return CHIP_VMEM_BYTES[generation]
+    if generation == "cpu":
+        return min(CHIP_VMEM_BYTES.values())
+    raise ValueError(
+        f"no VMEM budget for chip generation {generation!r}: add it to "
+        "analysis/vmem.CHIP_VMEM_BYTES (or set HEAT3D_VMEM_BYTES)"
+    )
+
+
+def _chip_vmem_budget() -> int:
+    """The live whole-chip VMEM budget: ``HEAT3D_VMEM_BYTES`` (operator
+    override) > the per-generation table keyed on the live chip
+    generation."""
+    import os
+
+    env = os.environ.get("HEAT3D_VMEM_BYTES")
+    if env:
+        return int(env)
+    from heat3d_tpu.tune.cache import chip_generation
+
+    return chip_vmem_budget_for(chip_generation())
+
 
 # Mosaic's default scoped-vmem pool (the tap-chain stack lives here — a
 # separate pool from the explicit ring/pipeline buffers).
@@ -208,13 +237,9 @@ def _budget_findings(
                 ),
             )
         )
-    # The old standing ANL305 warning (fused-DMA 32 MiB default vs
-    # 16 MiB parts) is resolved since PR 9: the gate resolves its
-    # whole-chip ceiling per generation from THIS table
-    # (ops/stencil_dma_fused.chip_vmem_budget_for). The gate-side
-    # adjudication — resolved budget vs capacity, including the live
-    # HEAT3D_VMEM_BYTES override — now lives in the IR memory-contract
-    # checker (ANL905, analysis/ir/memcontract.py), not here.
+    # The resolved budget vs capacity, including the live
+    # HEAT3D_VMEM_BYTES override, is adjudicated by the IR
+    # memory-contract checker (ANL905, analysis/ir/memcontract.py).
 
     # admitted worst-case footprints over the judged shapes: anything the
     # gates admit must fit the floor generation, with headroom reported
@@ -298,11 +323,7 @@ def check(
     findings = _ast_findings(root, paths)
     if arithmetic and files is None:
         if chip_table is None:
-            # module __getattr__ resolves the canonical ops-owned table
-            # lazily; plain global lookup would bypass it
-            from heat3d_tpu.analysis import vmem as _self
-
-            chip_table = _self.CHIP_VMEM_BYTES
+            chip_table = CHIP_VMEM_BYTES
         findings.extend(_budget_findings(chip_table, margin))
     elif arithmetic and chip_table is not None:
         findings.extend(_budget_findings(chip_table, margin))

@@ -107,17 +107,13 @@ def bench_throughput(
     # solver's loop: every provenance field reads that one record
     route = solver.route
     direct = route["kernel"] == "direct"
-    fused = route["kernel"] == "fused_dma"
     streamk = route["kernel"] == "streamk"
-    fused_rdma = route["kernel"] == "fused_rdma"
     mehrstellen = _mehrstellen_route(cfg, route["kernel"])
-    # the fused routes have an off-TPU emulation tier (interpret mode /
-    # the pure-XLA reference contracts under HEAT3D_DIRECT_INTERPRET):
-    # record it EXPLICITLY so A/B tooling cannot mistake an emulated row
-    # for a real Mosaic-kernel row without cross-checking the platform
-    fused_emulated = bool(fused and route["interpret"])
+    # the streamk route has an off-TPU tier (interpret mode under
+    # HEAT3D_DIRECT_INTERPRET): record it EXPLICITLY so A/B tooling
+    # cannot mistake an interpreted row for a real Mosaic-kernel row
+    # without cross-checking the platform
     streamk_emulated = bool(streamk and route["interpret"])
-    fused_rdma_emulated = bool(fused_rdma and route["interpret"])
     # cost-analysis provenance (obs/perf/roofline): XLA's own FLOPs/bytes
     # for ONE step of this config, so a row's achieved-vs-peak is
     # computable from the row alone (`obs summary` roofline section,
@@ -179,12 +175,6 @@ def bench_throughput(
         # row must say what ran — docs/TUNING.md "Persistent exchange
         # plans")
         "halo_plan": _effective_halo_plan(cfg),
-        # fused-RDMA knob provenance (the five-surface knob contract):
-        # the EFFECTIVE value — HEAT3D_FUSED_RDMA override included,
-        # 'auto' resolved — so an env-forced A/B row is keyable from the
-        # row alone (obs regress/sweepstate key on it; legacy rows key
-        # to off)
-        "fused_rdma": _effective_fused_rdma(cfg),
         "steps": steps,
         "steps_requested": steps_requested,
         # ensemble-workload provenance (REQUIRED by check_provenance.py on
@@ -218,27 +208,12 @@ def bench_throughput(
         # selection (the real selector, not the env) so the traffic model
         # can't mislabel them.
         "direct_path": direct,
-        # overlap+halo='dma' rows: whether the fused DMA-overlap kernel
-        # (vs an error'd/jnp fallback elsewhere) actually resolved —
-        # the pod A/B vs faces-direct needs the RESOLVED route on record
-        "fused_dma_path": fused,
-        # ... and whether that resolution was the XLA reference EMULATION
-        # tier rather than the Mosaic kernel (ADVICE r5 item 2)
-        "fused_dma_emulated": fused_emulated,
         # deep-tb route provenance: whether the fused k-sweep streaming
         # kernel resolved (tb=3..4); without it a tb=3 row's traffic model
         # can't distinguish one fused sweep from k plain sweeps. The
-        # _emulated twin marks interpret-tier resolutions (same contract
-        # as fused_dma_emulated).
+        # _emulated twin marks interpret-tier resolutions.
         "streamk_path": streamk,
         "streamk_emulated": streamk_emulated,
-        # fused in-kernel RDMA route: whether the plan-scheduled fused
-        # superstep actually resolved (vs the jnp plan-exchange fallback
-        # elsewhere) — the fused-vs-unfused A/B needs the RESOLVED route
-        # on record, and the _emulated twin marks reference-contract
-        # resolutions (same contract as fused_dma_emulated)
-        "fused_rdma_path": fused_rdma,
-        "fused_rdma_emulated": fused_rdma_emulated,
         # redundant-compute honesty (required by check_provenance.py on
         # tb>1 rows): fraction of the superstep's executed stencil flops
         # that are ghost-ring recompute — the discount between this row's
@@ -271,16 +246,6 @@ def _redundant_frac(cfg: SolverConfig) -> float:
     from heat3d_tpu.parallel.step import redundant_flops_frac
 
     return redundant_flops_frac(cfg)
-
-
-def _effective_fused_rdma(cfg: SolverConfig) -> str:
-    """The ONE effective-knob rule (parallel.step.resolve_fused_rdma):
-    rows record what the dispatcher saw — HEAT3D_FUSED_RDMA override
-    included, 'auto' resolved to its static fallback — mirroring the
-    halo_plan effective-mode posture."""
-    from heat3d_tpu.parallel.step import resolve_fused_rdma
-
-    return resolve_fused_rdma(cfg)
 
 
 def _chain_ops(

@@ -65,14 +65,7 @@ def _fmt_route(r: Dict) -> str:
         return "conv"  # one XLA conv op — neither transport tier applies
     if "direct_path" not in r and "chain_ops" not in r:
         return "—"
-    if r.get("fused_dma_path"):
-        # RDMA issued inside the sweep kernel; "(emu)" marks rows that ran
-        # the XLA reference contract, not the Mosaic kernel — never let an
-        # emulated row read as a real fused-kernel number
-        transport = (
-            "fused-dma(emu)" if r.get("fused_dma_emulated") else "fused-dma"
-        )
-    elif r.get("direct_path"):
+    if r.get("direct_path"):
         transport = "direct"
     else:
         transport = "exch"

@@ -132,18 +132,6 @@ def build_parser() -> argparse.ArgumentParser:
                    "smaller messages overlapped with compute; "
                    "value-identical, pins the exchange path), or 'auto' "
                    "(resolve through the tuning cache; docs/TUNING.md)")
-    p.add_argument("--fused-rdma", choices=["off", "on", "auto"],
-                   default="off",
-                   help="fused in-kernel RDMA superstep "
-                   "(ops/stencil_fused_rdma): 'on' runs the halo "
-                   "transfers INSIDE the stencil kernel — face remote "
-                   "copies issued at grid step 0 on the ExchangePlan "
-                   "schedule (--halo-plan partitioned splits the sends "
-                   "into sub-block descriptors), interior swept while "
-                   "they fly, skin planes after the semaphore waits; "
-                   "value-identical to the unfused route, x-slab meshes "
-                   "+ time-blocking <= 2 only (jnp path elsewhere); "
-                   "'auto' resolves through the tuning cache")
     p.add_argument("--time-blocking", type=int, default=1,
                    help="stencil updates per ghost exchange in the "
                    "fixed-step loop (k>1 = temporal blocking: width-k "
@@ -268,7 +256,6 @@ def config_from_args(args) -> SolverConfig:
         time_blocking=args.time_blocking,
         halo_order=args.halo_order,
         halo_plan=args.halo_plan,
-        fused_rdma=getattr(args, "fused_rdma", "off"),
         equation=getattr(args, "equation", "heat"),
         eq_params=_parse_eq_params(getattr(args, "eq_param", [])),
         integrator=getattr(args, "integrator", "explicit-euler"),
@@ -374,7 +361,6 @@ def _main(argv: Optional[List[str]] = None) -> int:
         halo=cfg.halo,
         halo_order=cfg.halo_order,
         halo_plan=cfg.halo_plan,
-        fused_rdma=cfg.fused_rdma,
         overlap=cfg.overlap,
         time_blocking=cfg.time_blocking,
         steps=cfg.run.num_steps,

@@ -208,7 +208,8 @@ class SolverConfig:
     # Split each step into interior + boundary-shell updates so XLA's async
     # collectives overlap the halo ppermutes with the interior sweep — the
     # TPU analogue of the reference class's two-stream interior/boundary
-    # overlap (SURVEY.md §3.2, §7.3 item 2). Needs local blocks >= 3 per axis.
+    # overlap (SURVEY.md §3.2, §7.3 item 2). Needs local blocks >= 3 per axis
+    # and the ppermute transport.
     overlap: bool = False
     # Ghost-exchange transport: 'ppermute' (XLA collective-permute, v1),
     # 'dma' (Pallas make_async_remote_copy kernels — the CUDA-aware/GPUDirect
@@ -252,18 +253,6 @@ class SolverConfig:
     # stand down — and requires the ppermute transport), or 'auto'
     # (resolve through the tuning cache, static fallback monolithic).
     halo_plan: str = "monolithic"
-    # Fused in-kernel RDMA superstep (ops/stencil_fused_rdma;
-    # docs/TUNING.md): 'on' dispatches the single Pallas kernel that
-    # starts the x-face remote copies itself (per-sub-block descriptors
-    # riding the ExchangePlan schedule — halo_plan='partitioned' splits
-    # the sends), sweeps the interior while they fly, then finishes the
-    # skin planes — the paper's compute/comm overlap done inside ONE
-    # kernel, without the 'dma'-transport exchange phase. Scope: x-slab
-    # meshes, time_blocking <= 2, axis ordering; outside the scope the
-    # route stands down and the plan-driven jnp path runs (values
-    # identical). 'auto' resolves through the tuning cache (static
-    # fallback 'off').
-    fused_rdma: str = "off"
     # Equation family (heat3d_tpu.eqn registry; docs/EQUATIONS.md):
     # which PDE the tap compiler lowers onto the stencil footprint.
     # 'heat' is the legacy hardcoded path, now spec-authored — its
@@ -320,43 +309,12 @@ class SolverConfig:
                 "faces by construction — use halo='ppermute' (or plan "
                 "mode 'monolithic')"
             )
-        if self.fused_rdma not in ("off", "on", "auto"):
+        if self.overlap and self.halo == "dma":
             raise ValueError(
-                f"unknown fused_rdma {self.fused_rdma!r} (want off|on|auto)"
+                "overlap=True with halo='dma': the side-effecting DMA "
+                "exchange kernels cannot overlap with compute — use "
+                "halo='ppermute' for XLA's async collective-permutes"
             )
-        if self.fused_rdma == "on":
-            # the fused superstep IS the exchange: it rides the
-            # ExchangePlan's axis-ordered ppermute-transport schedule, so
-            # the knobs that select a different exchange path conflict
-            # rather than compose
-            if self.halo == "dma":
-                raise ValueError(
-                    "fused_rdma='on' drives its own remote copies from "
-                    "the ExchangePlan schedule; the 'dma' exchange "
-                    "transport is a different path — use halo='ppermute'"
-                )
-            if self.overlap:
-                raise ValueError(
-                    "fused_rdma='on' and overlap are mutually exclusive: "
-                    "the fused kernel already overlaps the transfers "
-                    "with the interior sweep"
-                )
-            if self.halo_order == "pairwise":
-                raise ValueError(
-                    "fused_rdma='on' rides the plan's axis-ordered "
-                    "schedule; halo_order='pairwise' is a different "
-                    "exchange structure"
-                )
-            if self.time_blocking not in (0, 1, 2):
-                raise ValueError(
-                    "fused_rdma='on' composes with temporal blocking "
-                    f"k <= 2, got time_blocking={self.time_blocking}"
-                )
-            if self.backend == "conv":
-                raise ValueError(
-                    "fused_rdma='on' is a Pallas route; backend='conv' "
-                    "cannot host it"
-                )
         if self.halo_order == "pairwise":
             # pairwise ordering leaves corner/edge ghosts at bc_value:
             # exactly the cells the 27pt stencil and the temporally-blocked

@@ -108,13 +108,6 @@ class HeatSolver3D:
         solver = HeatSolver3D(cfg)
         u = solver.init_state("hot-cube")
         u = solver.run(u, num_steps=100)
-
-    Construction-time vs step-build-time checks: the constructor validates
-    only platform/emulation availability for ``halo='dma'``; the fused
-    DMA routes' SCOPE gates (x-sharded mesh, unpadded shards, local-extent
-    minima) are enforced at step-build time inside
-    ``parallel.step.make_step_fn`` — an out-of-scope config constructs
-    fine and raises its precise ValueError when the step is built.
     """
 
     def __init__(self, cfg: SolverConfig, devices=None):
@@ -156,24 +149,11 @@ class HeatSolver3D:
                     )
         if cfg.halo == "dma":
             platform = jax.devices()[0].platform
-            # The fused DMA-overlap routes (overlap=True) have an off-TPU
-            # emulation tier: HEAT3D_DIRECT_INTERPRET dispatches their
-            # pure-XLA reference contracts (parallel/step._fused_dma_route
-            # — interpret mode cannot discharge remote DMA on the 3-axis
-            # mesh). The plain DMA exchange transport has no such tier.
-            # The SHARED env gate decides (backend/padding rules included)
-            # so this check cannot drift from the route dispatch.
-            from heat3d_tpu.parallel.step import _kernel_env_gate
-
-            gate_ok, gate_interpret = _kernel_env_gate(cfg)
-            emulated = cfg.overlap and gate_ok and gate_interpret
-            if platform != "tpu" and not emulated:
+            if platform != "tpu":
                 raise ValueError(
                     f"halo='dma' needs TPU hardware (Mosaic remote-DMA "
                     f"kernels); platform is {platform!r} — use "
-                    "halo='ppermute' (or set HEAT3D_DIRECT_INTERPRET=1 "
-                    "with --overlap for the fused routes' XLA reference "
-                    "emulation)"
+                    "halo='ppermute'"
                 )
         self.cfg = cfg
         self.mesh = build_mesh(cfg.mesh, devices)
@@ -260,7 +240,7 @@ class HeatSolver3D:
         compute ('auto' resolved), ``kernel`` the Pallas kernel family of
         the loop's hot step (the superstep at tb > 1) or None for the
         exchange path, and ``interpret`` whether that kernel runs under
-        the off-TPU emulation. The loop that ran last is the one
+        the Pallas interpreter off-TPU. The loop that ran last is the one
         reported; before any run, the fixed-step loop is built (not
         compiled) to ask it. ``layout`` is the kernel's VMEM plane layout
         ('aligned' or 'framed', None without a kernel). Where the devices

@@ -230,14 +230,6 @@ def roofline_lines(events: List[Dict[str, Any]]) -> List[str]:
                 ):
                     tb = r.get("time_blocking", 1)
                     label = f"bench {grid} tb={tb}"
-                    # fused-route rows: say so in the label — the halo
-                    # bytes ride inside the step kernel here, so these
-                    # lines are not comparable to exchange-path rows of
-                    # the same shape without the tag
-                    if r.get("fused_rdma_path"):
-                        label += " fused-rdma"
-                    elif r.get("fused_dma_path"):
-                        label += " fused-dma"
                     frac = r.get("cost_redundant_flops_frac")
                     if isinstance(frac, (int, float)) and frac > 0:
                         # deep-tb rows: flag how much of the raw rate is

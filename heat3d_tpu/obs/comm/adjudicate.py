@@ -52,7 +52,7 @@ _ENVELOPE = ("ts", "run_id", "proc", "seq", "event", "kind", "src")
 # every knob any stage adjudicates — each stage's context includes the
 # OTHER stages' knobs, so a halo_plan pair can never straddle two
 # halo_orders
-_STAGE_KNOBS = ("halo_plan", "halo_order", "time_blocking", "fused_rdma")
+_STAGE_KNOBS = ("halo_plan", "halo_order", "time_blocking")
 
 # context fields that must match for two rows to be comparable (the
 # union present in the eligible rows is used — files predating a field
@@ -98,17 +98,6 @@ STAGES: Tuple[Dict[str, Any], ...] = (
         "metric": None,  # decide()'s throughput METRIC_KEYS
         "prefer": "higher",
         "title": "slab width / temporal-blocking depth (Gcell/s/chip)",
-    },
-    {
-        # stage 3-fused: the fused in-kernel RDMA superstep vs the
-        # unfused exchange route — rows stamp the EFFECTIVE knob
-        # (bench/harness), so an env-forced arm pairs correctly
-        "stage": "fused_rdma",
-        "knob": "fused_rdma",
-        "bench": "throughput",
-        "metric": None,  # decide()'s throughput METRIC_KEYS
-        "prefer": "higher",
-        "title": "fused in-kernel RDMA superstep vs unfused (Gcell/s/chip)",
     },
 )
 

@@ -114,10 +114,6 @@ def row_key(row: Dict[str, Any]) -> Optional[Tuple]:
             row.get("halo", "ppermute"),
             row.get("halo_order", "axis"),
             row.get("halo_plan", "monolithic"),
-            # fused-RDMA route leg: a fused superstep's rate must never
-            # baseline against the unfused exchange path of the same
-            # shape — rows predating the knob are off by construction
-            row.get("fused_rdma", "off"),
             row.get("backend", "auto"),
             # ensemble workload axis: a packed batch's aggregate rate must
             # only ever baseline against the same batch shape — without

@@ -265,14 +265,7 @@ def phase_costs_and_times(
     import time as _time
 
     out = []
-    seen = {}
     for phase, fn in programs.items():
-        if id(fn) in seen:  # fused_dma aliases the step program
-            rec = dict(seen[id(fn)])
-            rec["phase"] = phase
-            rec["alias_of"] = seen[id(fn)]["phase"]
-            out.append(rec)
-            continue
         jitted = jax.jit(fn)
         try:
             compiled = jitted.lower(u).compile()
@@ -313,7 +306,6 @@ def phase_costs_and_times(
             rec["eff_gcell_per_s"] = (
                 cfg.grid.num_cells * cfg.time_blocking / sec / 1e9
             )
-        seen[id(fn)] = rec
         out.append(rec)
     return out
 
@@ -352,7 +344,6 @@ def print_live_table(
         if "error" in r:
             print(f"{r['phase']:<16} error: {r['error']}", file=out)
             continue
-        alias = f" (= {r['alias_of']})" if r.get("alias_of") else ""
         fm = _pct(r.get("gflops"), vec)
         bm = _pct(r.get("gbps"), mem)
         bound = "?"
@@ -383,7 +374,7 @@ def print_live_table(
             f"{r['seconds'] * 1e3:>8.2f}ms "
             f"{r['gflops'] if r['gflops'] is not None else 0:>9.2f} "
             f"{r['gbps'] if r['gbps'] is not None else 0:>8.2f} "
-            f"{fm:>8} {bm:>8} {bound:>6}{alias}{eff}",
+            f"{fm:>8} {bm:>8} {bound:>6}{eff}",
             file=out,
         )
 
@@ -411,13 +402,7 @@ def phase_cost_records(cfg) -> Dict[str, Dict[str, Any]]:
         cfg.padded_shape, jnp.dtype(cfg.precision.storage), sharding=sharding
     )
     out: Dict[str, Dict[str, Any]] = {}
-    seen: Dict[int, str] = {}
     for phase, fn in programs.items():
-        if id(fn) in seen:  # fused_dma aliases the step program
-            rec = dict(out[seen[id(fn)]])
-            rec["alias_of"] = seen[id(fn)]
-            out[phase] = rec
-            continue
         try:
             compiled = jax.jit(fn).lower(aval).compile()
             flops, bytes_ = extract_cost(compiled.cost_analysis())
@@ -425,28 +410,22 @@ def phase_cost_records(cfg) -> Dict[str, Dict[str, Any]]:
         except Exception as e:  # noqa: BLE001 - keep the join best-effort
             out[phase] = {"error": f"{type(e).__name__}: {str(e)[:200]}"}
             continue
-        seen[id(fn)] = phase
     return out
 
 
 def _phase_calls(phase: str, steps: int, tb: int) -> Optional[int]:
     """How many times the phase program ran across ``steps`` updates:
-    one stencil sweep per update; one exchange (one fused kernel) per tb
-    updates. None means "total time only, no achieved rate": residual
+    one stencil sweep per update; one exchange per tb updates. None means "total time only, no achieved rate": residual
     cadence is run-configured and not recoverable from the capture, and
     the ``step`` scope's device time is EXCLUSIVE (ops inside the inner
     stencil/halo scopes attribute there, leaving only dispatch glue) —
     dividing the FULL step program's cost by glue-only time would report
     absurd fractions of peak."""
-    from heat3d_tpu.parallel.step import (
-        PHASE_FUSED,
-        PHASE_HALO,
-        PHASE_STENCIL,
-    )
+    from heat3d_tpu.parallel.step import PHASE_HALO, PHASE_STENCIL
 
     if phase == PHASE_STENCIL:
         return max(1, steps)
-    if phase in (PHASE_HALO, PHASE_FUSED):
+    if phase == PHASE_HALO:
         return max(1, steps // max(1, tb))
     return None
 
@@ -463,24 +442,10 @@ def profile_join_records(
     ``cfg.time_blocking`` set the call counts), and the achieved
     GFLOP/s / GB/s those imply — "stencil at X% of HBM peak, halo at Y%"
     from measured times, not span wall-clock."""
-    from heat3d_tpu.parallel.step import (
-        PHASE_FUSED,
-        PHASE_HALO,
-        PHASE_STEP,
-        PHASES,
-    )
+    from heat3d_tpu.parallel.step import PHASES
 
     costs = phase_cost_records(cfg)
     tb = cfg.time_blocking
-    # Fused-route captures (DMA-overlap or in-kernel RDMA) run NO
-    # standalone exchange — the halo bytes move inside the step-scope
-    # kernel. Joining the compiled halo_exchange program's bytes against
-    # the capture's (absent) halo span would print the phase as missing
-    # when it honestly VANISHED into the fused kernel, so drop it from
-    # the join; its traffic is already attributed to the fused span.
-    fused_active = costs.get(PHASE_FUSED, {}).get("alias_of") == PHASE_STEP
-    if fused_active and phase_us.get(PHASE_HALO) is None:
-        costs.pop(PHASE_HALO, None)
     attributed = sum(
         us for ph, us in phase_us.items() if ph != "(unattributed)"
     )
@@ -507,8 +472,6 @@ def profile_join_records(
         }
         if cost.get("error"):
             rec["error"] = cost["error"]
-        if cost.get("alias_of"):
-            rec["alias_of"] = cost["alias_of"]
         calls = _phase_calls(phase, steps, tb)
         if us is not None and calls:
             sec = us * 1e-6 / calls
@@ -561,11 +524,10 @@ def print_profile_table(
         )
         gf = f"{r['gflops']:.2f}" if r.get("gflops") is not None else "-"
         gb = f"{r['gbps']:.2f}" if r.get("gbps") is not None else "-"
-        alias = f" (= {r['alias_of']})" if r.get("alias_of") else ""
         print(
             f"{r['phase']:<16} {dev:>10} {share:>6} {per_call:>10} "
             f"{gf:>9} {gb:>8} {_pct(r.get('gflops'), vec):>8} "
-            f"{_pct(r.get('gbps'), mem):>8}{alias}",
+            f"{_pct(r.get('gbps'), mem):>8}",
             file=out,
         )
 
@@ -689,22 +651,6 @@ def bytes_per_cell_update(row) -> tuple:
     # read+write per exchange). Prefer the RESOLVED selection the harness
     # recorded (exact even for HEAT3D_NO_DIRECT A/B rows); derive for
     # legacy rows.
-    if row.get("fused_rdma_path"):
-        # fused in-kernel RDMA superstep: the halo bytes ride remote
-        # copies INSIDE the sweep kernel (no standalone exchange phase),
-        # so HBM traffic is one unpadded read+write per sweep of tb
-        # updates — counting an exchange copy here would double-count
-        # bytes the kernel never materializes
-        per_update = 2 * item / tb
-        path = f"fused-rdma{'' if tb == 1 else '2'}"
-        if row.get("halo_plan") == "partitioned":
-            path += "+planned-partitioned"
-        return per_update, path
-    if row.get("fused_dma_path"):
-        # fused DMA-overlap kernels: unpadded streaming sweep, one
-        # read+write per sweep of tb updates — same traffic shape as the
-        # direct kernels
-        return 2 * item / tb, f"fused-dma{'' if tb == 1 else '2'}"
     if row.get("streamk_path"):
         # fused k-sweep streaming kernel (deep tb): the width-k exchange
         # still materializes the padded copy (one r+w per superstep), but

@@ -223,18 +223,14 @@ def normalize_phase(token: str) -> str:
     fold into ``halo_exchange``, so the roofline/timeline joins keep
     attributing the finer-grained exchange scopes to the one exchange
     phase instead of ``(unattributed)``. A named Pallas kernel's op
-    (``heat3d.stencil.<family>``) folds into its phase the same way: the
-    fused DMA families (``fused_dma*``, ``fused_rdma*``) into
-    ``fused_dma``, every other family into ``stencil``. The names then
-    join ``parallel.step.phase_programs`` / the ledger spans on one key."""
+    (``heat3d.stencil.<family>``) folds into ``stencil`` the same way.
+    The names then join ``parallel.step.phase_programs`` / the ledger
+    spans on one key."""
     if token.startswith("heat3d."):
         token = token[len("heat3d."):]
     if token == "halo" or token.startswith("halo."):
         return "halo_exchange"
     if token.startswith("stencil."):
-        family = token[len("stencil."):]
-        if family.startswith(("fused_dma", "fused_rdma")):
-            return "fused_dma"
         return "stencil"
     return token
 

@@ -2,7 +2,7 @@
 
 A jax.profiler trace of the solver shows raw XLA op names (fusion.123,
 dynamic-update-slice.7) — useless for answering "how much of the step is
-halo exchange vs stencil compute vs fused-DMA wait", and its host lines
+halo exchange vs stencil compute vs shell patches", and its host lines
 show nothing of what the program was doing between dispatches. Three
 pieces answer that:
 
@@ -39,11 +39,10 @@ docs/OBSERVABILITY.md):
   attributes here instead of ``(unattributed)``)
 - ``heat3d.halo_exchange`` (and ``heat3d.halo.<axis>`` per axis)
 - ``heat3d.stencil``
-- ``heat3d.shell`` (nested in ``heat3d.stencil``: the faces-direct and
-  fused-DMA block routes' shard-boundary shell patches — slab assembly,
+- ``heat3d.shell`` (nested in ``heat3d.stencil``: the faces-direct
+  routes' shard-boundary shell patches — slab assembly,
   tap applications, pins and the update slices; the bulk kernel stays
   ``heat3d.stencil.<family>``)
-- ``heat3d.fused_dma``
 - ``heat3d.residual``
 
 Everything imports jax lazily, and only once the process has imported it

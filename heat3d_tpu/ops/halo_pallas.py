@@ -382,8 +382,7 @@ def exchange_halo_dma_planned(
     of re-deriving them from the mesh config on every trace — the step
     builders hand every transport the same plan object. DMA plans are
     monolithic by construction (``plan.build_plan`` rejects partitioned
-    DMA: the slab kernels stage and ship whole faces; sub-block RDMA is
-    the in-kernel-overlap arc's territory, ROADMAP). Must run inside
+    DMA: the slab kernels stage and ship whole faces). Must run inside
     shard_map over the plan's mesh."""
     if plan.transport != "dma" or plan.mode != "monolithic":
         raise ValueError(

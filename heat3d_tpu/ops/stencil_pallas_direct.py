@@ -43,8 +43,7 @@ Ring planes come in two layouts, chosen from the shape (_aligned_layout):
 - Ghost-framed, for any other nz and for the mehrstellen q-ring route:
   each plane is (by + 2h, nz + 2h) with its ghost ring stored around the
   chunk at offset (h, h), and every tap term is a slice at a lane and
-  sublane offset of 0..2h, which Mosaic relayouts. The fused-DMA and
-  fused-RDMA kernels share this scaffold.
+  sublane offset of 0..2h, which Mosaic relayouts.
 
 Both layouts sum the same values in the canonical order, so their results
 are bitwise equal.
@@ -190,8 +189,6 @@ def choose_chunk(
     n_taps: int = 7,
     compute_itemsize: int = 4,
     q_ring: bool = False,
-    reserve_bytes: int = 0,
-    total_budget: Optional[int] = None,
     aligned: bool = False,
 ) -> Optional[int]:
     """Largest y-chunk height ``by`` (a divisor of ny, multiple of 8 when
@@ -201,14 +198,7 @@ def choose_chunk(
     overrides ``n_taps`` with the mehrstellen stack size here, in ONE
     place, so the dispatch gate and the kernel builder can't drift.
     ``aligned`` budgets the aligned ring layout (the direct kernels pass
-    _aligned_layout's verdict; the fused-DMA kernels keep the framed one).
-
-    ``total_budget`` (with ``reserve_bytes``) adds a COMBINED whole-chip
-    constraint on top of the separate ring/stack ceilings: reserve +
-    ring/pipeline + stack <= total_budget. The fused-DMA kernels pass
-    their resident ghost-buffer bytes as the reserve so ``by`` shrinks to
-    a combined-feasible size instead of the route being rejected outright
-    (gate and builder must pass identical values)."""
+    _aligned_layout's verdict)."""
     if q_ring:
         n_taps = _MEHRSTELLEN_STACK_PLANES
     ny, nz = local_shape[1], local_shape[2]
@@ -230,11 +220,6 @@ def choose_chunk(
             aligned_itemsize=in_itemsize if aligned else 0,
         )
         if ring > _VMEM_BUDGET or stack > _TAP_STACK_BUDGET:
-            continue
-        if (
-            total_budget is not None
-            and reserve_bytes + ring + stack > total_budget
-        ):
             continue
         return by
     return None
@@ -300,8 +285,8 @@ def _store_framed_plane(ring, k, chunk, top, bot, bc, periodic, h):
     directly into the scratch via slice stores — one bulk chunk store plus
     narrow row/lane edge stores — instead of materializing it with two
     full-plane concatenates and then copying it into the ring (the VMEM
-    passes that made the fused kernels compute-bound, BASELINE.md traffic
-    model). The lane ghosts are read back from the ring after the row
+    passes that made the streaming kernels compute-bound, BASELINE.md
+    traffic model). The lane ghosts are read back from the ring after the row
     stores (Pallas refs have sequential semantics), so periodic corners
     wrap exactly as the concatenate construction did."""
     by, nz = chunk.shape

@@ -252,7 +252,6 @@ def exchange_halo_faces(
     bc: BoundaryCondition,
     bc_value: float = 0.0,
     width: int = 1,
-    x_ghosts=None,
 ):
     """Faces-only ghost exchange: the six width-``w`` ghost faces of the
     axis-ordered exchange WITHOUT materializing the padded volume (whose
@@ -266,14 +265,7 @@ def exchange_halo_faces(
     have, corners included (the later-axis send faces are built by
     concatenating the earlier ghosts onto the boundary slab, which is how
     corner data propagates here). Must run inside shard_map over the mesh
-    in ``mesh_cfg``.
-
-    ``x_ghosts`` = (xlo, xhi), each (w, ny, nz): x ghost faces already
-    landed by another transport (the fused DMA-overlap kernel's in-sweep
-    RDMA — parallel/step._local_step_fused_dma_3d), domain-BC values
-    already substituted at x-edge devices. The x ppermutes are skipped and
-    the y/z propagation proceeds from the supplied faces, so corner data
-    still flows x -> y -> z exactly as in the pure-ppermute form."""
+    in ``mesh_cfg``."""
     periodic = bc is BoundaryCondition.PERIODIC
     names, sizes = mesh_cfg.axis_names, mesh_cfg.shape
     w = width
@@ -282,18 +274,13 @@ def exchange_halo_faces(
             f"halo width {w} exceeds a local extent of {u.shape}"
         )
     with named_phase("halo_exchange"):
-        return _exchange_halo_faces(
-            u, names, sizes, periodic, bc_value, w, x_ghosts
-        )
+        return _exchange_halo_faces(u, names, sizes, periodic, bc_value, w)
 
 
-def _exchange_halo_faces(u, names, sizes, periodic, bc_value, w, x_ghosts):
-    if x_ghosts is not None:
-        xlo, xhi = x_ghosts
-    else:
-        xlo, xhi = axis_ghosts(
-            u[:w], u[-w:], names[0], sizes[0], periodic, bc_value
-        )
+def _exchange_halo_faces(u, names, sizes, periodic, bc_value, w):
+    xlo, xhi = axis_ghosts(
+        u[:w], u[-w:], names[0], sizes[0], periodic, bc_value
+    )
     # y send faces carry the x ghosts (corner propagation)
     y_lo_send = lax.concatenate([xlo[:, :w], u[:, :w], xhi[:, :w]], 0)
     y_hi_send = lax.concatenate([xlo[:, -w:], u[:, -w:], xhi[:, -w:]], 0)

@@ -269,31 +269,6 @@ class ExchangePlan:
             return 1
         return self.partitions
 
-    def face_partition_bounds(
-        self, axis: int, local_shape, itemsize: int
-    ) -> Tuple[Tuple[int, int], ...]:
-        """The sub-block decomposition this plan ships ``axis``'s faces
-        as: contiguous ``(start, end)`` ranges along the face's partition
-        dim (``axis_specs[axis].part_dim``). Monolithic mode — or a face
-        under the granularity floor — is the degenerate single
-        whole-face range, so callers can drive one loop for both modes.
-        The fused in-kernel RDMA route (ops/stencil_fused_rdma) derives
-        its per-sub-block remote-copy descriptors from THIS schedule, so
-        the kernel's sends ride the same audited decomposition the
-        partitioned ppermute exchange uses."""
-        spec = self._spec(axis)
-        pd = spec.part_dim
-        extent = int(local_shape[pd])
-        if self.mode != "partitioned":
-            return ((0, extent),)
-        face_shape = tuple(
-            self.width if d == axis else int(local_shape[d])
-            for d in range(3)
-        )
-        return partition_bounds(
-            extent, self._face_partitions(face_shape, itemsize)
-        )
-
     # ---- cost/footprint metadata -----------------------------------------
 
     def messages_per_exchange(self) -> int:

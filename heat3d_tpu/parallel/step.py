@@ -48,10 +48,9 @@ def _log_step_path_once(msg: str) -> None:
 
 def _routed(fn, kernel, interpret=False, layout=None):
     """Record on a built step function the route its builder dispatched:
-    ``kernel`` is the Pallas kernel family ('direct', 'fused_dma',
-    'fused_rdma', 'streamk'), or None for the exchange path with the
-    padded-block compute; ``interpret`` says the kernel (or its XLA
-    reference contract) runs off-TPU under HEAT3D_DIRECT_INTERPRET;
+    ``kernel`` is the Pallas kernel family ('direct', 'streamk'), or None
+    for the exchange path with the padded-block compute; ``interpret``
+    says the kernel runs off-TPU under HEAT3D_DIRECT_INTERPRET;
     ``layout`` is the direct kernels' VMEM plane layout, 'aligned' or
     'framed' (ops.stencil_pallas_direct.direct_layout). Every other kernel
     family builds framed planes, and there is no layout without a kernel.
@@ -209,15 +208,12 @@ def _local_step(
         return _pin_padding(u_new, cfg)
 
 
-def _kernel_env_gate(cfg: SolverConfig, allow_partitioned_plan: bool = False):
+def _kernel_env_gate(cfg: SolverConfig):
     """Shared dispatch gate for the Mosaic kernel routes: returns
     ``(ok, interpret)`` — ok=False when the config/env rules out any real
     kernel (backend, padding, platform), interpret=True when
     HEAT3D_DIRECT_INTERPRET routes the kernel through the Pallas
-    interpreter off-TPU (tests). ``allow_partitioned_plan`` is the fused
-    RDMA route's carve-out: that kernel CONSUMES the partitioned plan
-    (its sends ride the sub-block schedule), so the knob selects rather
-    than vetoes it."""
+    interpreter off-TPU (tests)."""
     import os
 
     if cfg.backend not in ("pallas", "auto"):
@@ -225,18 +221,15 @@ def _kernel_env_gate(cfg: SolverConfig, allow_partitioned_plan: bool = False):
     if cfg.is_padded:
         return False, False
     if cfg.halo_order != "axis":
-        # the direct/fused kernel families synthesize or patch ghosts
+        # the direct/streamk kernel families synthesize or patch ghosts
         # assuming axis-ordered corner propagation; the pairwise ordering
         # A/B is an EXCHANGE-path knob, so it pins the exchange path
         return False, False
-    if cfg.halo_plan == "partitioned" and not allow_partitioned_plan:
+    if cfg.halo_plan == "partitioned":
         # partitioned early-bird sends are likewise an exchange-path
         # structure (the kernels never issue per-face collectives to
         # partition) — the A/B must measure the exchange path, not
-        # silently run a kernel that ignores the knob. The fused RDMA
-        # kernel is the one route that implements the knob in-kernel
-        # (per-sub-block remote-copy descriptors), so it passes
-        # allow_partitioned_plan=True.
+        # silently run a kernel that ignores the knob
         return False, False
     interpret = bool(os.environ.get("HEAT3D_DIRECT_INTERPRET"))
     forced = bool(os.environ.get("HEAT3D_DIRECT_FORCE"))
@@ -396,24 +389,13 @@ def _local_step_direct_faces(
         compute_dtype=compute_dtype,
         out_dtype=out_dtype,
     )
-    return _patch_boundary_shells(
-        out, u_local, faces, taps, cfg, (0, 1, 2), compute_dtype, out_dtype
-    )
-
-
-def _patch_boundary_shells(
-    out, u_local, faces, taps, cfg, axes, compute_dtype, out_dtype
-):
-    """Recompute the 1-deep shard-boundary shells of ``axes`` (where a
-    bulk kernel's in-register ghost synthesis was wrong) from virtual
-    padded slabs over the exchanged ``faces``, and patch them into
-    ``out``. Axes of mesh size 1 are skipped — local BC/wrap synthesis is
-    already exact there. Every op of the patches carries the
-    ``heat3d.shell`` scope."""
+    # the kernel's in-register ghosts are wrong only in the 1-deep
+    # shells of sharded axes: recompute those from virtual padded slabs
+    # over the exchanged faces and patch them in
     with named_phase("shell"):
-        for axis in axes:
-            if cfg.mesh.shape[axis] == 1:
-                continue
+        for axis, size in enumerate(cfg.mesh.shape):
+            if size == 1:
+                continue  # kernel's local BC/wrap is already exact here
             n = u_local.shape[axis]
             for start, pos in ((0, 0), (n - 1, n - 1)):
                 slab = _padded_slab(u_local, faces, axis, start)
@@ -509,133 +491,6 @@ def _local_superstep_direct_faces(
         return out
 
 
-def _fused_dma_gate(cfg: SolverConfig) -> bool:
-    """The shared kernel gate for the explicitly requested fused
-    DMA-overlap routes (overlap + halo='dma'): returns ``interpret``, or
-    raises naming what rules the kernels out — no other route may run
-    under the requested name."""
-    ok, interpret = _kernel_env_gate(cfg)
-    if not ok:
-        raise ValueError(
-            "halo='dma' + overlap needs the fused DMA-overlap kernels: "
-            "backend auto|pallas, unpadded shards, axis-ordered halos, a "
-            "monolithic halo plan, and a TPU (or HEAT3D_DIRECT_INTERPRET=1 "
-            f"for the reference emulation); got backend={cfg.backend!r}, "
-            f"padded={cfg.is_padded}, halo_order={cfg.halo_order!r}, "
-            f"halo_plan={cfg.halo_plan!r}, "
-            f"platform={jax.devices()[0].platform!r}"
-        )
-    return interpret
-
-
-def _fused_dma_route(cfg: SolverConfig, tb: int):
-    """Shared resolver for the fused DMA-overlap routes: the tb=1 step
-    kernel or the tb=2 superstep kernel, or None when the config is
-    outside the x-slab scope (the tb=1 dispatch then tries the x-sharded
-    block route). Raises when the kernel gate rules the route out. One
-    body so the two routes cannot drift."""
-    interpret = _fused_dma_gate(cfg)
-    from heat3d_tpu.ops.stencil_dma_fused import (
-        apply_step_fused_dma,
-        apply_superstep_fused_dma,
-        fused_dma2_supported,
-        fused_dma_supported,
-        reference_fused_step_xla,
-        reference_fused_superstep_xla,
-    )
-
-    supported, apply_fn, reference_fn = (
-        (fused_dma_supported, apply_step_fused_dma, reference_fused_step_xla)
-        if tb == 1
-        else (
-            fused_dma2_supported,
-            apply_superstep_fused_dma,
-            reference_fused_superstep_xla,
-        )
-    )
-    if not _fused_admits(cfg, tb, supported, "halo='dma' + overlap"):
-        return None
-    if interpret:
-        # Pallas' interpreter cannot discharge remote DMA on the
-        # production 3-named-axis meshes (jax 0.9) — the off-TPU
-        # emulation tier dispatches the kernels' pure-XLA reference
-        # contracts (certified equal on the 1D ring, where interpret CAN
-        # run the real kernels: tests/multidevice_checks.py)
-        return reference_fn
-    return apply_fn
-
-
-def _fused_admits(cfg: SolverConfig, tb: int, supported, route: str):
-    """Whether the fused x-sharded kernels' gate ``supported`` admits
-    ``cfg`` at ``tb``. When the chunking does not fit VMEM, an explicitly
-    requested route raises, naming the budget, instead of letting
-    dispatch run another route under its name."""
-    itemsize = jnp.dtype(cfg.precision.storage).itemsize
-    c_item = jnp.dtype(cfg.precision.compute).itemsize
-    taps = _solver_taps(cfg)
-    if supported(
-        cfg.local_shape, cfg.mesh.shape, taps, itemsize, itemsize, c_item
-    ):
-        return True
-    if cfg.mesh.shape[0] >= 2 and cfg.local_shape[0] >= 2 * tb:
-        from heat3d_tpu.ops.stencil_dma_fused import fused_vmem_refusal
-
-        why = fused_vmem_refusal(
-            cfg.local_shape, tb, itemsize, itemsize,
-            effective_num_taps(taps), c_item,
-        )
-        if why:
-            raise ValueError(f"{route} at tb={tb} refused: {why}")
-    return False
-
-
-def _fused_dma_fn(cfg: SolverConfig):
-    """Return the fused DMA-overlap kernel entry for this config, or None.
-
-    The route exists for overlap=True on the RDMA transport (SURVEY.md
-    §7.1 item 7): one Pallas kernel issues the x-face remote copies, sweeps
-    every x-interior output plane while they fly, and waits only for the
-    two shard-boundary planes. Scope gates mirror the kernel's
-    (ops/stencil_dma_fused.fused_dma_supported): 1D x-slab mesh, unpadded
-    shards, either stencil family. HEAT3D_NO_DIRECT does NOT disable this
-    route (deliberate asymmetry: that knob A/Bs the direct kernels against
-    the exchange path; this route is selected explicitly by
-    overlap+halo='dma')."""
-    if not (cfg.overlap and cfg.halo == "dma"):
-        return None
-    return _fused_dma_route(cfg, tb=1)
-
-
-def _fused_dma_3d_fn(cfg: SolverConfig):
-    """Return the fused DMA-overlap kernel entry for an x-sharded 3D/2D
-    block mesh, or None. Mutually exclusive with _fused_dma_fn's x-slab
-    scope (fused_dma_3d_supported requires a sharded y or z axis); the
-    step wrapper is _local_step_fused_dma_3d, which patches the y/z
-    shard-boundary shells the kernel's domain-BC synthesis got wrong.
-    tb=2 is out of scope — the 3D superstep keeps the faces-direct route
-    (make_superstep_fn)."""
-    if not (cfg.overlap and cfg.halo == "dma"):
-        return None
-    interpret = _fused_dma_gate(cfg)
-    from heat3d_tpu.ops.stencil_dma_fused import (
-        apply_step_fused_dma,
-        fused_dma_3d_supported,
-        reference_fused_step_xla,
-    )
-
-    if not _fused_admits(
-        cfg, 1, fused_dma_3d_supported, "halo='dma' + overlap"
-    ):
-        return None
-    if interpret:
-        # Pallas' interpreter cannot discharge remote DMA on a
-        # >1-named-axis mesh (jax 0.9), so the off-TPU emulation tier
-        # runs the kernel's pure-XLA reference contract instead — the
-        # glue (face seeding + shell patches) stays the production code
-        return reference_fused_step_xla
-    return apply_step_fused_dma
-
-
 def _fused_streamk_fn(cfg: SolverConfig):
     """Return the fused k-sweep streaming kernel entry for this config's
     ``time_blocking = k`` (2 <= k <= 4), or None.
@@ -653,8 +508,7 @@ def _fused_streamk_fn(cfg: SolverConfig):
     if k not in (2, 3, 4):
         return None
     if cfg.overlap:
-        # the overlap branch of make_superstep_fn (fused DMA-overlap tb=2
-        # or the mutual-exclusion error) runs before any streamk dispatch
+        # make_superstep_fn refuses overlap before any streamk dispatch
         return None
     ok, interpret = _kernel_env_gate(cfg)
     if not ok:
@@ -711,240 +565,6 @@ def redundant_flops_frac(cfg: SolverConfig) -> float:
     next to a modest effective rate."""
     raw, effective = superstep_cell_updates(cfg)
     return 0.0 if raw <= effective else 1.0 - effective / raw
-
-
-def _fused_dma2_fn(cfg: SolverConfig):
-    """The tb=2 analogue of _fused_dma_fn: the fused two-update superstep
-    with the width-2 halo DMA overlapped under the phase-A sweep, for
-    overlap=True + halo='dma' + time_blocking=2 on an x-slab mesh."""
-    if not (cfg.overlap and cfg.halo == "dma" and cfg.time_blocking == 2):
-        return None
-    return _fused_dma_route(cfg, tb=2)
-
-
-def resolve_fused_rdma(cfg: SolverConfig) -> str:
-    """The concrete fused-RDMA knob value for ``cfg`` in the current env:
-    ``HEAT3D_FUSED_RDMA`` overrides the config field (the A/B escape
-    hatch — '1'/'on'/'true' asks for the route, '0'/'off' stands it
-    down), and any ``'auto'`` still standing here takes the static
-    fallback (off) — same belt-and-braces posture as the other auto
-    knobs (tune.cache resolves 'auto' at the entry points)."""
-    import os
-
-    env = os.environ.get("HEAT3D_FUSED_RDMA")
-    if env is not None:
-        return (
-            "on"
-            if env.strip().lower() in ("1", "on", "true", "yes")
-            else "off"
-        )
-    mode = getattr(cfg, "fused_rdma", "off")
-    return "off" if mode == "auto" else mode
-
-
-def _fused_rdma_route(cfg: SolverConfig, tb: int):
-    """Shared resolver for the fused in-kernel RDMA superstep routes
-    (ops/stencil_fused_rdma — the plan-scheduled sibling of the fused
-    DMA-overlap family): the tb=1 step kernel or the tb=2 superstep
-    kernel with ``plan`` bound, or None when the knob/config/env/scope
-    gates reject. Unlike the fused-DMA route this one is selected by an
-    explicit knob (``fused_rdma='on'`` / HEAT3D_FUSED_RDMA) rather than
-    by overlap+halo='dma', and it is the one kernel route that CONSUMES
-    ``halo_plan='partitioned'`` (per-sub-block remote-copy descriptors
-    ride the plan's schedule), so it passes the gate's
-    allow_partitioned_plan carve-out."""
-    if resolve_fused_rdma(cfg) != "on":
-        return None
-    if cfg.overlap or cfg.halo == "dma":
-        # those knobs select the fused-DMA family; config validation
-        # rejects the combination, and an env-forced 'on' defers the
-        # same way rather than fight the explicit transport choice
-        return None
-    ok, interpret = _kernel_env_gate(cfg, allow_partitioned_plan=True)
-    if not ok:
-        raise ValueError(
-            "fused_rdma='on' needs the Mosaic kernels: backend auto|pallas, "
-            "unpadded shards, axis-ordered halos, and a TPU (or "
-            "HEAT3D_DIRECT_INTERPRET=1 for the reference emulation)"
-        )
-    from heat3d_tpu.ops.stencil_fused_rdma import (
-        apply_step_fused_rdma,
-        apply_superstep_fused_rdma,
-        fused_rdma2_supported,
-        fused_rdma_supported,
-        plan_send_bounds,
-        reference_fused_rdma_step_xla,
-        reference_fused_rdma_superstep_xla,
-    )
-    supported, apply_fn, reference_fn = (
-        (
-            fused_rdma_supported,
-            apply_step_fused_rdma,
-            reference_fused_rdma_step_xla,
-        )
-        if tb == 1
-        else (
-            fused_rdma2_supported,
-            apply_superstep_fused_rdma,
-            reference_fused_rdma_superstep_xla,
-        )
-    )
-    if not _fused_admits(cfg, tb, supported, "fused_rdma='on'"):
-        raise ValueError(
-            f"fused_rdma='on' at tb={tb} needs a 1D x-slab mesh (>= 2 "
-            f"devices along x) with >= {2 * tb} x-planes per shard; got "
-            f"mesh {cfg.mesh.shape}, local block {cfg.local_shape}"
-        )
-    itemsize = jnp.dtype(cfg.precision.storage).itemsize
-    import functools
-
-    from heat3d_tpu.parallel.plan import _event_once, plan_for
-
-    plan = plan_for(cfg, width=tb)
-    _event_once(
-        "fused_rdma_dispatch",
-        plan.key,
-        tb=tb,
-        emulated=bool(interpret),
-        parts=len(plan_send_bounds(plan, cfg.local_shape, itemsize)),
-    )
-    if interpret:
-        # same posture as the fused-DMA route: Pallas' interpreter
-        # cannot discharge remote DMA on the production 3-named-axis
-        # meshes (jax 0.9) — the off-TPU emulation tier dispatches the
-        # kernel's pure-XLA reference contract, certified bitwise
-        # against the real kernel on the 1D ring where interpret CAN
-        # run it (tests/multidevice_checks.py fused_rdma)
-        return functools.partial(reference_fn, plan=plan)
-    return functools.partial(apply_fn, plan=plan)
-
-
-def _fused_rdma_fn(cfg: SolverConfig):
-    """The fused in-kernel RDMA step entry for this config, or None.
-    Also serves the remainder single steps of a tb=2 run — the step and
-    superstep kernels coexist under distinct collective ids."""
-    return _fused_rdma_route(cfg, tb=1)
-
-
-def _fused_rdma2_fn(cfg: SolverConfig):
-    """The tb=2 analogue of _fused_rdma_fn: the plan-scheduled fused
-    superstep (k <= 2 is the route's temporal-blocking ceiling)."""
-    if cfg.time_blocking != 2:
-        return None
-    return _fused_rdma_route(cfg, tb=2)
-
-
-def _local_step_fused_rdma(
-    u_local: jax.Array,
-    taps: np.ndarray,
-    cfg: SolverConfig,
-    fused,
-) -> jax.Array:
-    """The fused in-kernel RDMA step/superstep (ops/stencil_fused_rdma):
-    same call surface as the fused-DMA wrapper — the ExchangePlan is
-    already bound in the route's partial. The named scope stays
-    "fused_dma" (PHASE_FUSED): exchange+stencil are one kernel here
-    too, the roofline/profile join keys per-phase cost on that one
-    vocabulary, and the bench row's ``fused_rdma_path`` field carries
-    the route identity."""
-    with named_phase("fused_dma"):
-        out = fused(
-            u_local,
-            taps,
-            axis_name=cfg.mesh.axis_names[0],
-            axis_size=cfg.mesh.shape[0],
-            mesh_axes=cfg.mesh.axis_names,
-            periodic=cfg.stencil.bc is BoundaryCondition.PERIODIC,
-            bc_value=cfg.stencil.bc_value,
-            compute_dtype=jnp.dtype(cfg.precision.compute),
-            out_dtype=jnp.dtype(cfg.precision.storage),
-        )
-        return _pin_padding(out, cfg)
-
-
-def _local_step_fused_dma(
-    u_local: jax.Array,
-    taps: np.ndarray,
-    cfg: SolverConfig,
-    fused,
-) -> jax.Array:
-    with named_phase("fused_dma"):
-        out = fused(
-            u_local,
-            taps,
-            axis_name=cfg.mesh.axis_names[0],
-            axis_size=cfg.mesh.shape[0],
-            mesh_axes=cfg.mesh.axis_names,
-            periodic=cfg.stencil.bc is BoundaryCondition.PERIODIC,
-            bc_value=cfg.stencil.bc_value,
-            compute_dtype=jnp.dtype(cfg.precision.compute),
-            out_dtype=jnp.dtype(cfg.precision.storage),
-        )
-        return _pin_padding(out, cfg)
-
-
-def _local_step_fused_dma_3d(
-    u_local: jax.Array,
-    taps: np.ndarray,
-    cfg: SolverConfig,
-    fused,
-) -> jax.Array:
-    """The fused DMA-overlap step on an x-sharded 3D/2D block mesh
-    (BASELINE.json configs 3-5; VERDICT r4 item 5's generalization).
-
-    The unchanged x-slab kernel sweeps the bulk with its x-face RDMA in
-    flight (y/z frames synthesized as domain boundaries — wrong only in
-    the outermost shell of each sharded y/z axis), and ALSO returns the
-    two landed ghost planes. Those planes then seed the axis-ordered
-    faces-only exchange (``exchange_halo_faces(x_ghosts=...)``) — the y/z
-    ppermutes carry the x-ghost corners exactly as the pure-ppermute form
-    does, with NO second x transfer — and the y/z shells are recomputed
-    and patched like the faces-direct step's.
-
-    Overlap structure: the x faces (the slab worst case of the traffic
-    model, BASELINE.md) ride under the sweep in-kernel; the y/z face
-    ppermutes serialize after the sweep because their send faces embed the
-    RDMA-landed ghosts. At the judged block configs' shard sizes those
-    faces are microseconds against a multi-hundred-microsecond sweep; the
-    pod A/B against faces-direct (scripts/pod_ab_fused.sh) decides whether
-    that trade wins."""
-    periodic = cfg.stencil.bc is BoundaryCondition.PERIODIC
-    compute_dtype = jnp.dtype(cfg.precision.compute)
-    out_dtype = jnp.dtype(cfg.precision.storage)
-    with named_phase("fused_dma"):
-        out, glo, ghi = fused(
-            u_local,
-            taps,
-            axis_name=cfg.mesh.axis_names[0],
-            axis_size=cfg.mesh.shape[0],
-            mesh_axes=cfg.mesh.axis_names,
-            periodic=periodic,
-            bc_value=cfg.stencil.bc_value,
-            compute_dtype=compute_dtype,
-            out_dtype=out_dtype,
-            return_ghosts=True,
-        )
-    # (ny, nz) -> (1, ny, nz) x-faces; Dirichlet x-edge devices substitute
-    # the BC over the landed wrap transfer, exactly as the kernel reads it
-    from heat3d_tpu.ops.stencil_dma_fused import substitute_dirichlet_x_edges
-
-    xlo, xhi = substitute_dirichlet_x_edges(
-        glo[None], ghi[None],
-        axis_name=cfg.mesh.axis_names[0],
-        axis_size=cfg.mesh.shape[0],
-        periodic=periodic,
-        bc_value=cfg.stencil.bc_value,
-    )
-    from heat3d_tpu.parallel.halo import exchange_halo_faces
-
-    faces = exchange_halo_faces(
-        u_local, cfg.mesh, cfg.stencil.bc, cfg.stencil.bc_value,
-        x_ghosts=(xlo, xhi),
-    )
-    out = _patch_boundary_shells(
-        out, u_local, faces, taps, cfg, (1, 2), compute_dtype, out_dtype
-    )
-    return _pin_padding(out, cfg)
 
 
 def _local_step_overlap(
@@ -1016,27 +636,7 @@ def make_step_fn(
     local_step = _local_step
     kernel = None
     layout = None
-    # fused_rdma='on' wins the route when its gates pass: the knob is an
-    # explicit opt-in, so it is dispatched ahead of the direct family
-    # (which would otherwise take the same scope)
-    fused_rdma = _fused_rdma_fn(cfg)
-    if fused_rdma is not None:
-        kernel = "fused_rdma"
-        interpret = _kernel_env_gate(cfg, allow_partitioned_plan=True)[1]
-        _log_step_path_once(
-            "step path: fused in-kernel RDMA superstep kernel "
-            "(plan-scheduled remote face copies under the sweep)"
-            + (" [XLA reference emulation]" if interpret else "")
-        )
-
-        def local_step(u_local, taps, cfg, compute_padded):
-            return _local_step_fused_rdma(u_local, taps, cfg, fused_rdma)
-
-    direct = (
-        None
-        if fused_rdma is not None
-        else _direct_kernel_fn(cfg, halo=1, multichip=True)
-    )
+    direct = _direct_kernel_fn(cfg, halo=1, multichip=True)
     if direct is not None:
         from heat3d_tpu.ops.stencil_pallas_direct import direct_layout
 
@@ -1074,52 +674,15 @@ def make_step_fn(
                     )
 
     if cfg.overlap and direct is None:
-        fused_dma = _fused_dma_fn(cfg)
-        fused_dma_3d = None if fused_dma is not None else _fused_dma_3d_fn(cfg)
-        interpret = _kernel_env_gate(cfg)[1]
-        emulated = " [XLA reference emulation]" if interpret else ""
-        if fused_dma is not None or fused_dma_3d is not None:
-            kernel = "fused_dma"
-        if fused_dma is not None:
-            _log_step_path_once(
-                "step path: fused DMA-overlap kernel (remote face copies "
-                "under the sweep)" + emulated
+        # jnp interior/boundary split — the portable overlap form; when
+        # the direct kernel dispatched above, the faces-direct step
+        # already overlaps the face ppermutes with the bulk sweep
+        if min(cfg.local_shape) < 3:
+            raise ValueError(
+                f"overlap=True needs local blocks >= 3 per axis to have "
+                f"an interior, got {cfg.local_shape}"
             )
-
-            def local_step(u_local, taps, cfg, compute_padded):
-                return _local_step_fused_dma(u_local, taps, cfg, fused_dma)
-
-        elif fused_dma_3d is not None:
-            _log_step_path_once(
-                "step path: fused DMA-overlap kernel + y/z shell patches "
-                "(x-sharded block mesh)" + emulated
-            )
-
-            def local_step(u_local, taps, cfg, compute_padded):
-                return _local_step_fused_dma_3d(
-                    u_local, taps, cfg, fused_dma_3d
-                )
-
-        else:
-            # jnp interior/boundary split — the portable overlap form; when
-            # the direct kernel dispatched above, the faces-direct step
-            # already overlaps the face ppermutes with the bulk sweep
-            if min(cfg.local_shape) < 3:
-                raise ValueError(
-                    f"overlap=True needs local blocks >= 3 per axis to have "
-                    f"an interior, got {cfg.local_shape}"
-                )
-            if cfg.halo == "dma":
-                raise ValueError(
-                    "overlap=True with halo='dma' needs the fused "
-                    "DMA-overlap kernel (a mesh with >= 2 devices along x "
-                    "— slab or x-sharded block, unpadded shards, TPU); "
-                    "outside "
-                    "that scope the side-effecting DMA exchange kernels "
-                    "cannot overlap with compute — use halo='ppermute' for "
-                    "XLA's async collective-permutes"
-                )
-            local_step = _local_step_overlap
+        local_step = _local_step_overlap
 
     # check_vma=False: pallas_call inside shard_map would otherwise require a
     # `vma` annotation on its out_shape (jax 0.9), and the kernel is built
@@ -1209,42 +772,10 @@ def make_superstep_fn(
     halo transport (ppermute or the width-k DMA slab exchange); requires no
     overlap split and local extents >= k."""
     if cfg.overlap:
-        # One combination earns its keep: halo='dma' + tb=2 on an x-slab
-        # mesh, where the fused two-update kernel overlaps the width-2
-        # slab DMA under its phase-A sweep (the tb=2 form of the fused
-        # DMA-overlap route).
-        fused2 = _fused_dma2_fn(cfg)
-        if fused2 is not None:
-            interpret = _kernel_env_gate(cfg)[1]
-            _log_step_path_once(
-                "superstep path: fused DMA-overlap direct2 kernel "
-                "(width-2 slab RDMA under the sweep)"
-                + (" [XLA reference emulation]" if interpret else "")
-            )
-            taps2 = _solver_taps(cfg)
-            spec2 = P(*cfg.mesh.axis_names)
-
-            def local_fused2(u_local):
-                return _local_step_fused_dma(u_local, taps2, cfg, fused2)
-
-            return _routed(
-                scoped(
-                    PHASE_STEP,
-                    shard_map(
-                        local_fused2, mesh=mesh, in_specs=spec2,
-                        out_specs=spec2, check_vma=False,
-                    ),
-                ),
-                "fused_dma",
-                interpret,
-            )
         raise ValueError(
             f"time_blocking={cfg.time_blocking} and overlap=True are "
             "mutually exclusive — the superstep already restructures the "
-            "exchange/compute schedule. The one supported combination is "
-            "the fused DMA-overlap superstep: halo='dma' + tb=2 on a 1D "
-            "x-slab mesh with >= 2 devices, local nx >= 4, unpadded "
-            "shards, on TPU"
+            "exchange/compute schedule"
         )
     # k ghost layers must fit the local block AND the shrinking-ring
     # intermediates need a genuine interior to recompute into: below 3
@@ -1259,35 +790,6 @@ def make_superstep_fn(
         )
     taps = _solver_taps(cfg)
     spec = P(*cfg.mesh.axis_names)
-
-    # fused_rdma='on' at tb=2: the plan-scheduled in-kernel RDMA
-    # superstep — both updates AND the width-2 remote copies in ONE
-    # kernel. Dispatched ahead of the direct2/streamk families: the knob
-    # is an explicit opt-in, so when its gates pass it wins the route.
-    if cfg.time_blocking == 2:
-        fused_rdma2 = _fused_rdma2_fn(cfg)
-        if fused_rdma2 is not None:
-            interpret = _kernel_env_gate(cfg, allow_partitioned_plan=True)[1]
-            _log_step_path_once(
-                "superstep path: fused in-kernel RDMA superstep kernel "
-                "(plan-scheduled width-2 remote copies under the sweep)"
-                + (" [XLA reference emulation]" if interpret else "")
-            )
-
-            def local_fr2(u_local):
-                return _local_step_fused_rdma(u_local, taps, cfg, fused_rdma2)
-
-            return _routed(
-                scoped(
-                    PHASE_STEP,
-                    shard_map(
-                        local_fr2, mesh=mesh, in_specs=spec, out_specs=spec,
-                        check_vma=False,
-                    ),
-                ),
-                "fused_rdma",
-                interpret,
-            )
 
     # k=2 with the BC-fused direct2 kernel: both updates in one sweep of the
     # UNPADDED field — no width-2 ghost copy at all. On multi-chip meshes
@@ -1601,7 +1103,6 @@ PHASE_STENCIL = "stencil"
 # the stencil scope; no compile target of its own (phase_programs)
 PHASE_SHELL = "shell"
 PHASE_HALO = "halo_exchange"
-PHASE_FUSED = "fused_dma"
 PHASE_RESIDUAL = "residual"
 
 # The canonical phase vocabulary, in roofline-table order — the
@@ -1610,8 +1111,7 @@ PHASE_RESIDUAL = "residual"
 # _phase_calls); obs/perf/timeline.normalize_phase folds trace scopes
 # onto the same names.
 PHASES = (
-    PHASE_STEP, PHASE_STENCIL, PHASE_SHELL, PHASE_HALO, PHASE_FUSED,
-    PHASE_RESIDUAL,
+    PHASE_STEP, PHASE_STENCIL, PHASE_SHELL, PHASE_HALO, PHASE_RESIDUAL,
 )
 
 
@@ -1637,11 +1137,6 @@ def phase_programs(
     - ``stencil``: the local tap application alone on locally-padded
       blocks (no collective) — the compute leg of the roofline.
     - ``residual``: the fp32 reduction + psum alone.
-    - ``fused_dma``: only when this config resolves to a fused
-      DMA-overlap route OR the fused in-kernel RDMA route (both scope
-      under this one phase name), where exchange+stencil are ONE kernel
-      and per-leg programs would misattribute: the full step program is
-      the honest program for the span of the same name.
 
     Callers jit + ``.lower(u).compile().cost_analysis()`` each to get the
     FLOPs/bytes the roofline report divides measured span time by.
@@ -1687,7 +1182,7 @@ def phase_programs(
             )
             return lax.psum(r, cfg.mesh.axis_names)
 
-    programs = {
+    return {
         PHASE_STEP: (
             make_superstep_fn(cfg, mesh, compute_padded)
             if cfg.time_blocking > 1
@@ -1697,6 +1192,3 @@ def phase_programs(
         PHASE_STENCIL: _sharded(_stencil_only),
         PHASE_RESIDUAL: _sharded(_residual_only, out_specs=P()),
     }
-    if programs[PHASE_STEP].route["kernel"] in ("fused_dma", "fused_rdma"):
-        programs[PHASE_FUSED] = programs[PHASE_STEP]
-    return programs

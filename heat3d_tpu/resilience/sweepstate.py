@@ -97,9 +97,6 @@ ROUTE_ENV_KNOBS = (
     # bypasses the exchange-plan layer (partitioned degrades to the
     # ad-hoc monolithic path — a different measured schedule)
     "HEAT3D_NO_PLAN",
-    # forces/stands down the fused in-kernel RDMA superstep route — a
-    # fused arm and an unfused arm must never share a journal entry
-    "HEAT3D_FUSED_RDMA",
 )
 
 
@@ -146,18 +143,10 @@ def row_key(cfg, bench: str = "throughput") -> str:
         if cfg.integrator == "explicit-euler"
         else f":ti{cfg.integrator}"
     )
-    # fused-RDMA leg (same non-default suffix rule): the EFFECTIVE knob
-    # value (env override / auto fallback resolved — one rule,
-    # parallel.step.resolve_fused_rdma), so a fused arm never resumes an
-    # unfused row while every pre-fused journal key stays byte-identical
-    from heat3d_tpu.parallel.step import resolve_fused_rdma
-
-    fr_mode = resolve_fused_rdma(cfg)
-    fr = "" if fr_mode == "off" else f":fr{fr_mode}"
     return (
         f"{bench}:g{g}:m{m}:{cfg.stencil.kind}:{cfg.precision.storage}"
         f":c{cfg.precision.compute}:b{cfg.backend}:tb{cfg.time_blocking}"
-        f":ov{int(cfg.overlap)}:h{cfg.halo}{ho}{hp}{eq}{ti}{fr}"
+        f":ov{int(cfg.overlap)}:h{cfg.halo}{ho}{hp}{eq}{ti}"
         + (f":env[{env_bits}]" if env_bits else "")
     )
 

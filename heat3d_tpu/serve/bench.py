@@ -100,10 +100,6 @@ def bench_ensemble_throughput(
         # to the ad-hoc monolithic schedule — the solo harness's rule,
         # one source: parallel.plan.effective_halo_plan)
         "halo_plan": effective_halo_plan(cfg),
-        # the fused in-kernel RDMA route never dispatches on the batched
-        # ensemble path (vmapped members; no shard_map kernel) — rows
-        # record what ran, so the knob keys to off here
-        "fused_rdma": "off",
         "steps": steps,
         "steps_requested": steps_requested,
         "seconds_best": best,
@@ -123,12 +119,8 @@ def bench_ensemble_throughput(
         "chain_ops": _chain_ops(cfg, mehrstellen=solver._mehrstellen),
         "mehrstellen_route": solver._mehrstellen,
         "direct_path": False,
-        "fused_dma_path": False,
-        "fused_dma_emulated": False,
         "streamk_path": False,
         "streamk_emulated": False,
-        "fused_rdma_path": False,
-        "fused_rdma_emulated": False,
         "cost_redundant_flops_frac": redundant_flops_frac(cfg),
         "cost_flops_per_step": None,
         "cost_bytes_per_step": None,

@@ -39,17 +39,17 @@ provenance discipline for everything the tuner measures.
 
 Resolution (:func:`resolve_config`) replaces ONLY the auto knobs —
 ``backend='auto'``, ``halo='auto'``, ``time_blocking=0``,
-``halo_plan='auto'``, ``fused_rdma='auto'`` — with the
-cached winner's values; explicit knobs are never overridden, and the
-mesh is never swapped (an explicitly chosen decomposition is the user's
-call; ``tune apply`` emits it as a flag instead). Every resolution lands
+``halo_plan='auto'`` — with the cached winner's values; explicit knobs
+are never overridden, and the mesh is never swapped (an explicitly
+chosen decomposition is the user's call; ``tune apply`` emits it as a
+flag instead). Every resolution lands
 in the run ledger as ``tune_cache_hit`` / ``tune_cache_miss`` /
 ``tune_cache_stale`` (stale = jax-version mismatch, schema drift, or a
 cached knob invalid in the current env, e.g. ``halo='dma'`` off-TPU);
 misses and staleness fall back to the static defaults (halo
-``ppermute``, time_blocking 1, halo_plan ``monolithic``, fused_rdma
-``off``, backend left ``auto``). Resolution fails
-soft: no cache error can kill the run being configured.
+``ppermute``, time_blocking 1, halo_plan ``monolithic``, backend left
+``auto``). Resolution fails soft: no cache error can kill the run being
+configured.
 
 ``HEAT3D_TUNE_CACHE`` overrides the store path (default
 ``~/.cache/heat3d/tune_cache.json``); ``HEAT3D_TUNE_DISABLE=1`` skips
@@ -76,7 +76,6 @@ SCHEMA_VERSION = 1
 # the knobs an entry's config must carry (lint + resolution contract)
 CONFIG_KNOBS = (
     "backend", "halo", "overlap", "time_blocking", "halo_order", "halo_plan",
-    "fused_rdma",
 )
 
 # in-process memo: (path) -> (mtime_ns, doc). One stat per lookup instead
@@ -179,7 +178,6 @@ def config_knobs(cfg: SolverConfig) -> Dict[str, Any]:
         "time_blocking": int(cfg.time_blocking),
         "halo_order": cfg.halo_order,
         "halo_plan": cfg.halo_plan,
-        "fused_rdma": cfg.fused_rdma,
         "mesh": list(cfg.mesh.shape),
         "equation": cfg.equation,
         "eq_params": [[k, v] for k, v in cfg.eq_params],
@@ -364,7 +362,7 @@ def lint(path: Optional[str] = None) -> List[str]:
             tb = cfgd.get("time_blocking")
             if tb is not None and (not isinstance(tb, int) or tb < 1):
                 bad.append(f"{where}: time_blocking {tb!r} not an int >= 1")
-            for knob in ("backend", "halo", "halo_plan", "fused_rdma"):
+            for knob in ("backend", "halo", "halo_plan"):
                 if cfgd.get(knob) == "auto":
                     bad.append(
                         f"{where}: {knob}='auto' is not a concrete route "
@@ -403,8 +401,6 @@ def _static_fallback(cfg: SolverConfig) -> SolverConfig:
         kw["time_blocking"] = 1
     if cfg.halo_plan == "auto":
         kw["halo_plan"] = "monolithic"
-    if cfg.fused_rdma == "auto":
-        kw["fused_rdma"] = "off"
     return dataclasses.replace(cfg, **kw) if kw else cfg
 
 
@@ -418,8 +414,6 @@ def _auto_knobs(cfg: SolverConfig) -> List[str]:
         autos.append("time_blocking")
     if cfg.halo_plan == "auto":
         autos.append("halo_plan")
-    if cfg.fused_rdma == "auto":
-        autos.append("fused_rdma")
     return autos
 
 
@@ -480,8 +474,8 @@ def _resolved_invalid(resolved: SolverConfig) -> Optional[str]:
     None. Runs the real builders (mesh + backend selection + the
     multistep program — jit wrappers only, nothing compiles), so the
     gates are the production gates: a cached backend='pallas' the current
-    local shape doesn't support, or a cached overlap/tb combination
-    outside the fused scope, degrades to the static fallback instead of
+    local shape doesn't support, or a cached overlap/tb combination,
+    degrades to the static fallback instead of
     killing the run at solver construction."""
     try:
         from heat3d_tpu.models.heat3d import _select_backend
@@ -547,7 +541,6 @@ def _resolve(
         or kw.get("backend") == "auto"
         or kw.get("time_blocking") == 0
         or kw.get("halo_plan") == "auto"
-        or kw.get("fused_rdma") == "auto"
     ):
         return _stale("entry carries unresolved auto knobs")
     try:

@@ -22,7 +22,7 @@ Usage (via the wrapper)::
 
 By default only lines after the LAST session header in each file are
 considered — any of the ``SESSION_HEADERS`` prefixes
-(``=== tpu_measure_all``, ``=== pod_ab_fused``) starts a session (a log
+(``=== tpu_measure_all``) starts a session (a log
 accumulates many sessions; stale A/Bs from an older kernel would corrupt
 the decision).
 ``--min-win`` (default 5.0) is the speedup percentage below which the
@@ -39,7 +39,7 @@ import sys
 
 # any of these starts a measurement session; scoping keeps only lines
 # after the LAST header present in the file (stale-session protection)
-SESSION_HEADERS = ("=== tpu_measure_all", "=== pod_ab_fused")
+SESSION_HEADERS = ("=== tpu_measure_all",)
 _LINE = re.compile(r"^([A-Za-z0-9_=/. -]+?):\s*(\{.*\})\s*$")
 # bench-harness rows vs CLI summary lines (stage 3g logs the latter) name
 # the throughput metric differently; first present key wins

@@ -5,11 +5,11 @@ A candidate is ``base config + knob overrides``. Pruning reuses the
 framework's OWN validation instead of a parallel rule set that would
 drift: ``SolverConfig.__post_init__`` rejects structurally invalid
 combos (pairwise ordering with a corner-reading stencil, dma+pairwise,
-...), and :func:`prune_reason` then builds the solver and forces the
-multistep program the hot loop would run — every capability gate the
-real run would hit (dma off-TPU, pallas unsupported here, overlap
-local-extent minima, overlap/tb mutual exclusion outside the fused-DMA
-scope) raises the same ``ValueError`` it would raise in production, and
+dma+overlap, ...), and :func:`prune_reason` then builds the solver and
+forces the multistep program the hot loop would run — every capability
+gate the real run would hit (dma off-TPU, pallas unsupported here,
+overlap local-extent minima, overlap/tb mutual exclusion) raises the
+same ``ValueError`` it would raise in production, and
 the candidate is pruned with that exact message instead of burning
 measurement time. Solver construction builds jit WRAPPERS only (no
 trace, no compile), so pruning costs milliseconds per candidate.
@@ -51,12 +51,6 @@ DEFAULT_KNOBS: Dict[str, Tuple[Any, ...]] = {
     # the A/B is purely a transport-schedule measurement; dma+partitioned
     # combos are config-rejected and pruned.
     "halo_plan": ("monolithic", "partitioned"),
-    # fused in-kernel RDMA superstep (ops/stencil_fused_rdma): the halo
-    # transfers ride inside the stencil kernel itself. Value-identical to
-    # the unfused route (certified on the interpret tier), so the A/B is
-    # a pure overlap measurement; dma/overlap/pairwise/deep-tb combos are
-    # config-rejected and pruned.
-    "fused_rdma": ("off", "on"),
 }
 
 # knob-value parsers for CLI `--knob name=v1,v2` strings
@@ -93,7 +87,7 @@ def parse_knob_values(name: str, spec: str) -> Tuple[Any, ...]:
                 raise ValueError(f"mesh value {tok!r} (want PxQxR)")
             vals.append(dims)
         else:
-            if name in ("halo", "halo_plan", "fused_rdma") and tok == "auto":
+            if name in ("halo", "halo_plan") and tok == "auto":
                 raise ValueError(
                     f"searched {name} values must be concrete: 'auto' "
                     "means 'resolve through the cache this search is "
@@ -114,7 +108,7 @@ def check_concrete(space: Dict[str, Sequence[Any]]) -> None:
     for name, values in space.items():
         for v in values:
             if (name == "time_blocking" and isinstance(v, int) and v < 1) or (
-                name in ("halo", "halo_plan", "fused_rdma") and v == "auto"
+                name in ("halo", "halo_plan") and v == "auto"
             ):
                 raise ValueError(
                     f"search space knob {name}={v!r} is not concrete — "

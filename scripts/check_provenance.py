@@ -13,10 +13,10 @@ class of gap loud at measurement time instead of at judging time: it FAILS
 - has ``ts`` missing, null, or empty (a row that cannot prove which
   session measured it), or
 - is a throughput row missing its route-provenance fields (``platform``,
-  ``direct_path``, ``mehrstellen_route``, ``fused_dma_path``,
-  ``fused_dma_emulated``, ``streamk_path``, ``streamk_emulated``,
-  ``chain_ops`` — ``chain_ops: null`` is legal only for ``backend:
-  conv``, where a tap-chain op count does not exist), or
+  ``direct_path``, ``mehrstellen_route``, ``streamk_path``,
+  ``streamk_emulated``, ``halo_plan``, ``chain_ops`` — ``chain_ops:
+  null`` is legal only for ``backend: conv``, where a tap-chain op count
+  does not exist), or
 - is a ``time_blocking > 1`` throughput row missing a numeric
   ``cost_redundant_flops_frac`` (deep-tb recompute honesty), or
 - is a halo row missing ``platform``, or

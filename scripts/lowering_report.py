@@ -41,35 +41,14 @@ from heat3d_tpu.core.config import (
 from heat3d_tpu.parallel.step import make_step_fn, make_superstep_fn
 from heat3d_tpu.parallel.topology import abstract_mesh, lower_for_mesh
 
-# (label, judged grid, mesh, stencil, precision, tb, halo, overlap)
-# — BASELINE.json configs
+# (label, judged grid, mesh, stencil, precision, tb) — BASELINE.json
+# configs
 CONFIGS = [
-    ("2: 1024^3 slab v5p-8", 1024, (8, 1, 1), "7pt", Precision.fp32(), 1,
-     "ppermute", False),
-    ("3: 2048^3 block v5p-8", 2048, (2, 2, 2), "7pt", Precision.fp32(), 1,
-     "ppermute", False),
-    ("4: 4096^3 27pt v5p-64", 4096, (4, 4, 4), "27pt", Precision.fp32(), 1,
-     "ppermute", False),
-    ("5: 4096^3 bf16 v5p-128", 4096, (8, 4, 4), "7pt", Precision.bf16(), 1,
-     "ppermute", False),
-    ("2+tb: 1024^3 slab, tb=2", 1024, (8, 1, 1), "7pt", Precision.fp32(), 2,
-     "ppermute", False),
-    # the fused DMA-overlap kernels: zero collective_permutes by design —
-    # the halo rides kernel-initiated RDMA inside the one Mosaic custom
-    # call (SURVEY §7.1 item 7); tb=2 = the fused two-update superstep
-    # with the width-2 slab DMA under its phase-A sweep
-    ("2+fused: 1024^3 slab, RDMA overlap", 1024, (8, 1, 1), "7pt",
-     Precision.fp32(), 1, "dma", True),
-    ("2+fused2: 1024^3 slab, RDMA overlap tb=2", 1024, (8, 1, 1), "7pt",
-     Precision.fp32(), 2, "dma", True),
-    # the 3D-block generalization (VERDICT r4 item 5): x faces ride the
-    # in-kernel RDMA, y/z faces stay ppermutes seeded by the landed
-    # ghosts, y/z shells patched — expected permutes = 2 per sharded
-    # y/z axis, and the Mosaic call still present
-    ("3+fused: 2048^3 block, RDMA-x overlap", 2048, (2, 2, 2), "7pt",
-     Precision.fp32(), 1, "dma", True),
-    ("5+fused: 4096^3 bf16 block, RDMA-x overlap", 4096, (8, 4, 4), "7pt",
-     Precision.bf16(), 1, "dma", True),
+    ("2: 1024^3 slab v5p-8", 1024, (8, 1, 1), "7pt", Precision.fp32(), 1),
+    ("3: 2048^3 block v5p-8", 2048, (2, 2, 2), "7pt", Precision.fp32(), 1),
+    ("4: 4096^3 27pt v5p-64", 4096, (4, 4, 4), "27pt", Precision.fp32(), 1),
+    ("5: 4096^3 bf16 v5p-128", 4096, (8, 4, 4), "7pt", Precision.bf16(), 1),
+    ("2+tb: 1024^3 slab, tb=2", 1024, (8, 1, 1), "7pt", Precision.fp32(), 2),
 ]
 
 
@@ -81,73 +60,38 @@ def count(txt: str, op: str) -> int:
     return len(re.findall(rf"\b{pat}\b", txt))
 
 
-def lower_one(label, judged, mesh_shape, kind, prec, tb, halo, overlap):
+def lower_one(label, judged, mesh_shape, kind, prec, tb):
     # small local blocks, same topology: collective structure is identical
     local = 8
     grid = tuple(local * m for m in mesh_shape)
-    fused = halo == "dma" and overlap
     cfg = SolverConfig(
         grid=GridConfig(shape=grid),
         stencil=StencilConfig(kind=kind, bc=BoundaryCondition.DIRICHLET),
         mesh=MeshConfig(shape=mesh_shape),
         precision=prec,
-        # portable lowering for the collective rows; the fused-DMA row
-        # must dispatch the real Mosaic kernel (HEAT3D_DIRECT_FORCE below)
-        backend="auto" if fused else "jnp",
+        backend="jnp",
         time_blocking=tb,
-        halo=halo,
-        overlap=overlap,
     )
     am = abstract_mesh(cfg.mesh)
-    prior = os.environ.get("HEAT3D_DIRECT_FORCE")
-    prior_interp = os.environ.get("HEAT3D_DIRECT_INTERPRET")
-    if fused:
-        os.environ["HEAT3D_DIRECT_FORCE"] = "1"
-        # a stale interpret knob would override FORCE at the dispatch gate
-        # and lower plain JAX ops instead of the Mosaic call
-        os.environ.pop("HEAT3D_DIRECT_INTERPRET", None)
-    try:
-        if tb > 1:
-            fn = make_superstep_fn(cfg, am)
-        else:
-            fn = make_step_fn(cfg, am, with_residual=True)
-        dtype = jnp.dtype(prec.storage)
-        txt = lower_for_mesh(
-            fn, cfg.mesh, (grid, dtype, P("x", "y", "z"))
-        ).as_text()
-    finally:
-        if fused:
-            if prior is None:
-                os.environ.pop("HEAT3D_DIRECT_FORCE", None)
-            else:
-                os.environ["HEAT3D_DIRECT_FORCE"] = prior
-            if prior_interp is not None:
-                os.environ["HEAT3D_DIRECT_INTERPRET"] = prior_interp
-    nchips = cfg.mesh.num_devices
+    if tb > 1:
+        fn = make_superstep_fn(cfg, am)
+    else:
+        fn = make_step_fn(cfg, am, with_residual=True)
+    dtype = jnp.dtype(prec.storage)
+    txt = lower_for_mesh(fn, cfg.mesh, (grid, dtype, P("x", "y", "z"))).as_text()
     sharded_axes = sum(1 for m in mesh_shape if m > 1)
     return {
         "label": label,
         "judged": f"{judged}^3",
         "lowered": "x".join(map(str, grid)),
         "mesh": "x".join(map(str, mesh_shape)),
-        "chips": nchips,
+        "chips": cfg.mesh.num_devices,
         "stencil": kind,
         "dtype": str(dtype),
         "tb": tb,
         "permutes": count(txt, "collective_permute"),
         "allreduce": count(txt, "all_reduce"),
-        "custom_calls": count(txt, "tpu_custom_call"),
-        "sharded_axes": sharded_axes,
-        # the fused-DMA routes' x halo is RDMA inside the custom call:
-        # slab rows expect 0 permutes, 3D-block rows keep the 2-per-axis
-        # y/z face ppermutes (seeded by the landed x ghosts); at least
-        # one Mosaic call must appear either way
-        "expect_permutes": (
-            2 * sum(1 for m in mesh_shape[1:] if m > 1)
-            if fused
-            else 2 * sharded_axes
-        ),
-        "expect_custom_calls_min": 1 if fused else 0,
+        "expect_permutes": 2 * sharded_axes,
     }
 
 
@@ -169,13 +113,7 @@ def main(argv=None) -> int:
         "fp32 residual — its MPI_Allreduce). Expected permute count:",
         "2 directions per SHARDED mesh axis (size-1 axes short-circuit to",
         "local wraps/BC fills), independent of grid size; tb=2 supersteps",
-        "exchange width-2 ghosts in the same 2-per-axis pattern. The",
-        "fused-DMA slab rows expect ZERO permutes: their halo is",
-        "kernel-initiated RDMA inside the Mosaic custom call",
-        "(`tpu_custom_call` >= 1). The fused 3D-block rows keep 2 permutes",
-        "per sharded y/z axis — the y/z faces stay ppermutes, seeded by",
-        "the RDMA-landed x ghosts (no second x transfer), with the y/z",
-        "shard-boundary shells patched after the sweep.",
+        "exchange width-2 ghosts in the same 2-per-axis pattern.",
         "",
         "Beyond compile-only: the judged pod topologies also EXECUTE at",
         "tiny scale on virtual CPU meshes — (4,4,4) over 64 devices and",
@@ -183,24 +121,18 @@ def main(argv=None) -> int:
         "(tests/test_multidevice.py::test_judged_pod_topology_executes).",
         "",
         "| Config | Judged grid | Lowered grid | Mesh | Chips | Stencil |"
-        " Dtype | tb | collective_permute | all_reduce | tpu_custom_call |",
-        "|---|---|---|---|---|---|---|---|---|---|---|",
+        " Dtype | tb | collective_permute | all_reduce |",
+        "|---|---|---|---|---|---|---|---|---|---|",
     ]
     ok = True
     for r in rows:
         want = r["expect_permutes"]
         flag = "" if r["permutes"] == want else f" (expected {want}!)"
         ok = ok and r["permutes"] == want
-        cflag = (
-            "" if r["custom_calls"] >= r["expect_custom_calls_min"]
-            else " (expected >= 1!)"
-        )
-        ok = ok and r["custom_calls"] >= r["expect_custom_calls_min"]
         lines.append(
             f"| {r['label']} | {r['judged']} | {r['lowered']} | {r['mesh']} |"
             f" {r['chips']} | {r['stencil']} | {r['dtype']} | {r['tb']} |"
             f" {r['permutes']}{flag} | {r['allreduce']} |"
-            f" {r['custom_calls']}{cflag} |"
         )
     lines.append("")
     text = "\n".join(lines)

@@ -525,357 +525,6 @@ def check_dma_halo_ring_interpret():
     print("dma_halo_ring_interpret OK (axes 0-2, widths 1-4)")
 
 
-def check_fused_dma_overlap_ring_interpret():
-    """Fused DMA-overlap step (remote face copies issued at grid step 0,
-    interior sweep while in flight, boundary planes after the waits —
-    SURVEY.md §7.1 item 7) on a real 8-device ring == the single-device
-    oracle, both BCs, single- and multi-chunk-column modes. Runs on a 1D
-    named mesh for the same jax-0.9 interpret-mode reason as
-    check_dma_halo_ring_interpret; the production 3-axis-mesh dispatch is
-    covered by the TPU cross-lowering tests (tests/test_dma_fused.py)."""
-    from jax.sharding import Mesh, NamedSharding
-
-    import heat3d_tpu.ops.stencil_dma_fused as fused_mod
-    from heat3d_tpu.core.config import GridConfig
-    from heat3d_tpu.ops.stencil_jnp import step_single_device
-
-    grid = (16, 16, 16)
-    gc = GridConfig(shape=grid)
-    u_host = golden.random_init(grid, seed=31)
-    mesh = Mesh(np.array(jax.devices()).reshape(8), ("x",))
-    spec = P("x")
-    orig_chunk = fused_mod.choose_chunk
-    # One matrix over {stencil} x {precision tier} x {chunk mode} x {BC}:
-    # 27pt exercises the ghost-plane FRAMES (its x-plane taps read them —
-    # the x-slab-has-no-corner-neighbors property the widened gate rests
-    # on); fp32 matches to FMA rounding; bf16 storage / fp32 compute (the
-    # judged config-5 flavor, 2-byte itemsize exercising the ghost-row
-    # loads and ring tiles at bf16 geometry) matches to 1 bf16 ulp (2^-8)
-    # — kernel vs jnp accumulate in different association orders before
-    # the one storage-dtype round-off.
-    tiers = [
-        (jnp.asarray(u_host), Precision(), 1e-6),
-        (jnp.asarray(u_host).astype(jnp.bfloat16), Precision.bf16(), 4e-3),
-    ]
-    try:
-        for kind in ("7pt", "27pt"):
-            taps = stencil_taps(
-                STENCILS[kind], gc.alpha, gc.effective_dt(), gc.spacing
-            )
-            for u_in, prec, tol in tiers:
-                u_dev = jax.device_put(u_in, NamedSharding(mesh, spec))
-                for by in (None, 8):  # None = real chooser; 8 = 2 chunks
-                    fused_mod.choose_chunk = (
-                        orig_chunk if by is None
-                        else lambda *a, _by=by, **k: _by
-                    )
-                    for bc, bcv in [
-                        (BoundaryCondition.DIRICHLET, 1.5),
-                        (BoundaryCondition.PERIODIC, 0.0),
-                    ]:
-                        got = jax.jit(
-                            shard_map(
-                                lambda x, t=taps,
-                                p=bc is BoundaryCondition.PERIODIC,
-                                v=bcv: fused_mod.apply_step_fused_dma(
-                                    x, t, axis_name="x", axis_size=8,
-                                    mesh_axes=("x",), periodic=p,
-                                    bc_value=v, interpret=True,
-                                ),
-                                mesh=mesh, in_specs=spec, out_specs=spec,
-                                check_vma=False,
-                            )
-                        )(u_dev)
-                        want = step_single_device(
-                            u_in, taps, bc, bcv, precision=prec
-                        )
-                        assert got.dtype == jnp.dtype(prec.storage)
-                        assert want.dtype == jnp.dtype(prec.storage)
-                        np.testing.assert_allclose(
-                            np.asarray(got.astype(jnp.float32)),
-                            np.asarray(want.astype(jnp.float32)),
-                            rtol=tol, atol=tol,
-                            err_msg=(
-                                f"{kind} dtype={prec.storage} by={by} "
-                                f"bc={bc}"
-                            ),
-                        )
-    finally:
-        fused_mod.choose_chunk = orig_chunk
-    print(
-        "fused_dma_overlap_ring_interpret OK "
-        "(7pt+27pt, fp32+bf16, single+multi chunk, both BCs)"
-    )
-
-
-def check_fused_dma2_superstep_ring_interpret():
-    """The tb=2 fused DMA-overlap superstep (width-2 slab RDMA under the
-    phase-A sweep, epilogue recomputes the boundary mids) on a real
-    8-device ring == TWO single-device oracle steps — the same
-    mid-through-storage-dtype round trip as the unfused superstep. Same
-    1D-mesh interpret-mode scope as the other DMA tiers."""
-    from jax.sharding import Mesh, NamedSharding
-
-    import heat3d_tpu.ops.stencil_dma_fused as fused_mod
-    from heat3d_tpu.core.config import GridConfig
-    from heat3d_tpu.ops.stencil_jnp import step_single_device
-
-    grid = (32, 16, 16)  # 4 x-planes/shard: the tb=2 kernel's minimum
-    gc = GridConfig(shape=grid)
-    u_host = golden.random_init(grid, seed=41)
-    mesh = Mesh(np.array(jax.devices()).reshape(8), ("x",))
-    spec = P("x")
-    orig_chunk = fused_mod.choose_chunk
-    tiers = [
-        (jnp.asarray(u_host), Precision(), 1e-6,
-         [(BoundaryCondition.DIRICHLET, 1.5),
-          (BoundaryCondition.PERIODIC, 0.0)]),
-        # bf16: the mid's bf16 storage round trip must match two unfused
-        # bf16 steps; 2 chained updates => 2 bf16 ulps
-        (jnp.asarray(u_host).astype(jnp.bfloat16), Precision.bf16(), 8e-3,
-         [(BoundaryCondition.DIRICHLET, 1.5)]),
-    ]
-    try:
-        for kind in ("7pt", "27pt"):
-            taps = stencil_taps(
-                STENCILS[kind], gc.alpha, gc.effective_dt(), gc.spacing
-            )
-            for u_in, prec, tol, bcs in tiers:
-                u_dev = jax.device_put(u_in, NamedSharding(mesh, spec))
-                for by in (None, 8):
-                    fused_mod.choose_chunk = (
-                        orig_chunk if by is None
-                        else lambda *a, _by=by, **k: _by
-                    )
-                    for bc, bcv in bcs:
-                        got = jax.jit(
-                            shard_map(
-                                lambda x, t=taps,
-                                p=bc is BoundaryCondition.PERIODIC,
-                                v=bcv: fused_mod.apply_superstep_fused_dma(
-                                    x, t, axis_name="x", axis_size=8,
-                                    mesh_axes=("x",), periodic=p,
-                                    bc_value=v, interpret=True,
-                                ),
-                                mesh=mesh, in_specs=spec, out_specs=spec,
-                                check_vma=False,
-                            )
-                        )(u_dev)
-                        want = step_single_device(
-                            step_single_device(
-                                u_in, taps, bc, bcv, precision=prec
-                            ),
-                            taps, bc, bcv, precision=prec,
-                        )
-                        assert got.dtype == jnp.dtype(prec.storage)
-                        np.testing.assert_allclose(
-                            np.asarray(got.astype(jnp.float32)),
-                            np.asarray(want.astype(jnp.float32)),
-                            rtol=tol, atol=tol,
-                            err_msg=(
-                                f"tb2 {kind} dtype={prec.storage} "
-                                f"by={by} bc={bc}"
-                            ),
-                        )
-    finally:
-        fused_mod.choose_chunk = orig_chunk
-    print(
-        "fused_dma2_superstep_ring_interpret OK "
-        "(7pt+27pt, fp32+bf16, single+multi chunk)"
-    )
-
-
-def check_fused_dma_ghost_outputs_ring_interpret():
-    """apply_step_fused_dma(return_ghosts=True) on the 8-device ring: the
-    step output still matches the oracle, and the landed ghost planes are
-    exactly the neighbor faces the RDMA ring delivers (torus wrap — the
-    transfer always runs; Dirichlet substitution happens at READ time,
-    in-kernel and in the 3D route's glue)."""
-    from jax.sharding import Mesh, NamedSharding
-
-    import heat3d_tpu.ops.stencil_dma_fused as fused_mod
-    from heat3d_tpu.core.config import GridConfig
-    from heat3d_tpu.ops.stencil_jnp import step_single_device
-
-    grid = (16, 16, 16)
-    gc = GridConfig(shape=grid)
-    taps = stencil_taps(STENCILS["7pt"], gc.alpha, gc.effective_dt(), gc.spacing)
-    u_host = golden.random_init(grid, seed=53)
-    u = jnp.asarray(u_host)
-    mesh = Mesh(np.array(jax.devices()).reshape(8), ("x",))
-    spec = P("x")
-    u_dev = jax.device_put(u, NamedSharding(mesh, spec))
-    bc, bcv = BoundaryCondition.DIRICHLET, 1.5
-    out, glo, ghi = jax.jit(
-        shard_map(
-            lambda x: fused_mod.apply_step_fused_dma(
-                x, taps, axis_name="x", axis_size=8, mesh_axes=("x",),
-                periodic=False, bc_value=bcv, interpret=True,
-                return_ghosts=True,
-            ),
-            mesh=mesh, in_specs=spec,
-            out_specs=(spec, spec, spec), check_vma=False,
-        )
-    )(u_dev)
-    want = step_single_device(u, taps, bc, bcv)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(want), rtol=1e-6, atol=1e-6
-    )
-    # shard i's ghosts: glo = global plane (2i-1) mod 16, ghi = plane
-    # (2i+2) mod 16 (nx=2 per shard, ring wrap)
-    nxl = grid[0] // 8
-    glo_g = np.asarray(glo).reshape(8, grid[1], grid[2])
-    ghi_g = np.asarray(ghi).reshape(8, grid[1], grid[2])
-    for i in range(8):
-        np.testing.assert_array_equal(
-            glo_g[i], u_host[(i * nxl - 1) % grid[0]]
-        )
-        np.testing.assert_array_equal(
-            ghi_g[i], u_host[((i + 1) * nxl) % grid[0]]
-        )
-    print("fused_dma_ghost_outputs_ring_interpret OK")
-
-
-def check_fused_dma_3d_glue():
-    """The 3D fused-DMA route's glue (parallel/step._local_step_fused_dma_3d:
-    landed-ghost reuse as x faces, axis-ordered y/z face completion via
-    exchange_halo_faces(x_ghosts=...), y/z shell patches) on REAL
-    x-sharded block meshes == the single-device oracle — with the kernel
-    replaced by its XLA reference contract (reference_fused_step_xla).
-    Covers 7pt+27pt (corner propagation through the seeded faces),
-    both BCs, fp32 + bf16-storage/fp32-compute, meshes (2,2,2)/(2,4,1)/
-    (2,1,4)."""
-    from heat3d_tpu.ops.stencil_dma_fused import reference_fused_step_xla
-    from heat3d_tpu.ops.stencil_jnp import step_single_device
-    from heat3d_tpu.parallel.step import _local_step_fused_dma_3d
-
-    grid = (8, 16, 16)
-    gc = GridConfig(shape=grid)
-    u_host = golden.random_init(grid, seed=61)
-    tiers = [
-        (jnp.asarray(u_host), Precision(), 1e-6),
-        (jnp.asarray(u_host).astype(jnp.bfloat16), Precision.bf16(), 4e-3),
-    ]
-    for mesh_shape in [(2, 2, 2), (2, 4, 1), (2, 1, 4)]:
-        for kind in ("7pt", "27pt"):
-            taps = stencil_taps(
-                STENCILS[kind], gc.alpha, gc.effective_dt(), gc.spacing
-            )
-            for u_in, prec, tol in tiers:
-                for bc, bcv in [
-                    (BoundaryCondition.DIRICHLET, 1.5),
-                    (BoundaryCondition.PERIODIC, 0.0),
-                ]:
-                    cfg = SolverConfig(
-                        grid=GridConfig(shape=grid),
-                        stencil=StencilConfig(kind=kind, bc=bc, bc_value=bcv),
-                        mesh=MeshConfig(shape=mesh_shape),
-                        precision=prec,
-                        backend="jnp",
-                        halo="dma",
-                        overlap=True,
-                    )
-                    mesh = build_mesh(cfg.mesh)
-                    sharding = field_sharding(mesh, cfg.mesh)
-                    u_dev = jax.device_put(u_in, sharding)
-                    spec = P(*cfg.mesh.axis_names)
-                    got = jax.jit(
-                        shard_map(
-                            lambda x, t=taps, c=cfg:
-                            _local_step_fused_dma_3d(
-                                x, t, c, reference_fused_step_xla
-                            ),
-                            mesh=mesh, in_specs=spec, out_specs=spec,
-                            check_vma=False,
-                        )
-                    )(u_dev)
-                    want = step_single_device(
-                        u_in, taps, bc, bcv, precision=prec
-                    )
-                    assert got.dtype == jnp.dtype(prec.storage)
-                    np.testing.assert_allclose(
-                        np.asarray(got.astype(jnp.float32)),
-                        np.asarray(want.astype(jnp.float32)),
-                        rtol=tol, atol=tol,
-                        err_msg=(
-                            f"3d-glue {kind} mesh={mesh_shape} bc={bc} "
-                            f"dtype={prec.storage}"
-                        ),
-                    )
-    print(
-        "fused_dma_3d_glue OK (7pt+27pt, fp32+bf16, both BCs, "
-        "(2,2,2)/(2,4,1)/(2,1,4))"
-    )
-
-
-def check_fused_dma_edge_size_stress():
-    """Edge-size/chunk stress matrix for the fused DMA-overlap kernels on
-    the 8-ring (VERDICT r4 item 6): the smallest legal shard depths
-    (nx=2 for tb=1, nx=4 for tb=2 — where the overlap window degenerates
-    and the epilogue re-streams most of the shard), a non-power-of-two
-    chunk split (ny=24 with by=8 -> 3 chunk columns), and the judged
-    bf16-storage/fp32-compute tier, all against the single-device
-    oracle."""
-    from jax.sharding import Mesh, NamedSharding
-
-    import heat3d_tpu.ops.stencil_dma_fused as fused_mod
-    from heat3d_tpu.core.config import GridConfig
-    from heat3d_tpu.ops.stencil_jnp import step_single_device
-
-    mesh = Mesh(np.array(jax.devices()).reshape(8), ("x",))
-    spec = P("x")
-    orig_chunk = fused_mod.choose_chunk
-    cases = [
-        # (grid, tb, by, storage) — nx/shard = grid[0]//8
-        ((16, 24, 16), 1, 8, "fp32"),   # nx=2 minimum, 3 chunk columns
-        ((16, 24, 16), 1, None, "bf16"),  # nx=2, bf16 geometry
-        ((32, 24, 16), 2, 8, "fp32"),   # nx=4 tb=2 minimum, 3 chunks
-        ((32, 24, 16), 2, None, "bf16"),
-    ]
-    bc, bcv = BoundaryCondition.DIRICHLET, 1.5
-    try:
-        for grid, tb, by, storage in cases:
-            gc = GridConfig(shape=grid)
-            taps = stencil_taps(
-                STENCILS["7pt"], gc.alpha, gc.effective_dt(), gc.spacing
-            )
-            u_host = golden.random_init(grid, seed=67)
-            prec = Precision() if storage == "fp32" else Precision.bf16()
-            tol = 1e-6 if storage == "fp32" else (4e-3 if tb == 1 else 8e-3)
-            u_in = jnp.asarray(u_host).astype(jnp.dtype(prec.storage))
-            fused_mod.choose_chunk = (
-                orig_chunk if by is None else lambda *a, _by=by, **k: _by
-            )
-            apply = (
-                fused_mod.apply_step_fused_dma
-                if tb == 1
-                else fused_mod.apply_superstep_fused_dma
-            )
-            u_dev = jax.device_put(u_in, NamedSharding(mesh, spec))
-            got = jax.jit(
-                shard_map(
-                    lambda x, t=taps, f=apply: f(
-                        x, t, axis_name="x", axis_size=8, mesh_axes=("x",),
-                        periodic=False, bc_value=bcv, interpret=True,
-                    ),
-                    mesh=mesh, in_specs=spec, out_specs=spec,
-                    check_vma=False,
-                )
-            )(u_dev)
-            want = u_in
-            for _ in range(tb):
-                want = step_single_device(want, taps, bc, bcv, precision=prec)
-            np.testing.assert_allclose(
-                np.asarray(got.astype(jnp.float32)),
-                np.asarray(want.astype(jnp.float32)),
-                rtol=tol, atol=tol,
-                err_msg=f"stress grid={grid} tb={tb} by={by} {storage}",
-            )
-    finally:
-        fused_mod.choose_chunk = orig_chunk
-    print("fused_dma_edge_size_stress OK (nx=2/4, 3-chunk, bf16 tiers)")
-
-
 def check_sharded_checkpoint_roundtrip():
     import tempfile
 
@@ -1673,183 +1322,6 @@ def check_timeint_coef_serve_packing():
     print("timeint_coef_serve_packing OK")
 
 
-def check_fused_rdma_ring_interpret():
-    """The fused in-kernel RDMA superstep kernels (plan-scheduled remote
-    face copies under the sweep — ops/stencil_fused_rdma.py) on a REAL
-    4-device interpret ring, 7pt x dirichlet/periodic x tb{1,2} x
-    monolithic/partitioned plans. Three-way contract per case:
-    (1) the fused-RDMA kernel is BITWISE-equal to the certified
-    fused-DMA kernel — they share the sweep/emit bodies verbatim
-    through the rdma_factory seam, so ANY value difference means the
-    planned transfer protocol landed different ghost bytes;
-    (2) the partitioned plan (genuine sub-blocks, min_part_bytes=0) is
-    BITWISE-equal to monolithic — sub-block decomposition is pure
-    scheduling, never values;
-    (3) both match the single-device unfused oracle at the battery's
-    standard fp32 tolerance (1e-6): the fused streaming sweep and the
-    padded jnp sweep accumulate in different association orders, the
-    same posture as every other kernel battery here — bitwise equality
-    vs the UNFUSED route is not a property any fused kernel in this
-    repo has or claims."""
-    from jax.sharding import Mesh, NamedSharding
-
-    import heat3d_tpu.ops.stencil_dma_fused as dma_mod
-    import heat3d_tpu.ops.stencil_fused_rdma as rdma_mod
-    from heat3d_tpu.core.config import GridConfig
-    from heat3d_tpu.ops.stencil_jnp import step_single_device
-    from heat3d_tpu.parallel.plan import build_plan
-
-    grid = (16, 16, 16)  # 4 x-planes/shard on 4 devices: the tb=2 floor
-    gc = GridConfig(shape=grid)
-    taps = stencil_taps(STENCILS["7pt"], gc.alpha, gc.effective_dt(),
-                        gc.spacing)
-    u_host = golden.random_init(grid, seed=53)
-    u_in = jnp.asarray(u_host)
-    mesh = Mesh(np.array(jax.devices()[:4]).reshape(4), ("x",))
-    spec = P("x")
-    u_dev = jax.device_put(u_in, NamedSharding(mesh, spec))
-
-    def run(fn, **kw):
-        return np.asarray(
-            jax.jit(
-                shard_map(
-                    lambda x: fn(x, taps, **kw),
-                    mesh=mesh, in_specs=spec, out_specs=spec,
-                    check_vma=False,
-                )
-            )(u_dev)
-        )
-
-    for bc, bcv in [
-        (BoundaryCondition.DIRICHLET, 1.5),
-        (BoundaryCondition.PERIODIC, 0.0),
-    ]:
-        for tb, dma_fn, rdma_fn in (
-            (1, dma_mod.apply_step_fused_dma,
-             rdma_mod.apply_step_fused_rdma),
-            (2, dma_mod.apply_superstep_fused_dma,
-             rdma_mod.apply_superstep_fused_rdma),
-        ):
-            kw = dict(
-                axis_name="x", axis_size=4, mesh_axes=("x",),
-                periodic=bc is BoundaryCondition.PERIODIC,
-                bc_value=bcv, interpret=True,
-            )
-            base = run(dma_fn, **kw)
-            by_mode = {}
-            for mode in ("monolithic", "partitioned"):
-                plan = build_plan(
-                    MeshConfig(shape=(4, 1, 1)), bc, width=tb,
-                    transport="ppermute", mode=mode, min_part_bytes=0,
-                )
-                if mode == "partitioned":
-                    # the case must exercise GENUINE sub-block sends
-                    bounds = rdma_mod.plan_send_bounds(
-                        plan, (grid[0] // 4,) + grid[1:], 4
-                    )
-                    assert len(bounds) > 1, bounds
-                by_mode[mode] = run(rdma_fn, plan=plan, **kw)
-                assert np.array_equal(by_mode[mode], base), (
-                    f"fused-rdma != fused-dma bitwise "
-                    f"(tb={tb} bc={bc} plan={mode})"
-                )
-            assert np.array_equal(
-                by_mode["monolithic"], by_mode["partitioned"]
-            ), f"partitioned != monolithic bitwise (tb={tb} bc={bc})"
-            want = u_in
-            for _ in range(tb):
-                want = step_single_device(want, taps, bc, bcv)
-            np.testing.assert_allclose(
-                by_mode["monolithic"], np.asarray(want),
-                rtol=1e-6, atol=1e-6,
-                err_msg=f"fused-rdma vs unfused oracle (tb={tb} bc={bc})",
-            )
-    print(
-        "fused_rdma_ring_interpret OK "
-        "(7pt, both BCs, tb1+tb2, monolithic+partitioned, "
-        "bitwise vs fused-dma + oracle)"
-    )
-
-
-def check_fused_rdma_route_dispatch():
-    """The fused_rdma route end-to-end through HeatSolver3D on a real
-    4-device mesh: with the knob on (and the interpret gate), the step
-    and superstep builders must dispatch the fused route (emulation tier
-    = the kernel's certified pure-XLA reference contract), phase_programs
-    must alias the fused phase to the step program, and the simulated
-    values must match the unfused jnp route at the standard tolerance —
-    under monolithic AND genuine-sub-block partitioned plans
-    (HEAT3D_PLAN_PART_MIN_BYTES=0, keyed into the plan cache)."""
-    import dataclasses
-    import os
-
-    from heat3d_tpu.models.heat3d import HeatSolver3D, _select_backend
-    from heat3d_tpu.parallel.step import (
-        PHASE_FUSED,
-        PHASE_STEP,
-        _fused_rdma2_fn,
-        _fused_rdma_fn,
-        phase_programs,
-    )
-
-    saved = {
-        k: os.environ.get(k)
-        for k in (
-            "HEAT3D_DIRECT_INTERPRET",
-            "HEAT3D_FUSED_RDMA",
-            "HEAT3D_PLAN_PART_MIN_BYTES",
-        )
-    }
-    os.environ["HEAT3D_DIRECT_INTERPRET"] = "1"
-    os.environ.pop("HEAT3D_FUSED_RDMA", None)
-    os.environ["HEAT3D_PLAN_PART_MIN_BYTES"] = "0"
-    grid = (16, 16, 16)
-    try:
-        for tb in (1, 2):
-            for hp in ("monolithic", "partitioned"):
-                cfg = SolverConfig(
-                    grid=GridConfig(shape=grid),
-                    stencil=StencilConfig(
-                        bc=BoundaryCondition.DIRICHLET, bc_value=0.5
-                    ),
-                    mesh=MeshConfig(shape=(4, 1, 1)),
-                    backend="auto",
-                    time_blocking=tb,
-                    halo_plan=hp,
-                    fused_rdma="on",
-                )
-                route = (
-                    _fused_rdma_fn(cfg) if tb == 1 else _fused_rdma2_fn(cfg)
-                )
-                assert route is not None, (
-                    f"fused_rdma route did not resolve (tb={tb} hp={hp})"
-                )
-                mesh = build_mesh(cfg.mesh)
-                progs = phase_programs(cfg, mesh, _select_backend(cfg))
-                assert progs[PHASE_FUSED] is progs[PHASE_STEP], (
-                    "fused phase must alias the step program"
-                )
-                cfg_off = dataclasses.replace(
-                    cfg, fused_rdma="off", backend="jnp",
-                    halo_plan="monolithic", time_blocking=1,
-                )
-                u_host = golden.random_init(grid, seed=61)
-                s_on, s_off = HeatSolver3D(cfg), HeatSolver3D(cfg_off)
-                got = s_on.gather(s_on.run(s_on.init_state(u_host), 2))
-                want = s_off.gather(s_off.run(s_off.init_state(u_host), 2))
-                np.testing.assert_allclose(
-                    got, want, rtol=1e-6, atol=1e-6,
-                    err_msg=f"fused_rdma route vs jnp (tb={tb} hp={hp})",
-                )
-    finally:
-        for key, val in saved.items():
-            if val is None:
-                os.environ.pop(key, None)
-            else:
-                os.environ[key] = val
-    print("fused_rdma_route_dispatch OK (tb1+tb2, both plan modes)")
-
-
 def main():
     if len(sys.argv) > 1 and sys.argv[1] == "eqn":
         # focused tier-1 entry (tests/test_eqn.py runs it unmarked on a
@@ -1886,18 +1358,6 @@ def main():
         check_timeint_coef_serve_packing()
         print("ALL MULTIDEVICE CHECKS PASSED")
         return
-    if len(sys.argv) > 1 and sys.argv[1] == "fused_rdma":
-        # focused tier-1 entry (tests/test_fused_rdma.py runs it unmarked
-        # on a 4-device mesh): the fused in-kernel RDMA superstep battery
-        # — kernel bitwise vs the certified fused-DMA bodies + plan-mode
-        # bitwise identity + oracle parity, then the solver-route
-        # dispatch/aliasing/parity contract
-        n = len(jax.devices())
-        assert n >= 4, f"expected >= 4 CPU devices, got {n}"
-        check_fused_rdma_ring_interpret()
-        check_fused_rdma_route_dispatch()
-        print("ALL MULTIDEVICE CHECKS PASSED")
-        return
     if len(sys.argv) > 1 and sys.argv[1] == "deep_tb":
         # focused tier-1 entry (test_multidevice.py runs it unmarked on a
         # 4-device mesh; the full 8-device battery stays slow-marked)
@@ -1920,11 +1380,6 @@ def main():
     check_halo_ghost_identity()
     check_multistep_vs_golden()
     check_dma_halo_ring_interpret()
-    check_fused_dma_overlap_ring_interpret()
-    check_fused_dma2_superstep_ring_interpret()
-    check_fused_dma_ghost_outputs_ring_interpret()
-    check_fused_dma_3d_glue()
-    check_fused_dma_edge_size_stress()
     check_sharded_checkpoint_roundtrip()
     check_gather_slice_distributed()
     print("ALL MULTIDEVICE CHECKS PASSED")

@@ -8,7 +8,12 @@ import numpy as np
 import pytest
 
 from heat3d_tpu.bench.harness import bench_halo, bench_throughput
-from heat3d_tpu.core.config import GridConfig, MeshConfig, SolverConfig
+from heat3d_tpu.core.config import (
+    GridConfig,
+    MeshConfig,
+    SolverConfig,
+    StencilConfig,
+)
 
 
 def tiny_cfg():
@@ -248,39 +253,124 @@ def test_solver_records_direct_route(monkeypatch):
     assert HeatSolver3D(cfg).route["kernel"] is None
 
 
-@pytest.mark.parametrize(
-    "mesh,tb,kernel",
-    [
-        ((8, 1, 1), 1, "fused_dma"),  # x-slab kernel
-        ((8, 1, 1), 2, "fused_dma"),  # its tb=2 superstep
-        ((2, 2, 2), 1, "fused_dma"),  # the x-sharded block generalization
-    ],
-)
-def test_step_builders_record_fused_dma_route(monkeypatch, mesh, tb, kernel):
-    """fused_dma_path comes from the builders' record: every in-scope
-    overlap+halo='dma' config records the fused kernel (interpret mode
-    stands in for TPU off-chip), and ppermute transport records the
-    faces-direct kernel instead — pod A/B rows stay tellable apart."""
-    import dataclasses
+def _route(kernel, interpret=False, layout=None):
+    return {
+        "kernel": kernel,
+        "interpret": interpret,
+        "layout": layout if kernel is None else layout or "framed",
+    }
 
-    from heat3d_tpu.parallel.step import make_multistep_fn
+
+DIRECT = _route("direct", layout="aligned")
+
+# One row per distinct outcome of the step dispatcher: (mesh, grid, tb,
+# config knobs, env, what the builder records). ``env`` is "force"
+# (HEAT3D_DIRECT_FORCE: the Mosaic kernels off the chip), "interpret"
+# (HEAT3D_DIRECT_INTERPRET), "no-direct" (force + HEAT3D_NO_DIRECT),
+# "mehrstellen" (force + HEAT3D_MEHRSTELLEN), "no-direct-gate" (force,
+# with direct_supported refusing the shape) or None (off the chip, no
+# kernel). Grids with a local nz of 128 put the
+# direct kernels' planes on the aligned layout. A refused combination
+# expects a ValueError whose message matches the given pattern.
+ROUTE_MAP = [
+    pytest.param(mesh, grid, tb, {}, "force", DIRECT,
+                 id=f"{'x'.join(map(str, mesh))}-tb{tb}")
+    for mesh, grid in [
+        ((1, 1, 1), (16, 16, 128)),
+        ((2, 2, 1), (32, 32, 128)),
+        ((4, 1, 1), (32, 16, 128)),
+        ((2, 2, 2), (32, 32, 256)),
+    ]
+    for tb in (1, 2)
+] + [
+    pytest.param((1, 1, 1), (16, 16, 32), 1, {}, "force",
+                 _route("direct", layout="framed"), id="framed"),
+    pytest.param((1, 1, 1), (16, 16, 128), 1,
+                 {"stencil": StencilConfig(kind="27pt")}, "mehrstellen",
+                 _route("direct", layout="framed"), id="mehrstellen"),
+    pytest.param((4, 1, 1), (32, 16, 128), 2, {}, "interpret",
+                 _route("direct", True, "aligned"), id="interpret"),
+    pytest.param((2, 2, 1), (32, 32, 128), 1, {"backend": "jnp"}, "force",
+                 _route(None), id="backend-jnp"),
+    pytest.param((1, 1, 1), (16, 16, 128), 1, {"backend": "conv"}, "force",
+                 _route(None), id="backend-conv"),
+    pytest.param((4, 1, 1), (30, 16, 128), 1, {}, "force", _route(None),
+                 id="padded"),
+    pytest.param((2, 2, 1), (32, 32, 128), 1, {"halo_order": "pairwise"},
+                 "force", _route(None), id="pairwise"),
+    pytest.param((2, 2, 1), (32, 32, 128), 1, {"halo_plan": "partitioned"},
+                 "force", _route(None), id="partitioned"),
+    pytest.param((2, 2, 1), (32, 32, 128), 1, {}, "no-direct", _route(None),
+                 id="no-direct"),
+    pytest.param((2, 2, 1), (32, 32, 128), 2, {}, None, _route(None),
+                 id="off-tpu"),
+    pytest.param((4, 1, 1), (32, 16, 128), 1, {"halo": "dma"}, "force",
+                 _route(None), id="dma"),
+    pytest.param((4, 1, 1), (32, 16, 128), 2, {"halo": "dma"}, "force",
+                 _route("streamk"), id="dma-tb2"),
+    pytest.param((2, 2, 1), (32, 32, 128), 1, {"overlap": True}, "force",
+                 DIRECT, id="overlap-tb1"),
+    pytest.param((2, 2, 1), (32, 32, 128), 1, {"overlap": True}, None,
+                 _route(None), id="overlap-off-tpu"),
+    pytest.param((2, 2, 1), (4, 4, 128), 1, {"overlap": True}, None,
+                 "local blocks >= 3", id="overlap-small-block"),
+    pytest.param((2, 2, 1), (32, 32, 128), 2, {"overlap": True}, "force",
+                 "mutually exclusive", id="overlap-tb2"),
+    pytest.param((4, 1, 1), (32, 16, 128), 1,
+                 {"overlap": True, "halo": "dma"}, "force",
+                 "cannot overlap with compute", id="overlap-dma"),
+    pytest.param((1, 1, 1), (16, 16, 128), 3, {}, "force",
+                 _route("streamk"), id="tb3"),
+    pytest.param((1, 1, 1), (16, 16, 128), 4, {}, "force",
+                 _route("streamk"), id="tb4"),
+    pytest.param((1, 1, 1), (16, 16, 128), 5, {}, "force", _route(None),
+                 id="tb5"),
+    pytest.param((2, 2, 1), (32, 32, 128), 3, {}, None, _route(None),
+                 id="tb3-off-tpu"),
+    pytest.param((2, 2, 1), (32, 32, 128), 2, {}, "no-direct-gate",
+                 _route("streamk"), id="direct-unsupported-tb2"),
+]
+
+
+@pytest.mark.parametrize("mesh,grid,tb,knobs,env,want", ROUTE_MAP)
+def test_route_map(monkeypatch, mesh, grid, tb, knobs, env, want):
+    """The step builders' route record over the dispatcher's outcomes:
+    which kernel family (or the exchange path) each mesh, time-blocking
+    depth, knob and environment takes, built over an abstract mesh and
+    never lowered; refused combinations raise."""
+    from heat3d_tpu.parallel.step import make_step_fn, make_superstep_fn
     from heat3d_tpu.parallel.topology import abstract_mesh
 
-    monkeypatch.setenv("HEAT3D_DIRECT_INTERPRET", "1")
-    cfg = SolverConfig(
-        grid=GridConfig.cube(32),
-        mesh=MeshConfig(shape=mesh),
-        halo="dma",
-        overlap=True,
-        time_blocking=tb,
-    )
-    run = make_multistep_fn(cfg, abstract_mesh(cfg.mesh))
-    assert run.route == {
-        "kernel": kernel, "interpret": True, "layout": "framed"
-    }
-    pp = dataclasses.replace(cfg, halo="ppermute", overlap=tb == 1)
-    run = make_multistep_fn(pp, abstract_mesh(pp.mesh))
-    assert run.route["kernel"] == "direct"
+    for var in ("HEAT3D_DIRECT_FORCE", "HEAT3D_DIRECT_INTERPRET",
+                "HEAT3D_NO_DIRECT", "HEAT3D_MEHRSTELLEN"):
+        monkeypatch.delenv(var, raising=False)
+    if env == "interpret":
+        monkeypatch.setenv("HEAT3D_DIRECT_INTERPRET", "1")
+    elif env is not None:
+        monkeypatch.setenv("HEAT3D_DIRECT_FORCE", "1")
+    if env == "no-direct":
+        monkeypatch.setenv("HEAT3D_NO_DIRECT", "1")
+    if env == "mehrstellen":
+        monkeypatch.setenv("HEAT3D_MEHRSTELLEN", "1")
+    if env == "no-direct-gate":
+        monkeypatch.setattr(
+            "heat3d_tpu.ops.stencil_pallas_direct.direct_supported",
+            lambda *a, **k: False,
+        )
+
+    def build():
+        cfg = SolverConfig(
+            grid=GridConfig(shape=grid), mesh=MeshConfig(shape=mesh),
+            time_blocking=tb, **knobs,
+        )
+        builder = make_superstep_fn if tb > 1 else make_step_fn
+        return builder(cfg, abstract_mesh(cfg.mesh)).route
+
+    if isinstance(want, str):
+        with pytest.raises(ValueError, match=want):
+            build()
+    else:
+        assert build() == want
 
 
 def test_chain_ops_tracks_mehrstellen_route(monkeypatch):

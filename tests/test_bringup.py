@@ -114,12 +114,14 @@ def test_supervisor_and_elastic_probe_this_process():
 
 
 def test_vmem_budget_unknown_tpu_generation_is_an_error():
-    from heat3d_tpu.ops import stencil_dma_fused as dma
+    from heat3d_tpu.analysis import vmem
 
-    assert dma.chip_vmem_budget_for("tpu-v5-lite") == 16 * 1024 * 1024
-    assert dma.chip_vmem_budget_for("cpu") == min(dma.CHIP_VMEM_BYTES.values())
+    assert vmem.chip_vmem_budget_for("tpu-v5-lite") == 16 * 1024 * 1024
+    assert vmem.chip_vmem_budget_for("cpu") == min(
+        vmem.CHIP_VMEM_BYTES.values()
+    )
     with pytest.raises(ValueError, match="no VMEM budget"):
-        dma.chip_vmem_budget_for("tpu-v9-imaginary")
+        vmem.chip_vmem_budget_for("tpu-v9-imaginary")
 
 
 def test_peaks_keyed_by_device_kind():
@@ -130,62 +132,6 @@ def test_peaks_keyed_by_device_kind():
     assert "TPU v5e" in v5e["source"]
     other = peak_spec("TPU v9 imaginary")
     assert other["mem_gbps"] is None and other["bf16_tflops"] is None
-
-
-@pytest.mark.parametrize(
-    "route", [{"halo": "dma", "overlap": True}, {"fused_rdma": "on"}],
-    ids=["fused-dma", "fused-rdma"],
-)
-def test_requested_fused_route_refuses_by_vmem(monkeypatch, route):
-    """An explicitly requested fused route that does not fit VMEM raises,
-    naming the budget, instead of running another route under its
-    name."""
-    from heat3d_tpu.core.config import GridConfig, MeshConfig, SolverConfig
-    from heat3d_tpu.parallel.step import _fused_dma2_fn, _fused_rdma2_fn
-
-    monkeypatch.setenv("HEAT3D_DIRECT_INTERPRET", "1")
-    monkeypatch.setenv("HEAT3D_VMEM_BYTES", str(64 * 1024))
-    cfg = SolverConfig(
-        grid=GridConfig(shape=(16, 64, 128)), mesh=MeshConfig(shape=(4, 1, 1)),
-        backend="auto", time_blocking=2, **route,
-    )
-    resolve = _fused_dma2_fn if "halo" in route else _fused_rdma2_fn
-    with pytest.raises(ValueError, match="VMEM budget of 65536 bytes"):
-        resolve(cfg)
-
-
-@pytest.mark.parametrize(
-    "mesh,knobs,match",
-    [
-        ((1, 1, 1), {"backend": "jnp", "fused_rdma": "on"}, "fused_rdma='on'"),
-        ((4, 1, 1), {"fused_rdma": "on"}, "fused_rdma='on'"),  # no TPU
-        ((4, 1, 1), {"backend": "jnp", "halo": "dma", "overlap": True},
-         "halo='dma' \\+ overlap needs the fused"),
-        ((4, 1, 1), {"halo": "dma", "overlap": True},  # no TPU
-         "halo='dma' \\+ overlap needs the fused"),
-        ((1, 2, 2), {"halo": "dma", "overlap": True, "interpret": True},
-         "overlap=True with halo='dma' needs the fused"),  # x unsharded
-    ],
-)
-def test_requested_fused_route_out_of_gate_raises(
-    monkeypatch, mesh, knobs, match
-):
-    """Every gate failure of an explicitly requested fused route raises
-    naming why; none runs another route under the requested name."""
-    from heat3d_tpu.core.config import GridConfig, MeshConfig, SolverConfig
-    from heat3d_tpu.parallel.step import make_step_fn
-
-    knobs = dict(knobs)
-    if knobs.pop("interpret", False):
-        monkeypatch.setenv("HEAT3D_DIRECT_INTERPRET", "1")
-    else:
-        monkeypatch.delenv("HEAT3D_DIRECT_INTERPRET", raising=False)
-    monkeypatch.delenv("HEAT3D_DIRECT_FORCE", raising=False)
-    cfg = SolverConfig(
-        grid=GridConfig.cube(16), mesh=MeshConfig(shape=mesh), **knobs
-    )
-    with pytest.raises(ValueError, match=match):
-        make_step_fn(cfg, None)
 
 
 def test_cli_summary_records_resolved_route(capsys):

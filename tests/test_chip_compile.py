@@ -59,7 +59,6 @@ def v5e(monkeypatch, topo):
 
     monkeypatch.setenv("HEAT3D_DIRECT_FORCE", "1")
     monkeypatch.delenv("HEAT3D_DIRECT_INTERPRET", raising=False)
-    monkeypatch.delenv("HEAT3D_VMEM_BYTES", raising=False)
     monkeypatch.setattr(tcache, "chip_generation", lambda: "tpu-v5-lite")
     assert topo.devices[0].device_kind == V5E_KIND
     return topo
@@ -160,39 +159,6 @@ def test_direct2_superstep_kernel_keeps_its_name(v5e):
     assert calls and all(
         re.fullmatch(r"%heat3d\.stencil\.direct2\.\d+", c) for c in calls
     ), calls
-
-
-@pytest.mark.parametrize(
-    "route", [{"halo": "dma", "overlap": True}, {"fused_rdma": "on"}],
-    ids=["fused-dma", "fused-rdma"],
-)
-@pytest.mark.parametrize("tb", [1, 2])
-def test_fused_slab_route_at_cell_e(v5e, route, tb):
-    """Cell (e): the (4,1,1) slab run, 1024^3 at 256x1024x1024 per chip.
-    The explicitly requested fused route either compiles holding its
-    kernel, or the v5e VMEM gate refuses it by name — never another route
-    in its place."""
-    cfg = SolverConfig(
-        grid=GridConfig(shape=(1024, 1024, 1024)),
-        stencil=StencilConfig(kind="7pt"),
-        mesh=MeshConfig(shape=(4, 1, 1)),
-        precision=Precision.fp32(),
-        backend="auto",
-        time_blocking=tb,
-        **route,
-    )
-    try:
-        fn, x = _sharded_step(v5e, cfg)
-    except ValueError as e:
-        assert "VMEM budget of 16777216 bytes on 'tpu-v5-lite'" in str(e)
-        assert tb == 2  # the v5e budget admits the tb=1 kernels
-        return
-    assert fn.route == {
-        "kernel": "fused_dma" if "halo" in route else "fused_rdma",
-        "interpret": False,
-        "layout": "framed",
-    }
-    assert "tpu_custom_call" in _compile_text(fn, x)
 
 
 def test_build_mesh_orders_the_described_host(v5e):

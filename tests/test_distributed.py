@@ -185,6 +185,36 @@ def test_time_blocking_rejects_overlap():
         make_superstep_fn(dataclasses.replace(base, overlap=True), mesh)
 
 
+def test_overlap_tb_out_of_scope_still_errors():
+    """overlap+time_blocking keeps the mutual-exclusion config error on a
+    block mesh too."""
+    cfg = SolverConfig(
+        grid=GridConfig.cube(16),
+        stencil=StencilConfig(kind="7pt"),
+        mesh=MeshConfig(shape=(2, 2, 2)),
+        backend="jnp",
+        time_blocking=2,
+        overlap=True,
+    )
+    am = abstract_mesh(cfg.mesh)
+    with pytest.raises(ValueError, match="mutually exclusive"):
+        make_superstep_fn(cfg, am)
+
+
+def test_overlap_dma_out_of_scope_still_errors():
+    """overlap with halo='dma' is refused when the config is built: the
+    side-effecting DMA exchange kernels cannot overlap with compute."""
+    with pytest.raises(ValueError, match="cannot overlap with compute"):
+        SolverConfig(
+            grid=GridConfig.cube(16),
+            stencil=StencilConfig(kind="27pt"),
+            mesh=MeshConfig(shape=(2, 2, 2)),
+            backend="jnp",
+            halo="dma",
+            overlap=True,
+        )
+
+
 @pytest.mark.parametrize(
     "n,k,ok",
     [
@@ -381,40 +411,60 @@ def test_dma_halo_superstep_lowers_for_multichip_tpu(width):
     assert "tpu_custom_call" in txt  # the Mosaic DMA kernels
 
 
-@pytest.mark.parametrize("kind", ["7pt", "27pt"])
-def test_faces_direct_step_lowers_for_multichip_tpu(kind, monkeypatch):
+_D, _P = BoundaryCondition.DIRICHLET, BoundaryCondition.PERIODIC
+
+# (kind, mesh, [(bc, bc_value, time_blocking), ...]): the (2,2,2) block
+# mesh at both BCs and depths, and the (8,1,1) x-slab mesh at local
+# extent 4 along x
+FACES_DIRECT_LOWERING = [
+    pytest.param(
+        kind, (2, 2, 2),
+        [(bc, 0.5, tb) for bc in (_D, _P) for tb in (1, 2)], id=kind,
+    )
+    for kind in ("7pt", "27pt")
+] + [
+    pytest.param(kind, (8, 1, 1), cases, id=f"{kind}-8x1x1-{tag}")
+    for kind in ("7pt", "27pt")
+    for tag, cases in [
+        ("dirichlet-tb1", [(_D, 1.5, 1)]),
+        ("periodic-tb1", [(_P, 0.0, 1)]),
+        ("tb2", [(_D, 0.5, 2)]),
+    ]
+]
+
+
+@pytest.mark.parametrize("kind,mesh,cases", FACES_DIRECT_LOWERING)
+def test_faces_direct_step_lowers_for_multichip_tpu(
+    kind, mesh, cases, monkeypatch
+):
     """The multi-chip faces-direct step and tb=2 superstep — Mosaic direct
     kernels + faces-only ppermute exchange + shell patches — lower for a
-    (2,2,2) TPU mesh (HEAT3D_DIRECT_FORCE selects the real kernels
-    off-hardware; pallas->Mosaic lowering runs host-side, so block-spec
-    violations surface here, not on the chip)."""
+    TPU mesh (HEAT3D_DIRECT_FORCE selects the real kernels off-hardware;
+    pallas->Mosaic lowering runs host-side, so block-spec violations
+    surface here, not on the chip)."""
     monkeypatch.setenv("HEAT3D_DIRECT_FORCE", "1")
     from heat3d_tpu.parallel.step import _direct_kernel_fn
 
-    for bc in (BoundaryCondition.DIRICHLET, BoundaryCondition.PERIODIC):
+    for bc, bc_value, tb in cases:
         cfg = SolverConfig(
             grid=GridConfig.cube(32),
-            stencil=StencilConfig(kind=kind, bc=bc, bc_value=0.5),
-            mesh=MeshConfig(shape=(2, 2, 2)),
+            stencil=StencilConfig(kind=kind, bc=bc, bc_value=bc_value),
+            mesh=MeshConfig(shape=mesh),
             backend="auto",
+            time_blocking=tb,
         )
-        assert _direct_kernel_fn(cfg, 1, multichip=True) is not None
+        assert _direct_kernel_fn(cfg, tb, multichip=True) is not None
         am = abstract_mesh(cfg.mesh)
-        step = make_step_fn(cfg, am, with_residual=True)
+        step = (
+            make_superstep_fn(cfg, am) if tb == 2
+            else make_step_fn(cfg, am, with_residual=True)
+        )
+        assert step.route["kernel"] == "direct"
         txt = lower_for_mesh(
             step, cfg.mesh, (cfg.grid.shape, jnp.float32, P("x", "y", "z"))
         ).as_text()
         assert "tpu_custom_call" in txt  # Mosaic direct kernel
         assert "collective_permute" in txt  # faces exchange
-        cfg2 = SolverConfig(
-            grid=GridConfig.cube(32), stencil=cfg.stencil, mesh=cfg.mesh,
-            backend="auto", time_blocking=2,
-        )
-        sstep = make_superstep_fn(cfg2, am)
-        txt2 = lower_for_mesh(
-            sstep, cfg2.mesh, (cfg2.grid.shape, jnp.float32, P("x", "y", "z"))
-        ).as_text()
-        assert "tpu_custom_call" in txt2 and "collective_permute" in txt2
 
 
 def test_faces_direct_step_materializes_no_padded_volume(monkeypatch):

@@ -446,10 +446,8 @@ def test_provenance_post_heal_and_weak_scaling_rules(tmp_path):
     thr = {
         "bench": "throughput", "ts": "2026-08-04T00:00:00Z",
         "platform": "cpu", "direct_path": False,
-        "mehrstellen_route": False, "fused_dma_path": False,
-        "fused_dma_emulated": False, "streamk_path": False,
+        "mehrstellen_route": False, "streamk_path": False,
         "streamk_emulated": False, "halo_plan": "monolithic",
-        "fused_rdma_path": False, "fused_rdma_emulated": False,
         "chain_ops": 7, "backend": "jnp", "sync_rtt_s": 0.01,
         "batch_shape": [1], "members_per_step": 1, "equation": "heat",
         "integrator": "explicit-euler",

@@ -385,10 +385,8 @@ def test_provenance_requires_equation_on_throughput_rows():
     row = {
         "bench": "throughput", "ts": "2026-08-04T00:00:00Z",
         "platform": "cpu", "direct_path": False,
-        "mehrstellen_route": False, "fused_dma_path": False,
-        "fused_dma_emulated": False, "streamk_path": False,
+        "mehrstellen_route": False, "streamk_path": False,
         "streamk_emulated": False, "halo_plan": "monolithic",
-        "fused_rdma_path": False, "fused_rdma_emulated": False,
         "chain_ops": 7, "batch_shape": [1], "members_per_step": 1,
         "sync_rtt_s": 0.0, "integrator": "explicit-euler",
     }

@@ -536,19 +536,19 @@ def test_gate_adjudication_fires_on_live_override_above_capacity():
 
 
 def test_generation_aware_gate_budget(monkeypatch):
-    from heat3d_tpu.ops import stencil_dma_fused as dma
+    from heat3d_tpu.analysis import vmem
 
-    assert dma.chip_vmem_budget_for("tpu-v5-lite") == 16 * 1024 * 1024
-    assert dma.chip_vmem_budget_for("tpu-v5p") == 32 * 1024 * 1024
+    assert vmem.chip_vmem_budget_for("tpu-v5-lite") == 16 * 1024 * 1024
+    assert vmem.chip_vmem_budget_for("tpu-v5p") == 32 * 1024 * 1024
     with pytest.raises(ValueError, match="no VMEM budget"):
-        dma.chip_vmem_budget_for("weird-part")
+        vmem.chip_vmem_budget_for("weird-part")
     monkeypatch.setenv("HEAT3D_VMEM_BYTES", str(7 * 1024 * 1024))
-    assert dma._chip_vmem_budget() == 7 * 1024 * 1024
+    assert vmem._chip_vmem_budget() == 7 * 1024 * 1024
     monkeypatch.delenv("HEAT3D_VMEM_BYTES")
     monkeypatch.setattr(
         "heat3d_tpu.tune.cache.chip_generation", lambda: "tpu-v5-lite"
     )
-    assert dma._chip_vmem_budget() == 16 * 1024 * 1024
+    assert vmem._chip_vmem_budget() == 16 * 1024 * 1024
 
 
 # ---- fingerprints / framework ---------------------------------------------

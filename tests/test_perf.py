@@ -210,11 +210,9 @@ def test_phase_programs_keyed_like_spans():
         backend="jnp",
     )
     programs = phase_programs(cfg, build_mesh(cfg.mesh))
-    assert {PHASE_STEP, PHASE_HALO, PHASE_STENCIL, PHASE_RESIDUAL} <= set(
+    assert {PHASE_STEP, PHASE_HALO, PHASE_STENCIL, PHASE_RESIDUAL} == set(
         programs
     )
-    # no fused route on a (1,1,1) ppermute mesh
-    assert "fused_dma" not in programs
 
 
 def test_roofline_live_table_on_cpu(capsys):
@@ -730,8 +728,8 @@ def test_device_phase_totals_duck_typed_and_halo_fold():
 def test_device_phase_totals_fold_named_kernels_into_their_phase():
     """A named Pallas kernel's op (``heat3d.stencil.<family>.<n>``, as
     XLA names the custom call after the kernel's ``name=``) lands in its
-    phase: fused DMA families in fused_dma, the rest in stencil, the halo
-    DMA kernels in halo_exchange — no orphan ``stencil.<family>`` row."""
+    phase: the stencil families in stencil, the halo DMA kernels in
+    halo_exchange — no orphan ``stencil.<family>`` row."""
     from types import SimpleNamespace
 
     from heat3d_tpu.obs.perf.timeline import device_phase_totals
@@ -742,22 +740,20 @@ def test_device_phase_totals_fold_named_kernels_into_their_phase():
     meta = {
         1: SimpleNamespace(name="heat3d.stencil.direct2.1"),
         2: SimpleNamespace(name="heat3d.step/heat3d.stencil.streamk.4"),
-        3: SimpleNamespace(name="heat3d.stencil.fused_dma.3"),
-        4: SimpleNamespace(name="heat3d.stencil.fused_rdma2.7"),
+        3: SimpleNamespace(name="heat3d.stencil.direct.3"),
         5: SimpleNamespace(name="heat3d.halo.x.dma.2"),
     }
     plane = SimpleNamespace(
         name="/device:TPU:0",
         lines=[SimpleNamespace(
             name="XLA Ops",
-            events=[ev(1, 30.0), ev(2, 10.0), ev(3, 7.0), ev(4, 5.0),
-                    ev(5, 3.0)],
+            events=[ev(1, 30.0), ev(2, 10.0), ev(3, 7.0), ev(5, 3.0)],
         )],
         event_metadata=meta,
     )
     totals = device_phase_totals(SimpleNamespace(planes=[plane]))
     assert totals == pytest.approx(
-        {"stencil": 40.0, "fused_dma": 12.0, "halo_exchange": 3.0}
+        {"stencil": 47.0, "halo_exchange": 3.0}
     )
 
 

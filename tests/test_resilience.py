@@ -602,12 +602,8 @@ def test_check_provenance_catches_null_ts_and_missing_routes(tmp_path):
     good = {
         "bench": "throughput", "ts": "2026-01-01T00:00:00Z",
         "platform": "tpu", "direct_path": True, "mehrstellen_route": False,
-        "fused_dma_path": False, "fused_dma_emulated": False,
         "streamk_path": False, "streamk_emulated": False,
         "halo_plan": "monolithic",
-        # fused-RDMA route provenance (PR 20): required on every
-        # throughput row — the fused superstep's rate must be keyable
-        "fused_rdma_path": False, "fused_rdma_emulated": False,
         "chain_ops": 7, "backend": "auto", "sync_rtt_s": 7.5e-2,
         # ensemble-workload provenance (PR 7): required on every
         # throughput row — solo rows carry [1]/1
@@ -626,7 +622,7 @@ def test_check_provenance_catches_null_ts_and_missing_routes(tmp_path):
     rows = [
         good,
         {**good, "ts": None},                      # the VERDICT r5 defect
-        {k: v for k, v in good.items() if k != "fused_dma_emulated"},
+        {k: v for k, v in good.items() if k != "streamk_emulated"},
         {**good, "chain_ops": None},               # null ops on non-conv
         {**good, "chain_ops": None, "backend": "conv"},  # legal for conv
         halo_good,
@@ -659,11 +655,11 @@ def test_check_provenance_catches_null_ts_and_missing_routes(tmp_path):
 
 def test_fresh_bench_rows_pass_the_provenance_lint():
     """The lint and the harness must agree: a row the harness emits today
-    passes the lint (fused_dma_emulated + ts + route fields present)."""
+    passes the lint (streamk_emulated + ts + route fields present)."""
     from heat3d_tpu.bench.harness import bench_throughput
 
     mod = _load_check_provenance()
     cfg = SolverConfig(grid=GridConfig.cube(16), backend="jnp")
     r = bench_throughput(cfg, steps=2, warmup=1, repeats=1)
-    assert r["fused_dma_emulated"] is False
+    assert r["streamk_emulated"] is False
     assert not mod.check_row(r), mod.check_row(r)

@@ -432,10 +432,8 @@ def test_solo_throughput_rows_carry_solo_batch_fields():
         "steps": 3, "gcell_per_sec": 1.0, "sync_rtt_s": 1e-5,
         "ts": "2026-08-03T00:00:00Z",
         "chain_ops": "x", "mehrstellen_route": False,
-        "direct_path": False, "fused_dma_path": False,
-        "fused_dma_emulated": False, "streamk_path": False,
+        "direct_path": False, "streamk_path": False,
         "streamk_emulated": False, "halo_plan": "monolithic",
-        "fused_rdma_path": False, "fused_rdma_emulated": False,
         "batch_shape": [1], "members_per_step": 1, "equation": "heat",
         "integrator": "explicit-euler",
     }
@@ -462,6 +460,10 @@ def test_regress_keys_and_reports_split_batch_shapes():
     # legacy rows (no batch fields) key as solo — history stays usable
     legacy = {k: v for k, v in solo.items() if k != "batch_shape"}
     assert row_key(legacy) == row_key(solo)
+    # rows written before a route was removed still carry its knob and
+    # route fields; they key to the same baseline as rows without them
+    retired = dict(solo, retired_route="off", retired_route_path=False)
+    assert row_key(retired) == row_key(solo)
     # a packed row baselines only against packed history and reports the
     # per-member effective split
     report = compare([packed], [dict(packed, gcell_per_sec_per_chip=2.2)])

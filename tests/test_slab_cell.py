@@ -34,32 +34,32 @@ STEPS = 7  # three supersteps and the trailing single step
 REL_TOL = 1e-5
 
 
-def _cfg(**kw):
+def _cfg(tb=2, kind="7pt", bc=BoundaryCondition.DIRICHLET, **kw):
     return SolverConfig(
         grid=GridConfig(shape=GRID),
-        stencil=StencilConfig(
-            kind="7pt", bc=BoundaryCondition.DIRICHLET, bc_value=0.5
-        ),
+        stencil=StencilConfig(kind=kind, bc=bc, bc_value=0.5),
         mesh=MeshConfig(shape=(4, 1, 1)),
         backend="auto",
-        time_blocking=2,
+        time_blocking=tb,
         **kw,
     )
 
 
 _PROBE = r"""
 import json
+import sys
 import jax
 import numpy as np
 from heat3d_tpu.core import golden
-from heat3d_tpu.core.config import Precision
+from heat3d_tpu.core.config import BoundaryCondition, Precision
 from heat3d_tpu.models.heat3d import HeatSolver3D
 from tests.test_slab_cell import STEPS, _cfg
 
+tb, kind, bc = int(sys.argv[1]), sys.argv[2], BoundaryCondition(sys.argv[3])
 out = {}
 u0 = golden.random_init(_cfg().grid.shape, seed=11)
 for name, precision in (("fp32", Precision.fp32()), ("bf16", Precision.bf16())):
-    cfg = _cfg(precision=precision)
+    cfg = _cfg(tb, kind, bc, precision=precision)
     solver = HeatSolver3D(cfg, devices=jax.devices()[:4])
     got = np.asarray(solver.run(solver.init_state(u0), STEPS), np.float64)
     want = golden.run(u0, cfg.grid, cfg.stencil, STEPS, impl="numpy")
@@ -73,11 +73,21 @@ print(json.dumps(out))
 """
 
 
-def test_slab_route_matches_golden_on_cpu_mesh():
-    """HeatSolver3D.run at tb=2 on (4,1,1) takes the faces-direct direct2
-    route with aligned planes (its kernels interpreted) and matches the
-    float64 golden stepper to fp32 rounding after an odd step count; bf16
-    storage does not. CPU devices keep their order in the mesh."""
+@pytest.mark.parametrize("tb,kind,bc", [
+    pytest.param(tb, kind, bc, id=f"tb{tb}-{kind}-{bc.value}")
+    for tb, kind, bc in [
+        (2, "7pt", BoundaryCondition.DIRICHLET),
+        (1, "7pt", BoundaryCondition.DIRICHLET),
+        (2, "27pt", BoundaryCondition.PERIODIC),
+        (1, "27pt", BoundaryCondition.PERIODIC),
+    ]
+])
+def test_slab_route_matches_golden_on_cpu_mesh(tb, kind, bc):
+    """HeatSolver3D.run on (4,1,1) takes the faces-direct route (direct2
+    at tb=2, direct at tb=1) with aligned planes (its kernels
+    interpreted) and matches the float64 golden stepper to fp32 rounding
+    after an odd step count; bf16 storage does not. CPU devices keep
+    their order in the mesh."""
     env = dict(
         os.environ,
         JAX_PLATFORMS="cpu",
@@ -89,7 +99,8 @@ def test_slab_route_matches_golden_on_cpu_mesh():
         env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=4"
     ).strip()
     p = subprocess.run(
-        [sys.executable, "-c", _PROBE], cwd=REPO, env=env,
+        [sys.executable, "-c", _PROBE, str(tb), kind, bc.value],
+        cwd=REPO, env=env,
         capture_output=True, text=True, timeout=600,
     )
     assert p.returncode == 0, p.stderr[-3000:]

@@ -484,10 +484,8 @@ def test_provenance_requires_integrator_on_throughput_rows():
     row = {
         "bench": "throughput", "ts": "2026-08-06T00:00:00Z",
         "platform": "cpu", "direct_path": False,
-        "mehrstellen_route": False, "fused_dma_path": False,
-        "fused_dma_emulated": False, "streamk_path": False,
+        "mehrstellen_route": False, "streamk_path": False,
         "streamk_emulated": False, "halo_plan": "monolithic",
-        "fused_rdma_path": False, "fused_rdma_emulated": False,
         "chain_ops": 7, "batch_shape": [1], "members_per_step": 1,
         "sync_rtt_s": 0.0, "equation": "heat",
     }
