@@ -267,7 +267,11 @@ def _streamk_case(kind: str, k: int, periodic: bool) -> KernelCase:
     )
 
 
-def _direct_case(kind: str, periodic: bool, shape=None, tag="") -> KernelCase:
+def _direct_case(
+    kind: str, periodic: bool, shape=None, tag="", residual=False,
+) -> KernelCase:
+    """The tb=1 direct kernel; ``residual`` adds its change-residual
+    partials, the second output the residual step's kernel writes."""
     shape = shape or _SHAPE
 
     def build():
@@ -278,15 +282,17 @@ def _direct_case(kind: str, periodic: bool, shape=None, tag="") -> KernelCase:
 
         taps = _taps(kind)
         aval = jax.ShapeDtypeStruct(shape, jnp.float32)
+        extra = {"residual_dtype": jnp.float32} if residual else {}
         return (
             lambda u: apply_taps_direct(
-                u, taps, periodic=periodic, bc_value=1.5
+                u, taps, periodic=periodic, bc_value=1.5, **extra
             )
         ), (aval,)
 
     ptag = "/periodic" if periodic else ""
+    rtag = "/residual" if residual else ""
     return KernelCase(
-        key=f"direct/{kind}{ptag}{tag}",
+        key=f"direct/{kind}{ptag}{rtag}{tag}",
         path="heat3d_tpu/ops/stencil_pallas_direct.py",
         entry="apply_taps_direct",
         build=build,
@@ -409,6 +415,8 @@ def _cached_matrix() -> Tuple[KernelCase, ...]:
         _direct_case("7pt", periodic=False),
         _direct_case("7pt", periodic=True),
         _direct_case("27pt", periodic=False),
+        _direct_case("7pt", periodic=False, residual=True),
+        _direct_case("27pt", periodic=True, residual=True),
         _direct2_case("7pt"),
     ]
     if n >= 2:
@@ -425,11 +433,13 @@ def _cached_matrix() -> Tuple[KernelCase, ...]:
             _dma_axis_case(width=4, size=4, periodic=False),
             _dma_planned_case()[0],
         ]
-        cases.append(
+        cases += [
             _direct_case(
-                "7pt", periodic=False, shape=(8, 1024, 512), tag="/chunked"
+                "7pt", periodic=False, shape=(8, 1024, 512), tag="/chunked",
+                residual=residual,
             )
-        )
+            for residual in (False, True)
+        ]
     return tuple(cases)
 
 

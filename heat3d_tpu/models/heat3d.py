@@ -181,6 +181,7 @@ class HeatSolver3D:
                 timeint.make_step_fn(cfg, self.mesh, with_residual=True),
                 donate_argnums=0,
             )
+            self._residual_source = "reduce"
             # convergence mode is steady-state machinery: wave runs
             # oscillate forever and an implicit solve's change residual
             # measures dt, not proximity to steady state
@@ -197,9 +198,14 @@ class HeatSolver3D:
             make_pair_step_fn(cfg, self.mesh, compute),
             donate_argnums=(0, 1), keep_unused=True,
         )
+        step_res = make_pair_step_fn(
+            cfg, self.mesh, compute, with_residual=True
+        )
+        # where the residual step sums its residual: 'kernel' (the direct
+        # kernel's partials) or 'reduce' (heat3d_solver_residuals_total)
+        self._residual_source = step_res.route["residual"]
         self._step_res = jax.jit(
-            make_pair_step_fn(cfg, self.mesh, compute, with_residual=True),
-            donate_argnums=(0, 1), keep_unused=True,
+            step_res, donate_argnums=(0, 1), keep_unused=True
         )
         converge = make_converge_fn(cfg, self.mesh, compute)
         self._exchange_bytes = (
@@ -243,9 +249,12 @@ class HeatSolver3D:
         the Pallas interpreter off-TPU. The loop that ran last is the one
         reported; before any run, the fixed-step loop is built (not
         compiled) to ask it. ``layout`` is the kernel's VMEM plane layout
-        ('aligned' or 'framed', None without a kernel). Where the devices
-        carry ICI ``coords`` (TPU), ``mesh_coords`` lists them in the
-        mesh's row-major order (``parallel.topology.ici_order``)."""
+        ('aligned' or 'framed', None without a kernel). After a
+        convergence run, ``residual`` says where its residual steps summed
+        the residual: 'kernel' (the direct kernel's partials) or 'reduce'.
+        Where the devices carry ICI ``coords`` (TPU), ``mesh_coords``
+        lists them in the mesh's row-major order
+        (``parallel.topology.ici_order``)."""
         if self._route is None:
             _ = self._multistep
         route = {"backend": resolved_backend_name(self.cfg), **self._route}
@@ -514,6 +523,9 @@ class HeatSolver3D:
             else:
                 out = self._on_pair("step_residual", self._step_res, u, 1)
         self._dispatched("step_residual", 1)
+        obs.REGISTRY.counter("solver_residuals_total").inc(
+            source=self._residual_source
+        )
         return out
 
     def carry_residual(self, carry: Tuple[jax.Array, jax.Array]) -> jax.Array:

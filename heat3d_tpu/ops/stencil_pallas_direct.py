@@ -143,6 +143,7 @@ def _vmem_bytes(
     out_itemsize: int,
     q_itemsize: int = 0,
     aligned: bool = False,
+    partial_itemsize: int = 0,
 ) -> int:
     """VMEM footprint of the direct kernel at chunk height ``by`` and ghost
     width ``halo`` (1 = single step, 2 = fused two-step): the assembled-plane
@@ -150,7 +151,9 @@ def _vmem_bytes(
     double-buffered output pipeline. ``q_itemsize`` > 0 adds the mehrstellen
     per-plane 2D-conv cache ring (3 planes, compute dtype). ``aligned``
     sizes the rings as aligned planes (_aligned_layout): nz lanes, the
-    chunk between two pad tiles, in both rings."""
+    chunk between two pad tiles, in both rings. ``partial_itemsize`` > 0
+    adds the double-buffered block of change-residual partial sums that
+    apply_taps_direct emits with ``residual_dtype``."""
     if aligned:
         ring = 3 * halo * _plane_bytes(
             _aligned_rows(by, in_itemsize), nz, in_itemsize
@@ -171,7 +174,18 @@ def _vmem_bytes(
         + 2 * halo * _plane_bytes(1, nz, in_itemsize)
     )
     pipe_out = 2 * _plane_bytes(by, nz, out_itemsize)
+    if partial_itemsize:
+        pipe_out += 2 * _plane_bytes(
+            _SUBLANE, _partial_lanes(nz), partial_itemsize
+        )
     return ring + pipe_in + pipe_out
+
+
+def _partial_lanes(nz: int) -> int:
+    """Lanes of a change-residual partial block: one lane tile where nz is
+    a whole number of them (the lanes fold by adding lane-tile slices),
+    else all nz lanes (a full-extent block)."""
+    return _LANE if nz % _LANE == 0 else nz
 
 
 # Scoped-stack planes of the mehrstellen emit/store (vs the tap chain's
@@ -190,6 +204,7 @@ def choose_chunk(
     compute_itemsize: int = 4,
     q_ring: bool = False,
     aligned: bool = False,
+    partial_itemsize: int = 0,
 ) -> Optional[int]:
     """Largest y-chunk height ``by`` (a divisor of ny, multiple of 8 when
     ny >= 8) whose working set fits the VMEM budget — both the explicit
@@ -198,7 +213,8 @@ def choose_chunk(
     overrides ``n_taps`` with the mehrstellen stack size here, in ONE
     place, so the dispatch gate and the kernel builder can't drift.
     ``aligned`` budgets the aligned ring layout (the direct kernels pass
-    _aligned_layout's verdict)."""
+    _aligned_layout's verdict); ``partial_itemsize`` the tb=1 kernel's
+    change-residual partials (_vmem_bytes)."""
     if q_ring:
         n_taps = _MEHRSTELLEN_STACK_PLANES
     ny, nz = local_shape[1], local_shape[2]
@@ -214,6 +230,7 @@ def choose_chunk(
             by, nz, halo, in_itemsize, out_itemsize,
             q_itemsize=compute_itemsize if q_ring else 0,
             aligned=aligned,
+            partial_itemsize=partial_itemsize,
         )
         stack = _tap_stack_bytes(
             by, nz, halo, n_taps, compute_itemsize,
@@ -262,10 +279,12 @@ def direct_supported(
     n_taps: int = 7,
     compute_itemsize: int = 4,
     taps=None,
+    partial_itemsize: int = 0,
 ) -> bool:
     """Pass ``taps`` so the gate budgets the same route (q-ring or chain)
     apply_taps_direct will actually build; without them the chain route
-    is assumed (the mehrstellen knob is ignored)."""
+    is assumed (the mehrstellen knob is ignored). ``partial_itemsize``
+    budgets the change-residual partials of a tb=1 residual step."""
     nx, ny, nz = local_shape
     if halo == 2 and (nx < 2 or ny < 2 or nz < 2):
         return False  # wrapped/clamped width-2 ghosts would alias interior
@@ -275,6 +294,7 @@ def direct_supported(
             local_shape, halo, in_itemsize, out_itemsize, n_taps,
             compute_itemsize, q_ring=q_ring,
             aligned=_aligned_layout(nz, q_ring),
+            partial_itemsize=partial_itemsize,
         )
         is not None
     )
@@ -473,6 +493,7 @@ def _direct_kernel(
     compute_dtype,
     out_dtype,
     aligned=False,
+    partial_ref=None,
 ):
     """Grid step (j, i): assemble plane p = i-1 of chunk column j into a
     3-slot ring; once 3 planes are resident emit output plane i-2.
@@ -486,7 +507,12 @@ def _direct_kernel(
     = the mehrstellen S+F route on framed planes, where each stored plane
     also caches its 2D conv in ``ring_q`` (computed ONCE per input plane
     instead of once per output plane that reads it — the shifted-read reuse
-    the route exists for)."""
+    the route exists for).
+
+    With ``partial_ref`` (apply_taps_direct's ``residual_dtype``) each emit
+    also writes the partial sums of (new - old)^2 over the output chunk:
+    the stored new values against the centre plane's data cells, both in
+    the partials' dtype (_change_partials)."""
     j = pl.program_id(0)
     i = pl.program_id(1)
     bc = u_ref.dtype.type(bc_value)
@@ -536,12 +562,47 @@ def _direct_kernel(
                 )
             else:
                 res = _plane_taps(planes, taps_flat, by, nz, compute_dtype)
-            out_ref[0] = res.astype(out_dtype)
+            stored = res.astype(out_dtype)
+            out_ref[0] = stored
+            if partial_ref is not None:
+                if aligned:
+                    pad = _pad_rows(ring.dtype.itemsize)
+                    old = ring[slots[0], pad : pad + by]
+                else:
+                    old = ring[slots[0]][1 : 1 + by, 1 : 1 + nz]
+                partial_ref[0] = _change_partials(
+                    stored, old, partial_ref.shape[1:], partial_ref.dtype
+                )
 
 
 def _direct_kernel_single(u_ref, out_ref, ring, ring_q=None, **params):
     """Single-chunk-column variant: no ghost-row refs (derived in-kernel)."""
     _direct_kernel(u_ref, None, None, out_ref, ring, ring_q, **params)
+
+
+def _tree_sum(parts):
+    """Sum of equal-shaped arrays by pairwise adds."""
+    while len(parts) > 1:
+        parts = [a + b for a, b in zip(parts[::2], parts[1::2])] + (
+            parts[-1:] if len(parts) % 2 else []
+        )
+    return parts[0]
+
+
+def _change_partials(new, old, shape, dtype):
+    """Partial sums of ``(new - old)^2`` over one (by, nz) output chunk,
+    in ``dtype`` and folded to ``shape`` = (rows, lanes): lanes by adding
+    lane-tile slices, rows by adding row-tile slices (rows = 1: one
+    sublane sum), each by pairwise adds. residual_sumsq's values, summed
+    in another order."""
+    rows, lanes = shape
+    d = new.astype(dtype) - old.astype(dtype)
+    sq = d * d
+    by, nz = sq.shape
+    sq = _tree_sum([sq[:, c : c + lanes] for c in range(0, nz, lanes)])
+    if rows == 1:
+        return jnp.sum(sq, axis=0, keepdims=True)
+    return _tree_sum([sq[r : r + rows] for r in range(0, by, rows)])
 
 
 def _plane_q(framed, by, nz, compute_dtype):
@@ -583,11 +644,19 @@ def apply_taps_direct(
     compute_dtype=jnp.float32,
     out_dtype=None,
     interpret: bool = False,
-) -> jax.Array:
+    residual_dtype=None,
+):
     """One stencil update of the full (1,1,1)-mesh shard: unpadded
     (nx, ny, nz) in, (nx, ny, nz) out, boundary conditions synthesized
     in-kernel. Equivalent to ``exchange_halo`` + ``apply_taps_padded`` at
-    half the HBM traffic."""
+    half the HBM traffic.
+
+    With ``residual_dtype`` it returns ``(out, partials)``: partial sums
+    of ``(out - u)^2`` in that dtype, one (rows, lanes) block per output
+    plane and chunk column, whose sum is ``residual_sumsq(out, u,
+    residual_dtype)`` up to summation order. rows is 8 (1 for a single
+    chunk of another height), lanes 128 where nz is a whole number of lane
+    tiles, else nz. Only data cells count."""
     nx, ny, nz = u.shape
     out_dtype = out_dtype or u.dtype
     compute_dtype = jnp.dtype(compute_dtype).type
@@ -595,12 +664,15 @@ def apply_taps_direct(
     q_ring = _mehrstellen_q_ring(taps)
     coeffs = decompose_mehrstellen(taps) if q_ring else None
     aligned = _aligned_layout(nz, q_ring)
+    if residual_dtype is not None:
+        residual_dtype = jnp.dtype(residual_dtype)
     by = choose_chunk(
         u.shape, 1, u.dtype.itemsize, jnp.dtype(out_dtype).itemsize,
         n_taps=effective_num_taps(taps),
         compute_itemsize=jnp.dtype(compute_dtype).itemsize,
         q_ring=q_ring,
         aligned=aligned,
+        partial_itemsize=residual_dtype.itemsize if residual_dtype else 0,
     )
     if by is None:
         raise ValueError(f"no VMEM-feasible chunking for {u.shape}")
@@ -646,24 +718,52 @@ def apply_taps_direct(
     from heat3d_tpu.core.stencils import MEHRSTELLEN_OPS
 
     flops_per_cell = 2 * (MEHRSTELLEN_OPS if coeffs is not None else len(flat))
+    out_index = lambda j, i: (jnp.maximum(i - 2, 0), j, 0)  # noqa: E731
+    out_specs = pl.BlockSpec((1, by, nz), out_index)
+    out_shape = jax.ShapeDtypeStruct((nx, ny, nz), out_dtype)
+    bytes_accessed = nx * ny * nz * (
+        u.dtype.itemsize + jnp.dtype(out_dtype).itemsize
+    )
+    if residual_dtype is not None:
+        rows = _SUBLANE if by % _SUBLANE == 0 else 1
+        lanes = _partial_lanes(nz)
+        partials = jax.ShapeDtypeStruct(
+            (nx, rows * n_chunks, lanes), residual_dtype
+        )
+        out_specs = [out_specs, pl.BlockSpec((1, rows, lanes), out_index)]
+        out_shape = [out_shape, partials]
+        # the subtract, multiply and add of each cell's change
+        flops_per_cell += 3
+        bytes_accessed += partials.size * residual_dtype.itemsize
+        kernel = _with_partials(kernel, len(operands))
     return pl.pallas_call(
         kernel,
         name="heat3d.stencil.direct",
         grid=(n_chunks, nx + 2),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, by, nz), lambda j, i: (jnp.maximum(i - 2, 0), j, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((nx, ny, nz), out_dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=scratch_shapes,
         cost_estimate=pl.CostEstimate(
             flops=flops_per_cell * nx * ny * nz,
-            bytes_accessed=nx * ny * nz
-            * (u.dtype.itemsize + jnp.dtype(out_dtype).itemsize),
+            bytes_accessed=bytes_accessed,
             transcendentals=0,
         ),
         interpret=interpret,
     )(*operands)
+
+
+def _with_partials(kernel, n_in):
+    """The direct kernel with its partials' output: Pallas passes the
+    inputs, both outputs, then the scratch refs; ``kernel`` takes the
+    partials' ref by keyword."""
+
+    def with_partials(*refs):
+        out_ref, partial_ref = refs[n_in : n_in + 2]
+        kernel(*refs[:n_in], out_ref, *refs[n_in + 2 :],
+               partial_ref=partial_ref)
+
+    return with_partials
 
 
 def _direct2_kernel(

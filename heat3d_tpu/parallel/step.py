@@ -46,7 +46,7 @@ def _log_step_path_once(msg: str) -> None:
         get_logger(__name__).info("%s", msg)
 
 
-def _routed(fn, kernel, interpret=False, layout=None):
+def _routed(fn, kernel, interpret=False, layout=None, residual=None):
     """Record on a built step function the route its builder dispatched:
     ``kernel`` is the Pallas kernel family ('direct', 'streamk'), or None
     for the exchange path with the padded-block compute; ``interpret``
@@ -54,12 +54,17 @@ def _routed(fn, kernel, interpret=False, layout=None):
     ``layout`` is the direct kernels' VMEM plane layout, 'aligned' or
     'framed' (ops.stencil_pallas_direct.direct_layout). Every other kernel
     family builds framed planes, and there is no layout without a kernel.
+    A step with a residual also records where the residual is summed:
+    ``residual`` 'kernel' (the direct kernel's partial sums) or 'reduce'
+    (``residual_sumsq`` over both fields after the update).
     The CLI summary, bench rows and chip_smoke read this one record."""
     fn.route = {
         "kernel": kernel,
         "interpret": bool(interpret),
         "layout": None if kernel is None else layout or "framed",
     }
+    if residual is not None:
+        fn.route["residual"] = residual
     return fn
 
 
@@ -238,7 +243,10 @@ def _kernel_env_gate(cfg: SolverConfig):
     return True, interpret
 
 
-def _direct_kernel_fn(cfg: SolverConfig, halo: int, multichip: bool = False):
+def _direct_kernel_fn(
+    cfg: SolverConfig, halo: int, multichip: bool = False,
+    residual_dtype=None,
+):
     """Return the BC-fused direct Pallas kernel for this config, or None.
 
     On a (1, 1, 1) mesh every shard boundary is a domain boundary, so the
@@ -251,6 +259,9 @@ def _direct_kernel_fn(cfg: SolverConfig, halo: int, multichip: bool = False):
     for halo=1, _local_superstep_direct_faces for halo=2) any mesh
     qualifies: the kernel computes the bulk and the exchanged faces patch
     the shard-boundary shells.
+
+    With ``residual_dtype`` (tb=1, one shard) the kernel also returns its
+    change-residual partial sums in that dtype, and the gate budgets them.
     """
     import os
 
@@ -284,14 +295,20 @@ def _direct_kernel_fn(cfg: SolverConfig, halo: int, multichip: bool = False):
     c_item = jnp.dtype(cfg.precision.compute).itemsize
     # taps: weights suffice for the gate's mehrstellen predicate — the
     # update taps T = I + c*W decompose iff W does (affine in the center)
+    partial_itemsize = (
+        0 if residual_dtype is None else jnp.dtype(residual_dtype).itemsize
+    )
     if not direct_supported(
         cfg.local_shape, halo, itemsize, itemsize, n_taps, c_item,
         taps=STENCILS[cfg.stencil.kind].weights,
+        partial_itemsize=partial_itemsize,
     ):
         return None
     import functools
 
     kernel = apply_taps_direct if halo == 1 else apply_taps_direct2
+    if residual_dtype is not None:
+        kernel = functools.partial(kernel, residual_dtype=residual_dtype)
     return functools.partial(kernel, interpret=True) if interpret else kernel
 
 
@@ -636,6 +653,9 @@ def make_step_fn(
     local_step = _local_step
     kernel = None
     layout = None
+    # the single-shard direct step that also returns the kernel's
+    # change-residual partials, where a residual is asked for
+    partial_step = None
     direct = _direct_kernel_fn(cfg, halo=1, multichip=True)
     if direct is not None:
         from heat3d_tpu.ops.stencil_pallas_direct import direct_layout
@@ -654,16 +674,30 @@ def make_step_fn(
         if cfg.mesh.shape == (1, 1, 1):
             periodic = cfg.stencil.bc is BoundaryCondition.PERIODIC
 
-            def local_step(u_local, taps, cfg, compute_padded):
-                with named_phase("stencil"):
-                    return direct(
-                        u_local,
-                        taps,
-                        periodic=periodic,
-                        bc_value=cfg.stencil.bc_value,
-                        compute_dtype=jnp.dtype(cfg.precision.compute),
-                        out_dtype=jnp.dtype(cfg.precision.storage),
-                    )
+            def single_shard(kernel_fn):
+                def local_step(u_local, taps, cfg, compute_padded):
+                    with named_phase("stencil"):
+                        return kernel_fn(
+                            u_local,
+                            taps,
+                            periodic=periodic,
+                            bc_value=cfg.stencil.bc_value,
+                            compute_dtype=jnp.dtype(cfg.precision.compute),
+                            out_dtype=jnp.dtype(cfg.precision.storage),
+                        )
+
+                return local_step
+
+            local_step = single_shard(direct)
+            # every cell of the one shard is a kernel output cell, so the
+            # kernel's partials are the whole residual; on a multi-chip
+            # mesh the shell patches rewrite cells after the kernel
+            if with_residual:
+                partials = _direct_kernel_fn(
+                    cfg, halo=1, residual_dtype=cfg.precision.residual
+                )
+                if partials is not None:
+                    partial_step = single_shard(partials)
 
         else:
 
@@ -689,13 +723,20 @@ def make_step_fn(
     # mesh-agnostic. The unmapped residual out_spec stays sound: psum over all
     # mesh axes makes it replicated by construction.
     if with_residual:
+        residual_dtype = jnp.dtype(cfg.precision.residual)
 
         def local(u_local):
-            u_new = local_step(u_local, taps, cfg, compute_padded)
-            with named_phase("residual"):
-                r = residual_sumsq(
-                    u_new, u_local, jnp.dtype(cfg.precision.residual)
+            if partial_step is not None:
+                u_new, partials = partial_step(
+                    u_local, taps, cfg, compute_padded
                 )
+            else:
+                u_new = local_step(u_local, taps, cfg, compute_padded)
+            with named_phase("residual"):
+                if partial_step is not None:
+                    r = jnp.sum(partials, dtype=residual_dtype)
+                else:
+                    r = residual_sumsq(u_new, u_local, residual_dtype)
                 # MPI_Allreduce analogue (SURVEY.md §3.3)
                 r = lax.psum(r, axes)
             return u_new, r
@@ -716,6 +757,7 @@ def make_step_fn(
             kernel,
             kernel is not None and interpret,
             layout,
+            residual="reduce" if partial_step is None else "kernel",
         )
 
     def local(u_local):
@@ -747,10 +789,11 @@ def make_pair_step_fn(
     read, and both buffers returned in order. A caller that donates both
     (``HeatSolver3D.step``, ``step_with_residual``; jit them with
     ``keep_unused=True``, since the spare is only a buffer) pays no copy:
-    ``u`` stays in its own buffer, alive for the residual's reduction,
-    which reads both. The program is named ``wrapper``, as the step
-    builders' ``scoped`` programs are (jitted: ``jit_wrapper``), the name
-    the benchmark's ``solver.residual_share`` finds the residual step by."""
+    ``u`` stays in its own buffer, alive for the residual, which reads
+    both (on one shard inside the direct kernel). The program is named
+    ``wrapper``, as the step builders' ``scoped`` programs are (jitted:
+    ``jit_wrapper``), the name the benchmark's ``solver.residual_share``
+    finds the residual step by."""
     step = make_step_fn(cfg, mesh, compute_padded, with_residual=with_residual)
 
     def wrapper(u, spare):
@@ -1070,7 +1113,7 @@ def make_converge_fn(
             u, steps, r2 = lax.while_loop(cond, body, init)
             return u, steps, jnp.sqrt(r2)
 
-        run.route = multistep.route
+        run.route = {**multistep.route, "residual": step_r.route["residual"]}
         return run
 
     def run(u, max_steps, tol):

@@ -269,3 +269,48 @@ def test_four_chip_solver_programs_copy_no_field(v5e, mesh, grid, program):
     text = _pair_program(v5e, cfg, program).compile().as_text()
     assert "tpu_custom_call" in text
     assert full_volume_copies(text, cfg.local_shape) == 0
+
+
+@pytest.mark.parametrize("storage", ["float32", "bfloat16"])
+def test_residual_kernel_compiles_at_1024(v5e, storage):
+    """The residual step's kernel, the tb=1 direct kernel with its
+    change-residual partials, at 1024^3 on the aligned layout: one (8,
+    128) float32 block per output plane and chunk column."""
+    from heat3d_tpu.ops.stencil_pallas_direct import apply_taps_direct
+
+    taps = stencil_taps(STENCILS["7pt"], 0.1, 0.1, (1.0, 1.0, 1.0))
+    x = jax.ShapeDtypeStruct(
+        (1024, 1024, 1024), jnp.dtype(storage),
+        sharding=SingleDeviceSharding(v5e.devices[0]),
+    )
+    fn = lambda u: apply_taps_direct(  # noqa: E731
+        u, taps, bc_value=0.0, residual_dtype=jnp.float32
+    )
+    out, partials = jax.eval_shape(fn, x)
+    assert out.shape == x.shape and out.dtype == x.dtype
+    assert partials.shape[0] == 1024 and partials.shape[2] == 128
+    assert partials.shape[1] % 8 == 0 and partials.dtype == jnp.float32
+    assert "tpu_custom_call" in _compile_text(fn, x)
+
+
+def test_residual_step_reads_the_field_once_at_1024(v5e):
+    """The one-chip residual step at 1024^3 sums the kernel's partials:
+    no op but the kernel reads the incoming field (the reduce over both
+    fields is gone), and the kernel keeps its ``%heat3d.stencil.direct``
+    name."""
+    cfg = SolverConfig(grid=GridConfig.cube(1024), time_blocking=2,
+                       backend="auto")
+    text = _pair_program(v5e, cfg, "residual").compile().as_text()
+    entry = text[text.index("\nENTRY"):]
+    field = re.search(
+        r"(%[\w.-]+) = f32\[1024,1024,1024\]\S* parameter\(0\)", entry
+    ).group(1)
+    readers = [
+        line for line in entry.splitlines()[1:]
+        if re.search(re.escape(field) + r"(?![\w.])", line)
+        and " parameter(0)" not in line
+    ]
+    assert readers and all(
+        "tpu_custom_call" in r or r.lstrip().startswith("ROOT") for r in readers
+    ), readers
+    assert re.search(r"%heat3d\.stencil\.direct\.\d+ = ", entry)

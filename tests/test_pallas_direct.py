@@ -8,6 +8,7 @@ tolerance (FMA contraction differs between fused XLA loops and per-plane
 kernel ops — ~1 ulp)."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +25,7 @@ from heat3d_tpu.core.config import (
     StencilConfig,
 )
 from heat3d_tpu.core.stencils import STENCILS, stencil_taps
-from heat3d_tpu.ops.stencil_jnp import step_single_device
+from heat3d_tpu.ops.stencil_jnp import residual_sumsq, step_single_device
 from heat3d_tpu.ops.stencil_pallas_direct import (
     apply_taps_direct,
     apply_taps_direct2,
@@ -200,6 +201,45 @@ def test_direct_compiled_on_tpu(kind, shape, layout):
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6,
             err_msg=f"{kind} {shape} {bc}",
+        )
+
+
+@pytest.mark.tpu_smoke
+@pytest.mark.skipif(not on_tpu, reason="needs TPU")
+@pytest.mark.parametrize(
+    "shape,layout",
+    [
+        ((64, 64, 128), "aligned"),
+        ((32, 2048, 256), "aligned"),
+        ((64, 64, 96), "framed"),
+        ((32, 2048, 96), "framed"),
+    ],
+)
+@pytest.mark.parametrize("storage", ["float32", "bfloat16"])
+def test_direct_change_partials_compiled_on_tpu(shape, layout, storage):
+    """The compiled kernel's change partials: the field bit for bit as
+    without them, their sum ``residual_sumsq`` of the stored field."""
+    from heat3d_tpu.ops.stencil_pallas_direct import direct_layout
+
+    u = jnp.asarray(golden.random_init(shape, seed=8), storage)
+    taps = _taps("7pt", shape)
+    assert direct_layout(shape, taps) == layout
+    for bc, bcv in CASES:
+        periodic = bc is BoundaryCondition.PERIODIC
+        call = functools.partial(
+            apply_taps_direct, taps=taps, periodic=periodic, bc_value=bcv
+        )
+        plain = jax.jit(call)(u)
+        out, partials = jax.jit(
+            functools.partial(call, residual_dtype=jnp.float32)
+        )(u)
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(plain), err_msg=f"{shape} {bc}"
+        )
+        np.testing.assert_allclose(
+            float(jnp.sum(partials)),
+            float(residual_sumsq(out, u, jnp.float32)),
+            rtol=1e-5, err_msg=f"{shape} {bc}",
         )
 
 
@@ -520,3 +560,101 @@ def test_solver_route_records_layout(nz, layout, tb, monkeypatch):
     route = HeatSolver3D(cfg).route
     assert route["kernel"] == "direct"
     assert route["layout"] == layout
+
+
+# (shape, kind, case in CASES, storage, forced chunk height or None,
+# mehrstellen): the aligned, framed and mehrstellen emit routes, one and
+# several chunk columns, and a single chunk whose height is no multiple
+# of 8 (its partials fold to one row)
+PARTIAL_CASES = [
+    pytest.param((5, 16, 128), "7pt", 1, "float32", None, False,
+                 id="aligned-dirichlet"),
+    pytest.param((6, 16, 256), "27pt", 2, "float32", 8, False,
+                 id="aligned-periodic-multichunk"),
+    pytest.param((6, 16, 32), "7pt", 0, "float32", None, False,
+                 id="framed-dirichlet"),
+    pytest.param((6, 16, 32), "27pt", 2, "float32", 8, False,
+                 id="framed-periodic-multichunk"),
+    pytest.param((4, 12, 16), "7pt", 1, "float32", None, False,
+                 id="framed-rows12"),
+    pytest.param((5, 16, 128), "27pt", 1, "float32", None, True,
+                 id="mehrstellen-dirichlet"),
+    pytest.param((6, 16, 32), "27pt", 2, "float32", 8, True,
+                 id="mehrstellen-periodic-multichunk"),
+    pytest.param((6, 16, 128), "7pt", 1, "bfloat16", None, False,
+                 id="bf16-aligned-dirichlet"),
+    pytest.param((6, 16, 32), "7pt", 2, "bfloat16", 8, False,
+                 id="bf16-framed-periodic-multichunk"),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,kind,case,storage,by,mehrstellen", PARTIAL_CASES
+)
+def test_direct_change_partials(shape, kind, case, storage, by, mehrstellen,
+                                monkeypatch):
+    """With ``residual_dtype`` the tb=1 kernel returns the same field bits
+    as without it, and partial sums of the change that add up, per output
+    plane and chunk column, to ``residual_sumsq`` of the stored field
+    against the input (bf16 storage: the stored bf16 values) in float32."""
+    import heat3d_tpu.ops.stencil_pallas_direct as d
+
+    if by is not None:
+        monkeypatch.setattr(d, "choose_chunk", lambda *a, **k: by)
+    if mehrstellen:
+        monkeypatch.setenv("HEAT3D_MEHRSTELLEN", "1")
+    else:
+        monkeypatch.delenv("HEAT3D_MEHRSTELLEN", raising=False)
+    bc, bcv = CASES[case]
+    u = jnp.asarray(golden.random_init(shape, seed=13), storage)
+    taps = _taps(kind, shape)
+    layout = "aligned" if shape[2] % 128 == 0 and not mehrstellen else "framed"
+    assert d.direct_layout(shape, taps) == layout
+    call = functools.partial(
+        d.apply_taps_direct, u, taps,
+        periodic=bc is BoundaryCondition.PERIODIC, bc_value=bcv,
+        interpret=True,
+    )
+    plain = call()
+    out, partials = call(residual_dtype=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(plain))
+    assert out.dtype == u.dtype and partials.dtype == jnp.float32
+
+    nx, ny, nz = shape
+    rows = 1 if partials.shape[1] == 1 else 8
+    chunks = partials.shape[1] // rows
+    lanes = 128 if nz % 128 == 0 else nz
+    assert partials.shape == (nx, rows * chunks, lanes)
+    assert chunks == (ny // by if by else 1)
+    want = float(residual_sumsq(out, u, jnp.float32))
+    np.testing.assert_allclose(
+        float(jnp.sum(partials)), want, rtol=1e-5
+    )
+    # each block holds its own plane's and chunk column's cells
+    change = (np.asarray(out, np.float64) - np.asarray(u, np.float64)) ** 2
+    np.testing.assert_allclose(
+        np.asarray(partials, np.float64)
+        .reshape(nx, chunks, rows * lanes).sum(-1),
+        change.reshape(nx, chunks, ny // chunks * nz).sum(-1),
+        rtol=1e-5, atol=1e-6 * want,
+    )
+
+
+def test_change_partials_count_in_the_vmem_budget():
+    """The partials' double-buffered block counts in the tb=1 kernel's
+    VMEM footprint (an (8, 128) block, or (8, nz) where nz is no whole
+    number of lane tiles), so the gate budgets what the residual step
+    builds; at 1024^3 it leaves the chunk height as it was."""
+    import heat3d_tpu.ops.stencil_pallas_direct as d
+
+    for nz, lanes in ((1024, 128), (96, 96)):
+        plain = d._vmem_bytes(128, nz, 1, 4, 4, aligned=nz % 128 == 0)
+        with_partials = d._vmem_bytes(
+            128, nz, 1, 4, 4, aligned=nz % 128 == 0, partial_itemsize=4
+        )
+        assert with_partials - plain == 2 * 8 * d._round_up(lanes, 128) * 4
+    shape = (1024, 1024, 1024)
+    assert d.choose_chunk(shape, aligned=True) == d.choose_chunk(
+        shape, aligned=True, partial_itemsize=4
+    )
+    assert d.direct_supported(shape, partial_itemsize=4)

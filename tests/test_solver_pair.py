@@ -18,6 +18,7 @@ XLA:CPU fuses the two loops' bodies differently and rounds some cells of
 an even count 1 ulp apart, so that route is not compared bit for bit.
 """
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -180,6 +181,55 @@ def test_one_spare_serves_a_mixed_sequence(kernel_route):
     assert _count("solver_spare_allocs_total") - allocs == 2
 
 
+@pytest.mark.parametrize("tb", TBS)
+def test_residual_step_sums_in_the_kernel(solvers, tb, monkeypatch):
+    """On one shard with the direct kernel, the residual step sums the
+    kernel's change partials (``heat3d_solver_residuals_total{source=
+    "kernel"}``) and gives the field and residual the reduce path gives
+    (``HEAT3D_NO_DIRECT``: the padded-block step, then
+    ``residual_sumsq``)."""
+    solver = solvers[tb][0]
+    u0 = golden.random_init(solver.cfg.grid.shape, seed=17)
+    before = _count("solver_residuals_total", source="kernel")
+    got, got_r2 = solver.step_with_residual(solver.init_state(u0))
+    assert _count("solver_residuals_total", source="kernel") - before == 1
+    monkeypatch.setenv("HEAT3D_NO_DIRECT", "1")
+    reduce = HeatSolver3D(_cfg(tb))
+    before = _count("solver_residuals_total", source="reduce")
+    want, want_r2 = reduce.step_with_residual(reduce.init_state(u0))
+    assert _count("solver_residuals_total", source="reduce") - before == 1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(float(got_r2), float(want_r2), rtol=1e-5)
+
+
+def test_converge_stops_where_the_reduce_path_does(kernel_route,
+                                                   monkeypatch):
+    """``run_to_convergence`` with a residual every 10 steps takes its
+    residual step from the kernel's partials and stops at the same step,
+    with the same residual, as on the reduce path: 50 steps, where the
+    residual first falls under 0.55 (0.592 at 40, 0.505 at 50)."""
+    from heat3d_tpu.core.config import RunConfig
+    from heat3d_tpu.parallel.step import make_converge_fn
+
+    def converge():
+        cfg = dataclasses.replace(_cfg(2), run=RunConfig(residual_every=10))
+        solver = HeatSolver3D(cfg)
+        u = solver.init_state(golden.random_init(cfg.grid.shape, seed=5))
+        return solver, solver.run_to_convergence(u, 0.55, 200)
+
+    solver, got = converge()
+    assert make_step_fn(solver.cfg, solver.mesh, solver._compute,
+                        with_residual=True).route["residual"] == "kernel"
+    monkeypatch.setenv("HEAT3D_NO_DIRECT", "1")
+    reduce, want = converge()
+    assert reduce._residual_source == "reduce"
+    assert got.steps == want.steps == 50
+    np.testing.assert_allclose(got.residual, want.residual, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(got.u), np.asarray(want.u),
+                               rtol=1e-5, atol=1e-6)
+
+
 def test_cli_timed_loop_compiles_nothing(capsys):
     """The CLI's warm-up compiles every loop executable its timed loop
     dispatches (one per write structure of a group's step count,
@@ -247,11 +297,17 @@ for tb in TBS:
             "deleted": u.is_deleted(),
         }
     allocs = _count("solver_spare_allocs_total")
+    sources = {s: _count("solver_residuals_total", source=s)
+               for s in ("kernel", "reduce")}
     u = solver.init_state(u0)
     for n in (9, 25, 2):
         u = solver.run(u, n)
         u, _ = solver.step_with_residual(u)
     out[f"allocs{tb}-mixed"] = _count("solver_spare_allocs_total") - allocs
+    out[f"residuals{tb}"] = {
+        s: _count("solver_residuals_total", source=s) - n
+        for s, n in sources.items()
+    }
     u = solver.run(jax.device_put(u, NamedSharding(solver.mesh, P())), 3)
     u = solver.run(u, 3)
     out[f"allocs{tb}-resharded"] = _count("solver_spare_allocs_total") - allocs
@@ -307,3 +363,10 @@ def test_spare_on_the_2x2x1_cpu_mesh(mesh221, tb):
     assert mesh221[f"allocs{tb}-mixed"] == 0
     assert (mesh221[f"allocs{tb}-resharded"]
             == mesh221[f"allocs{tb}-mixed"] + 1)
+
+
+@pytest.mark.parametrize("tb", TBS)
+def test_residual_source_on_the_2x2x1_cpu_mesh(mesh221, tb):
+    """On (2,2,1) the shell patches rewrite cells after the kernel, so
+    the residual step keeps the reduce: three steps, three counts."""
+    assert mesh221[f"residuals{tb}"] == {"kernel": 0, "reduce": 3}
