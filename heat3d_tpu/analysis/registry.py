@@ -61,6 +61,7 @@ LEDGER_EVENTS: Dict[str, Dict[str, Any]] = {
     "solver.dispatch": {"kind": "span", "module": "models/heat3d.py",
                         "desc": "one solver program dispatch (program: "
                                 "run|step|step_residual|converge; steps; "
+                                "dtype: the field's storage dtype; "
                                 "compile_s when it compiled)"},
     "cg_solve": {"kind": "point", "module": "models/heat3d.py",
                  "desc": "implicit-cg run finished: steps, last solve's "

@@ -3,14 +3,15 @@
 Reference parity (SURVEY.md §2 C4/C12, §3.1): the reference is launched as
 ``mpirun -np P ./heat3d NX NY NZ NITER [Px Py Pz]``. Equivalent here::
 
-    heat3d --grid 1024 --steps 1000 --mesh 8 1 1           # config 2 (slab)
+    heat3d --grid 1024 --steps 1000 --mesh 4 1 1           # config 2 (slab)
     heat3d --grid 2048 --mesh 2 2 2                        # config 3
     heat3d --grid 4096 --stencil 27pt --mesh 4 4 4         # config 4
-    heat3d --grid 4096 --dtype bf16 --mesh 8 4 4           # config 5
+    heat3d --grid 4096 --dtype bf16 --mesh 4 4 4           # config 5
     heat3d --grid 128 --golden-check                       # config 1
 
-One process per host on a pod slice; ``jax.distributed`` replaces mpirun
-(BASELINE.json north star). All output is JSON on stdout, human logs on
+One JAX device per v5p chip (two TensorCores): v5p-8 is 4 devices, v5p-128
+is 64. One process per host on a pod slice; ``jax.distributed`` replaces
+mpirun (BASELINE.json north star). All output is JSON on stdout, human logs on
 stderr.
 """
 

@@ -7,10 +7,14 @@ Here every judged config from BASELINE.json is expressible as a frozen
 dataclass (and via the CLI front-end in ``heat3d_tpu.cli``):
 
   1. 128^3, 7-point, single-rank golden reference   -> GridConfig(128), StencilConfig('7pt'), MeshConfig((1,1,1))
-  2. 1024^3, 7-point, 1D slab on v5p-8              -> MeshConfig((8,1,1))
+  2. 1024^3, 7-point, 1D slab on v5p-8              -> MeshConfig((4,1,1))
   3. 2048^3, 7-point, 3D block (2x2x2) on v5p-8     -> MeshConfig((2,2,2))
   4. 4096^3, 27-point, 3D block on v5p-64           -> StencilConfig('27pt'), MeshConfig((4,4,4))
-  5. 4096^3, bf16 stencil + fp32 residual, v5p-128  -> Precision(compute='bfloat16', residual='float32')
+  5. 4096^3, bf16 stencil + fp32 residual, v5p-128  -> Precision.bf16(), MeshConfig((4,4,4))
+
+A v5p chip has two TensorCores and is one JAX device: v5p-8 is 4 devices,
+v5p-128 is 64. Config 5's "bf16 stencil" is bf16 storage with the stencil
+arithmetic and the residual in float32 (``Precision.bf16()``).
 """
 
 from __future__ import annotations

@@ -254,10 +254,12 @@ class HeatSolver3D:
         the residual: 'kernel' (the direct kernel's partials) or 'reduce'.
         Where the devices carry ICI ``coords`` (TPU), ``mesh_coords``
         lists them in the mesh's row-major order
-        (``parallel.topology.ici_order``)."""
+        (``parallel.topology.ici_order``). ``precision`` holds the
+        config's ``storage``, ``compute`` and ``residual`` dtypes."""
         if self._route is None:
             _ = self._multistep
         route = {"backend": resolved_backend_name(self.cfg), **self._route}
+        route["precision"] = dataclasses.asdict(self.cfg.precision)
         coords = [getattr(d, "coords", None) for d in self.mesh.devices.flat]
         if all(c is not None for c in coords):
             route["mesh_coords"] = [list(c) for c in coords]
@@ -446,6 +448,14 @@ class HeatSolver3D:
             )
         return steps * one
 
+    def _dispatch_span(self, program: str, **fields):
+        """The ``solver.dispatch`` span of one dispatch of ``program``,
+        with the field's storage ``dtype``."""
+        return obs.get().span(
+            "solver.dispatch", program=program,
+            dtype=self.cfg.precision.storage, **fields,
+        )
+
     def _dispatched(self, program: str, steps: int) -> None:
         """Count one dispatch of ``program`` that made ``steps`` updates:
         ``heat3d_solver_steps_total{program}`` and
@@ -506,7 +516,7 @@ class HeatSolver3D:
         jax.block_until_ready(self.run(self.zeros_state(), n))
 
     def step(self, u: jax.Array) -> jax.Array:
-        with obs.get().span("solver.dispatch", program="step", steps=1):
+        with self._dispatch_span("step", steps=1):
             if self.cfg.integrator != "explicit-euler":
                 u = self._step(u)
             else:
@@ -515,9 +525,7 @@ class HeatSolver3D:
         return u
 
     def step_with_residual(self, u: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        with obs.get().span(
-            "solver.dispatch", program="step_residual", steps=1
-        ):
+        with self._dispatch_span("step_residual", steps=1):
             if self.cfg.integrator != "explicit-euler":
                 out = self._step_res(u)
             else:
@@ -565,7 +573,7 @@ class HeatSolver3D:
         host sync happens after the loop, where the caller consumes the
         field anyway)."""
         steps = int(num_steps)
-        with obs.get().span("solver.dispatch", program="run", steps=steps):
+        with self._dispatch_span("run", steps=steps):
             if self.cfg.integrator != "explicit-euler":
                 out = self._multistep(u, jnp.int32(steps))
             else:
@@ -598,7 +606,7 @@ class HeatSolver3D:
                 "fixed-step run() (docs/INTEGRATORS.md)"
             )
         self._route = self._converge_route
-        with obs.get().span("solver.dispatch", program="converge") as sp:
+        with self._dispatch_span("converge") as sp:
             u, steps, res = self._converge(
                 u, jnp.int32(max_steps), jnp.float32(tol)
             )

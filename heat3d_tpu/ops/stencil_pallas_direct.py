@@ -379,6 +379,15 @@ def _edge_lane_select(x, lane, fill):
     return jnp.concatenate([p for p in parts if p.shape[1]], axis=1)
 
 
+def _roll(x, shift, axis):
+    """``pltpu.roll`` of ``x``. Mosaic rotates 32-bit data only, so a
+    narrower dtype (a bf16 compute dtype) is rotated as float32, which
+    holds each of its values exactly, and cast back."""
+    if x.dtype.itemsize < 4:
+        return pltpu.roll(x.astype(jnp.float32), shift, axis).astype(x.dtype)
+    return pltpu.roll(x, shift, axis)
+
+
 def _aligned_plane_taps(planes, taps_flat, lo, n, edge, compute_dtype):
     """The tap chain over three aligned (R, nz) planes: the (n, nz) update
     of rows [lo, lo + n) of the middle plane, in the canonical
@@ -398,13 +407,13 @@ def _aligned_plane_taps(planes, taps_flat, lo, n, edge, compute_dtype):
 
     def rows(src, dj):
         if dj:
-            src = pltpu.roll(src, (-dj) % rows_total, 0)
+            src = _roll(src, (-dj) % rows_total, 0)
         return src[lo : lo + n]
 
     def lanes(x, dk, fill):
         if dk == 0:
             return x
-        x = pltpu.roll(x, (-dk) % nz, 1)
+        x = _roll(x, (-dk) % nz, 1)
         if fill is None:
             return x
         return _edge_lane_select(x, 0 if dk < 0 else nz - 1, fill)

@@ -248,6 +248,9 @@ def test_solver_records_direct_route(monkeypatch):
     assert HeatSolver3D(cfg).route == {
         "backend": "jnp", "kernel": "direct", "interpret": True,
         "layout": "framed",  # nz = 16: not a whole lane tile
+        "precision": {
+            "storage": "float32", "compute": "float32", "residual": "float32",
+        },
     }
     monkeypatch.setenv("HEAT3D_NO_DIRECT", "1")
     assert HeatSolver3D(cfg).route["kernel"] is None

@@ -141,7 +141,10 @@ def test_cli_summary_records_resolved_route(capsys):
     summary = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     # 'auto' off the chip: the jnp padded-block compute, no kernel
     assert summary["route"] == {
-        "backend": "jnp", "kernel": None, "interpret": False, "layout": None
+        "backend": "jnp", "kernel": None, "interpret": False, "layout": None,
+        "precision": {
+            "storage": "float32", "compute": "float32", "residual": "float32",
+        },
     }
     assert summary["device_kind"] == "cpu"
     assert summary["warmup_s"] > 0

@@ -91,6 +91,30 @@ def test_direct_kernel_compiles_at_1024(v5e, kind, storage, tb):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("kind", ["7pt", "27pt"])
+@pytest.mark.parametrize("tb", [1, 2])
+def test_bf16_compute_direct_kernel_compiles_at_1024(v5e, kind, tb):
+    """The ``--compute-dtype bf16`` A/B (the bf16 cell's control): bf16
+    storage and bf16 tap arithmetic on the aligned layout, whose rolls
+    Mosaic takes in 32-bit data only."""
+    from heat3d_tpu.ops.stencil_pallas_direct import (
+        apply_taps_direct,
+        apply_taps_direct2,
+    )
+
+    kernel = apply_taps_direct if tb == 1 else apply_taps_direct2
+    taps = stencil_taps(STENCILS[kind], 0.1, 0.1, (1.0, 1.0, 1.0))
+    x = jax.ShapeDtypeStruct(
+        (1024, 1024, 1024), jnp.bfloat16,
+        sharding=SingleDeviceSharding(v5e.devices[0]),
+    )
+    text = _compile_text(
+        lambda u: kernel(u, taps, bc_value=0.0, compute_dtype=jnp.bfloat16),
+        x,
+    )
+    assert "tpu_custom_call" in text
+
+
 def _sharded_step(topo, cfg):
     from heat3d_tpu.parallel.step import make_step_fn, make_superstep_fn
     from heat3d_tpu.parallel.topology import build_mesh
@@ -177,12 +201,12 @@ def test_build_mesh_orders_the_described_host(v5e):
     assert list(block.devices.flat) == list(devs)
 
 
-def full_volume_copies(text, shape):
-    """Copies of a whole ``shape`` fp32 block in HBM in compiled HLO text:
-    ``copy`` and ``copy-start`` whose destination is in the default memory
-    space (a layout without ``S(n)``; an ``S(1)`` destination is a
-    prefetch into on-chip memory)."""
-    dims = r"f32\[%s\]" % ",".join(map(str, shape))
+def full_volume_copies(text, shape, dtype="f32"):
+    """Copies of a whole ``shape`` block of ``dtype`` (HLO's name) in HBM
+    in compiled HLO text: ``copy`` and ``copy-start`` whose destination is
+    in the default memory space (a layout without ``S(n)``; an ``S(1)``
+    destination is a prefetch into on-chip memory)."""
+    dims = r"%s\[%s\]" % (dtype, ",".join(map(str, shape)))
     copies = re.findall(r"= %s(\{[^}]*\}) copy\(" % dims, text)
     starts = re.findall(r"= \(%s(\{[^}]*\}), [^\n]* copy-start\(" % dims, text)
     return sum("S(" not in layout for layout in copies + starts)
@@ -197,7 +221,7 @@ def _mesh_field(topo, cfg):
 
     mesh = build_mesh(cfg.mesh, topo.devices[: cfg.mesh.num_devices])
     return mesh, jax.ShapeDtypeStruct(
-        cfg.grid.shape, jnp.float32,
+        cfg.grid.shape, jnp.dtype(cfg.precision.storage),
         sharding=NamedSharding(mesh, P(*cfg.mesh.axis_names)),
     )
 
@@ -228,14 +252,29 @@ def test_solver_programs_copy_no_field_at_1024(v5e, program):
     the buffer the field is not in, so the compiled program holds no
     full-volume copy and no temporary field; the pair comes back
     aliased to the two donated inputs."""
+    _copies_no_field(v5e, Precision.fp32(), program, "f32")
+
+
+def _copies_no_field(topo, precision, program, dtype):
+    """``program`` of the one-chip cells at 1024^3, tb=2, in
+    ``precision``: a kernel, no full-volume copy of ``dtype`` (HLO's
+    name), no temporary field, the pair aliased to its inputs."""
     cfg = SolverConfig(grid=GridConfig.cube(1024), time_blocking=2,
-                       backend="auto")
-    compiled = _pair_program(v5e, cfg, program).compile()
+                       backend="auto", precision=precision)
+    compiled = _pair_program(topo, cfg, program).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert full_volume_copies(text, (1024,) * 3) == 0
+    assert full_volume_copies(text, (1024,) * 3, dtype) == 0
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
     assert "{0}: (0, {}, may-alias), {1}: (1, {}, may-alias)" in text
+
+
+@pytest.mark.parametrize("program", ["run-49", "residual"])
+def test_bf16_cell_programs_copy_no_field_at_1024(v5e, program):
+    """The bf16 cell's two programs (``monitor50``: ``run(u, 49)``, then
+    the residual step), 1024^3 in bf16 storage at tb=2: the direct
+    kernels, no full-volume copy, the pair aliased to its inputs."""
+    _copies_no_field(v5e, Precision.bf16(), program, "bf16")
 
 
 def test_single_buffer_loop_copies_the_field(v5e):

@@ -108,6 +108,9 @@ def test_slab_route_matches_golden_on_cpu_mesh(tb, kind, bc):
     assert out["fp32"]["route"] == {
         "backend": "jnp", "kernel": "direct", "interpret": True,
         "layout": "aligned",
+        "precision": {
+            "storage": "float32", "compute": "float32", "residual": "float32",
+        },
     }
     assert out["fp32"]["rel_err"] < REL_TOL, out["fp32"]
     assert out["bf16"]["rel_err"] > REL_TOL, out["bf16"]
